@@ -217,7 +217,10 @@ class WorldResult:
     """What run_world returns: per-app-rank results and per-server stats."""
 
     app_results: dict[int, Any]
-    server_stats: dict[int, dict[int, float]]
+    # rank -> {int(InfoKey): float}, plus one "solver" entry where the
+    # planner lived (the master server, or the balancer sidecar's
+    # pseudo-rank on the native plane): see solver_facts()
+    server_stats: dict[int, dict]
     aborted: bool
     exception: Optional[BaseException] = None
     # merged Chrome-trace events when Config(trace=True) (the reference's
@@ -244,6 +247,17 @@ class WorldResult:
         from adlb_tpu.runtime.trace import save_chrome_trace
 
         save_chrome_trace(self.trace_events, path)
+
+    def solver_facts(self) -> Optional[dict]:
+        """Which path planned this world (``PlanEngine.solver_facts``):
+        platform, device_kind, device_count, path (``none`` | ``numpy`` |
+        ``xla`` | ``pallas`` | ``pallas-interpret`` | ``mesh-device`` |
+        ``mesh-host``), device_solves, host_solves, device_failures.
+        None when no planner host reported (native servers under steal)."""
+        for s in self.server_stats.values():
+            if "solver" in s:
+                return s["solver"]
+        return None
 
     def info_get(self, key: InfoKey) -> float:
         """Aggregate a stats key over servers the way the reference's
@@ -444,7 +458,7 @@ def run_world(
     )
     fabric = InProcFabric(world.nranks)
     app_results: dict[int, Any] = {}
-    server_stats: dict[int, dict[int, float]] = {}
+    server_stats: dict[int, dict] = {}
     trace_events: list[dict] = []
     errors: list[BaseException] = []
     casualties: list[int] = []

@@ -73,12 +73,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from adlb_tpu.balancer.jobdim import bias_vector, expand_types
 from adlb_tpu.balancer.solve import (
@@ -128,7 +124,7 @@ def _build_gather_fn(mesh: Mesh, T: int, D: int, axis: str = "s"):
         shard_fn, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis)),
         out_specs=(P(axis, None, None), P(axis, None, None)),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -257,7 +253,7 @@ def _build_plan_fn(mesh: Mesh, T: int, D: int, C: int, rounds: int,
         in_specs=(P(axis, None), P(axis, None), P(axis),
                   P(None, None), P(None), P(None)),
         out_specs=P(axis, None, None),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -635,6 +631,25 @@ class DistributedAssignmentSolver:
         self.last_solve_ms = 0.0
         self.last_extract_ms = 0.0
         self.solve_count = 0
+        self.first_device_solve_s = 0.0
+        self.device_failures = 0
+
+    def facts(self) -> dict:
+        """Which path answered, and how often (PlanEngine.solver_facts).
+        Both tiers run the candidate sweep on the mesh; the name says
+        where the auction runs."""
+        return {
+            "path": "mesh-device" if self.auction == "device"
+            else "mesh-host",
+            "device_solves": self.solve_count,
+            "host_solves": 0,
+            "device_failures": self.device_failures,
+            "first_device_solve_s": round(self.first_device_solve_s, 3),
+            # distinct devices holding a shard of the resident task
+            # table (0 until the first ingest builds it)
+            "table_devices": 0 if self._dev_tp is None else len(
+                {sh.device for sh in self._dev_tp.addressable_shards}),
+        }
 
     # ------------------------------------------------------------------
     def set_job_bias(self, job_weights: Optional[dict]) -> bool:
@@ -656,6 +671,9 @@ class DistributedAssignmentSolver:
     def _ensure_built(self) -> None:
         if self._gather_fn is not None:
             return
+        from adlb_tpu.utils.jaxenv import ensure_compile_cache
+
+        ensure_compile_cache()
         self._gather_fn = _build_gather_fn(self.mesh, self.T, self.D)
         self._shard = NamedSharding(self.mesh, P("s", None))
         self._shard1 = NamedSharding(self.mesh, P("s"))
@@ -1177,6 +1195,9 @@ class DistributedAssignmentSolver:
             self._planned_servers.add(holder)
             self._planned_servers.add(req_home)
         self.last_extract_ms = (time.perf_counter() - t1) * 1e3
+        if self.solve_count == 0:
+            # set-up, not speed: build + compile (or cache load) + one plan
+            self.first_device_solve_s = time.perf_counter() - t0
         self.solve_count += 1
         return pairs
 
@@ -1184,8 +1205,15 @@ class DistributedAssignmentSolver:
         """Engine-compatible one-call path: ingest deltas, then plan.
         Accepts either the filtered-snapshot dict or the engine's
         array-resident ledger view."""
-        if getattr(snapshots, "is_array", False):
-            self._ingest_view(snapshots)
-        else:
-            self.ingest(snapshots)
-        return self.plan()
+        try:
+            if getattr(snapshots, "is_array", False):
+                self._ingest_view(snapshots)
+            else:
+                self.ingest(snapshots)
+            return self.plan()
+        except Exception:
+            # counted and re-raised: the resident table, the sweep and
+            # the auction are all the mesh path, and nothing below the
+            # caller replaces it with a host solve
+            self.device_failures += 1
+            raise

@@ -185,6 +185,10 @@ def pallas_greedy_assign(
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((NTp, 1), jnp.int32),
+        # the open vector and counter carry from one task block to the
+        # next in scratch: the grid must run in order, on one core
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(nopen0.reshape(1), compat)[:NT, 0]
 

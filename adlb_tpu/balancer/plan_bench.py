@@ -1,22 +1,25 @@
 """Planning-latency sweep for the sharded (multichip) balancer.
 
 Measures the full planning round — snapshot-delta ingest -> sharded
-solve -> plan extracted on host — on a host-simulated device mesh, at a
-ladder of world sizes up to 1,000 servers / 100k parked requesters
-(ROADMAP item 1's scale target). Steady state is engine-faithful: every
-round ships task deltas for a handful of servers, the previous round's
-plan is consumed by the data plane (matched tasks leave their queues,
-matched requesters unpark), and stamps ride the snapshots so the
-solver's unchanged-server fast path is exercised the way the engine
-drives it.
+solve -> plan extracted on host — on the devices JAX shows, at a ladder
+of world sizes up to 10,000 servers / 1M parked requesters. Steady state
+is engine-faithful: every round ships task deltas for a handful of
+servers, the previous round's plan is consumed by the data plane
+(matched tasks leave their queues, matched requesters unpark), and
+stamps ride the snapshots so the solver's unchanged-server fast path is
+exercised the way the engine drives it.
 
-Run standalone (self-provisions the virtual mesh):
+    python -m adlb_tpu.balancer.plan_bench [--quick] [--ndev N]
 
-    python -m adlb_tpu.balancer.plan_bench [--quick] [--ndev 8]
+``--ndev`` defaults to every visible device and fails when fewer are
+visible than asked for; it never re-provisions. For a CPU mesh, say so
+in the environment:
 
-or from scripts/sim_scale.py --plan-sweep. bench.py shells out to this
-module so the virtual-mesh provisioning cannot disturb the parent
-process's accelerator backend.
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python -m adlb_tpu.balancer.plan_bench --quick
+
+bench.py and scripts/sim_scale.py --plan-sweep shell out to this module,
+so a parent that must stay off JAX can.
 """
 
 from __future__ import annotations
@@ -52,8 +55,14 @@ def run_sweep(scales=None, reps: int = 40, ndev: int = 8,
 
     from adlb_tpu.balancer.distributed import DistributedAssignmentSolver
 
+    from adlb_tpu.utils.jaxenv import ensure_compile_cache
+
+    ensure_compile_cache()
     devs = np.array(jax.devices()[:ndev])
-    assert len(devs) >= ndev, f"need {ndev} devices, have {len(devs)}"
+    if len(devs) < ndev:
+        raise RuntimeError(
+            f"need {ndev} devices, JAX shows {len(devs)} "
+            f"({jax.devices()[0].platform})")
     mesh = Mesh(devs, axis_names=("s",))
     rows = []
     for S, K, R in scales or SCALES:
@@ -155,6 +164,8 @@ def run_sweep(scales=None, reps: int = 40, ndev: int = 8,
         )
     out = {
         "metric": "plan_round_latency",
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
         "n_devices": ndev,
         "rounds": rounds,
         "auction": auction,
@@ -162,7 +173,7 @@ def run_sweep(scales=None, reps: int = 40, ndev: int = 8,
         "rows": rows,
         "note": (
             "full planning round (snapshot-delta ingest -> sharded solve "
-            "-> plan extracted on host) on an 8-way host-simulated mesh; "
+            "-> plan extracted on host) on the mesh named above; "
             "steady state is engine-faithful (plans consumed, stamps "
             "ride snapshots). device_sweep_ms is the full mesh re-sweep "
             "paid at cold start / large deltas / every RESYNC_INTERVAL "
@@ -346,7 +357,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--quick", action="store_true",
                     help="fewer reps, smallest+largest scales only")
-    ap.add_argument("--ndev", type=int, default=8)
+    ap.add_argument("--ndev", type=int, default=None,
+                    help="mesh size (default: every visible device)")
     ap.add_argument("--auction", choices=("device", "host"),
                     default="device",
                     help="sharded-solver auction tier to measure "
@@ -368,14 +380,14 @@ def main(argv=None) -> int:
             return run_engine_sweep(
                 scales=scales, reps=20 if args.quick else 40)
     else:
-        from adlb_tpu.utils.jaxenv import force_cpu_devices
-
-        force_cpu_devices(args.ndev)
         scales = [SCALES[0], SCALES[2], SCALES[-1]] if args.quick else SCALES
         reps = 20 if args.quick else 40
 
         def run():
-            return run_sweep(scales=scales, reps=reps, ndev=args.ndev,
+            import jax
+
+            return run_sweep(scales=scales, reps=reps,
+                             ndev=args.ndev or len(jax.devices()),
                              auction=args.auction)
 
     if args.json_only:

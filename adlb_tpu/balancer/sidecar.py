@@ -22,6 +22,30 @@ import time
 from adlb_tpu.runtime.messages import Tag, msg
 
 
+class SidecarThread(threading.Thread):
+    """The sidecar's serve loop on a thread of the launching process —
+    which is therefore the process that owns the chip. ``facts`` holds
+    the engine's solver facts once the loop ends; ``error`` holds what
+    ended it early. :func:`stop_sidecar` hands both to the launcher."""
+
+    def __init__(self, world, cfg, ep, abort_event) -> None:
+        super().__init__(daemon=True, name="adlb-balancer-sidecar")
+        self._args = (world, cfg, ep, abort_event)
+        self.facts: dict = {}
+        self.error: BaseException | None = None
+
+    def run(self) -> None:
+        try:
+            self.facts = run_sidecar(*self._args)
+        except BaseException as e:  # noqa: BLE001 — raised by stop_sidecar
+            self.error = e
+            # tpu mode has no other cross-server matching: end the world
+            # now, not at the launcher's timeout with every worker parked
+            abort_event = self._args[3]
+            if abort_event is not None:
+                abort_event.set()
+
+
 def start_sidecar(world, cfg, abort_event=None, host: str = "127.0.0.1"):
     """Bind the sidecar's endpoint at pseudo-rank ``world.nranks`` and build
     its (not-yet-started) thread. Returns (endpoint, thread): add the
@@ -36,24 +60,24 @@ def start_sidecar(world, cfg, abort_event=None, host: str = "127.0.0.1"):
         world.nranks, {world.nranks: (host, 0)},
         binary_peers=set(world.server_ranks),
     )
-    thread = threading.Thread(
-        target=run_sidecar,
-        args=(world, cfg, ep, abort_event),
-        daemon=True,
-        name="adlb-balancer-sidecar",
-    )
-    return ep, thread
+    return ep, SidecarThread(world, cfg, ep, abort_event)
 
 
-def stop_sidecar(ep, thread, abort_event=None, timeout: float = 10.0) -> None:
-    """Join (the loop exits on the servers' DS_ENDs, or on abort_event) and
-    close the endpoint."""
+def stop_sidecar(ep, thread, abort_event=None, timeout: float = 10.0) -> dict:
+    """Join (the loop exits on the servers' DS_ENDs, or on abort_event),
+    close the endpoint, and return the solver facts — or raise the error
+    that ended the serve loop, as a server rank's would be."""
     if thread.is_alive():
         thread.join(timeout=timeout)
         if thread.is_alive() and abort_event is not None:
             abort_event.set()
             thread.join(timeout=2.0)
     ep.close()
+    if thread.error is not None:
+        raise RuntimeError(
+            f"balancer sidecar failed: {thread.error!r}"
+        ) from thread.error
+    return thread.facts
 
 
 def decode_snapshot(m) -> dict:
@@ -89,9 +113,10 @@ def decode_snapshot(m) -> dict:
     }
 
 
-def run_sidecar(world, cfg, ep, abort_event=None) -> int:
+def run_sidecar(world, cfg, ep, abort_event=None) -> dict:
     """Serve balancer rounds until every server says DS_END; returns the
-    number of planning rounds executed."""
+    engine's solver facts plus ``rounds``, the number of planning rounds
+    executed (the flight artifact carries both, error or not)."""
     from adlb_tpu.balancer.engine import PlanEngine, round_gap
     from adlb_tpu.obs.metrics import Registry, attach
 
@@ -264,17 +289,7 @@ def run_sidecar(world, cfg, ep, abort_event=None) -> int:
             if not dirty or not snapshots:
                 continue
             dirty = False
-            try:
-                matches, migrations = engine.round(snapshots, world)
-            except Exception as e:  # noqa: BLE001 — must keep serving
-                import sys
-
-                print(
-                    f"[adlb sidecar] solve failed ({e!r}); forcing host path",
-                    file=sys.stderr,
-                )
-                engine.force_host_path()
-                continue
+            matches, migrations = engine.round(snapshots, world)
             rounds += 1
             for holder, seqno, req_home, for_rank, rqseqno in matches:
                 if holder in ended:  # died earlier in this very plan loop
@@ -303,6 +318,7 @@ def run_sidecar(world, cfg, ep, abort_event=None) -> int:
         # server post-mortem cannot see into otherwise
         from adlb_tpu.obs.flight import write_artifact
 
+        solver = engine.solver_facts()
         write_artifact(
             cfg.flight_dir,
             "sidecar",
@@ -312,7 +328,8 @@ def run_sidecar(world, cfg, ep, abort_event=None) -> int:
                 "reason": "aborted" if (abort_event is not None
                                         and abort_event.is_set()) else "exit",
                 "rounds": rounds,
+                "solver": solver,
                 "metrics": metrics.snapshot(),
             },
         )
-    return rounds
+    return {**solver, "rounds": rounds}

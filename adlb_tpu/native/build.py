@@ -1,42 +1,97 @@
 """Build + load the native core.
 
-Compiles ``wqcore.cpp`` into a shared library next to the source with the
-system ``g++`` (cached by mtime), then loads it with ctypes. No
-pip/pybind11/setuptools involvement — the reference's build layer is plain
-CMake over C sources (reference ``CMakeLists.txt:44-56``); this is the same
-spirit with less machinery.
+Compiles the C/C++ sources beside this file with the system ``g++`` and
+loads them with ctypes. No pip/pybind11/setuptools involvement — the
+reference's build layer is plain CMake over C sources (reference
+``CMakeLists.txt:44-56``); this is the same spirit with less machinery.
+
+Every output lands in one git-ignored directory inside the checkout,
+``adlb_tpu/native/_build/<key>/<name>``, where ``<key>`` hashes the
+CONTENT of the sources and headers the compile reads plus the compile
+command. A checkout therefore never runs a binary built from other
+sources — not a stale one left by an older commit (mtimes say nothing
+after a ``git checkout`` or a copy), and not another checkout's (nothing
+is shared through the system temp directory). Deleting ``_build/`` is
+always safe: the next use rebuilds.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
-from typing import Optional
+from typing import Optional, Sequence
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_DIR, "wqcore.cpp")
-_LIB = os.path.join(_DIR, "libadlbwq.so")
+BUILD_DIR = os.path.join(_DIR, "_build")
+
+_build_lock = threading.Lock()
+
+
+class BuildError(RuntimeError):
+    """A native artefact could not be compiled (the compiler's stderr, or
+    the OS error, is the message)."""
+
+
+def build_artifact(name: str, cmd: Sequence[str],
+                   inputs: Sequence[str]) -> str:
+    """Return the path of ``name`` as built by ``cmd`` from ``inputs``,
+    compiling it first unless exactly this content was already built.
+
+    ``cmd`` is the compiler argv with ``"{out}"`` where the output path
+    goes; ``inputs`` lists every file whose content the result depends on
+    (sources AND the headers they include). A failed compile leaves a
+    ``<name>.err`` marker under the same key, so the dozens of ranks a
+    world spawns do not each re-pay a doomed g++ run; it clears when the
+    content (hence the key) changes."""
+    h = hashlib.sha256("\0".join(cmd).encode())
+    for path in inputs:
+        h.update(b"\0" + os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+    out_dir = os.path.join(BUILD_DIR, h.hexdigest()[:16])
+    out = os.path.join(out_dir, name)
+    with _build_lock:
+        if os.path.exists(out):
+            return out
+        errmark = out + ".err"
+        if os.path.exists(errmark):
+            with open(errmark) as f:
+                raise BuildError(
+                    f"{name} build failed previously ({errmark}):\n"
+                    f"{f.read()}")
+        # compile to a private temp name and rename into place: concurrent
+        # processes racing to build must never load a half-written file
+        tmp = f"{out}.{os.getpid()}.tmp"
+        try:
+            os.makedirs(out_dir, exist_ok=True)
+            subprocess.run(
+                [tmp if a == "{out}" else a for a in cmd],
+                check=True, capture_output=True, text=True,
+            )
+            os.replace(tmp, out)
+        except (OSError, subprocess.CalledProcessError) as e:
+            detail = (getattr(e, "stderr", "") or str(e))[:800]
+            try:
+                with open(errmark, "w") as f:
+                    f.write(detail)
+            except OSError:
+                pass
+            raise BuildError(f"{name} build failed:\n{detail}") from None
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+        return out
+
+
+_WQ_SRC = os.path.join(_DIR, "wqcore.cpp")
+_WQ_HDR = os.path.join(_DIR, "wqcore.hpp")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
-
-
-def _compile() -> None:
-    # compile to a private temp file and rename into place: concurrent
-    # processes racing to build must never dlopen a half-written .so
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
-    cmd = [
-        "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp, _SRC,
-    ]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
-        os.replace(tmp, _LIB)
-    finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -90,7 +145,7 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def ensure_built() -> Optional[ctypes.CDLL]:
-    """Build if stale and load; returns None (and records why) on failure."""
+    """Build if needed and load; returns None (and records why) on failure."""
     global _lib, _build_error
     with _lock:
         if _lib is not None:
@@ -98,16 +153,16 @@ def ensure_built() -> Optional[ctypes.CDLL]:
         if _build_error is not None:
             return None
         try:
-            if (
-                not os.path.exists(_LIB)
-                or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-            ):
-                _compile()
-            _lib = _bind(ctypes.CDLL(_LIB))
+            path = build_artifact(
+                "libadlbwq.so",
+                ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
+                 "-o", "{out}", _WQ_SRC],
+                [_WQ_SRC, _WQ_HDR],
+            )
+            _lib = _bind(ctypes.CDLL(path))
             return _lib
-        except (OSError, subprocess.CalledProcessError) as e:
-            detail = getattr(e, "stderr", "") or str(e)
-            _build_error = f"native core unavailable: {detail[:500]}"
+        except (OSError, BuildError) as e:
+            _build_error = f"native core unavailable: {str(e)[:500]}"
             return None
 
 
@@ -122,46 +177,10 @@ def build_error() -> Optional[str]:
 # ------------------------------------------------------------------ codec
 
 _CODEC_SRC = os.path.join(_DIR, "codec.cpp")
-_CODEC_LIB = os.path.join(_DIR, "libadlbcodec.so")
-_CODEC_ERRMARK = os.path.join(_DIR, "libadlbcodec.err")
-
-
-def _errmark_paths() -> list:
-    """Candidate failed-compile marker locations: the package dir, then
-    a tempdir fallback keyed on the source path — a read-only
-    site-packages must still be able to record "this compile is doomed"
-    so every spawned rank doesn't re-pay the failed g++ at import."""
-    import hashlib
-    import tempfile
-
-    h = hashlib.sha1(_CODEC_SRC.encode()).hexdigest()[:12]
-    return [
-        _CODEC_ERRMARK,
-        os.path.join(tempfile.gettempdir(), f"adlbcodec.{h}.err"),
-    ]
 
 _codec_lock = threading.Lock()
 _codec_lib = None  # the _adlbcodec module object once loaded
 _codec_error: Optional[str] = None
-
-
-def _compile_codec() -> None:
-    import sysconfig
-
-    inc = sysconfig.get_paths()["include"]
-    if not os.path.exists(os.path.join(inc, "Python.h")):
-        raise OSError(f"Python.h not found under {inc}")
-    tmp = f"{_CODEC_LIB}.{os.getpid()}.tmp"
-    cmd = [
-        "g++", "-O2", "-std=c++17", "-shared", "-fPIC", f"-I{inc}",
-        "-o", tmp, _CODEC_SRC,
-    ]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
-        os.replace(tmp, _CODEC_LIB)
-    finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
 
 
 def _bind_codec(lib: ctypes.PyDLL):
@@ -175,59 +194,31 @@ def _bind_codec(lib: ctypes.PyDLL):
 
 
 def ensure_codec():
-    """Build (if stale) and load the compiled TLV codec; returns the
+    """Build (if needed) and load the compiled TLV codec; returns the
     codec MODULE object, or None (recording why) when the toolchain or
-    headers are unavailable.
-
-    A failed compile writes a marker stamped with the source mtime so
-    every subsequently spawned rank skips the doomed g++ attempt instead
-    of paying it per process (spawn worlds fork dozens)."""
+    headers are unavailable."""
     global _codec_lib, _codec_error
     with _codec_lock:
         if _codec_lib is not None:
             return _codec_lib
         if _codec_error is not None:
             return None
-        src_mtime = os.path.getmtime(_CODEC_SRC)
+        import sysconfig
+
+        inc = sysconfig.get_paths()["include"]
         try:
-            if (
-                not os.path.exists(_CODEC_LIB)
-                or os.path.getmtime(_CODEC_LIB) < src_mtime
-            ):
-                for mark in _errmark_paths():
-                    try:
-                        with open(mark) as f:
-                            if float(f.read().split("\n", 1)[0]) \
-                                    == src_mtime:
-                                _codec_error = (
-                                    "codec build failed previously "
-                                    f"(see {mark})"
-                                )
-                                return None
-                    except (OSError, ValueError):
-                        continue
-                _compile_codec()
-                for mark in _errmark_paths():
-                    try:
-                        os.unlink(mark)
-                    except OSError:
-                        pass
-            _codec_lib = _bind_codec(ctypes.PyDLL(_CODEC_LIB))
+            if not os.path.exists(os.path.join(inc, "Python.h")):
+                raise OSError(f"Python.h not found under {inc}")
+            path = build_artifact(
+                "libadlbcodec.so",
+                ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
+                 f"-I{inc}", "-o", "{out}", _CODEC_SRC],
+                [_CODEC_SRC],
+            )
+            _codec_lib = _bind_codec(ctypes.PyDLL(path))
             return _codec_lib
-        except AttributeError as e:
-            # a stale .so predating the module-object entrypoint
-            _codec_error = f"compiled codec unavailable: {e}"
-            return None
-        except (OSError, subprocess.CalledProcessError) as e:
-            detail = getattr(e, "stderr", "") or str(e)
-            _codec_error = f"compiled codec unavailable: {detail[:500]}"
-            for mark in _errmark_paths():
-                try:
-                    with open(mark, "w") as f:
-                        f.write(f"{src_mtime}\n{_codec_error}\n")
-                    break  # first writable location wins
-                except OSError:
-                    continue
+        except (OSError, BuildError) as e:
+            _codec_error = f"compiled codec unavailable: {str(e)[:500]}"
             return None
 
 
@@ -238,43 +229,18 @@ def codec_error() -> Optional[str]:
 # ---------------------------------------------------------------- serverd
 
 _SERVERD_SRC = os.path.join(_DIR, "serverd.cpp")
-_SERVERD_HDR = os.path.join(_DIR, "wqcore.hpp")
-_SERVERD_BIN = os.path.join(_DIR, "adlb_serverd")
-
-_serverd_lock = threading.Lock()
-_serverd_error: Optional[str] = None
 
 
 def ensure_serverd() -> str:
-    """Build (if stale) and return the path of the native server daemon.
+    """Build (if needed) and return the path of the native server daemon.
 
-    Raises RuntimeError when the toolchain is unavailable — callers asked
-    for server_impl="native" explicitly, so there is no silent fallback.
+    Raises BuildError (a RuntimeError) when the toolchain is unavailable —
+    callers asked for server_impl="native" explicitly, so there is no
+    silent fallback.
     """
-    global _serverd_error
-    with _serverd_lock:
-        if _serverd_error is not None:
-            raise RuntimeError(_serverd_error)
-        src_mtime = max(
-            os.path.getmtime(_SERVERD_SRC), os.path.getmtime(_SERVERD_HDR)
-        )
-        if (
-            not os.path.exists(_SERVERD_BIN)
-            or os.path.getmtime(_SERVERD_BIN) < src_mtime
-        ):
-            tmp = f"{_SERVERD_BIN}.{os.getpid()}.tmp"
-            cmd = [
-                "g++", "-O2", "-std=c++17", "-pthread", "-o", tmp,
-                _SERVERD_SRC,
-            ]
-            try:
-                subprocess.run(cmd, check=True, capture_output=True, text=True)
-                os.replace(tmp, _SERVERD_BIN)
-            except (OSError, subprocess.CalledProcessError) as e:
-                detail = getattr(e, "stderr", "") or str(e)
-                _serverd_error = f"native server unavailable: {detail[:800]}"
-                raise RuntimeError(_serverd_error) from None
-            finally:
-                if os.path.exists(tmp):
-                    os.unlink(tmp)
-        return _SERVERD_BIN
+    return build_artifact(
+        "adlb_serverd",
+        ["g++", "-O2", "-std=c++17", "-pthread", "-o", "{out}",
+         _SERVERD_SRC],
+        [_SERVERD_SRC, _WQ_HDR],
+    )

@@ -16,60 +16,39 @@ import tempfile
 import threading
 from typing import Optional, Sequence
 
+from adlb_tpu.native.build import build_artifact
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _REPO = os.path.dirname(os.path.dirname(_DIR))
 _SRC = os.path.join(_DIR, "libadlb.cpp")
 _FSRC = os.path.join(_DIR, "adlbf.c")
-_LIB = os.path.join(_DIR, "libadlb.so")
 _INCLUDE = os.path.join(_REPO, "include")
-
-_lock = threading.Lock()
+_HDR = os.path.join(_INCLUDE, "adlb", "adlb.h")
 
 
 def build_libadlb() -> str:
-    """Compile libadlb.so (cached by mtime); returns its path."""
-    with _lock:
-        srcs = [_SRC] + ([_FSRC] if os.path.exists(_FSRC) else [])
-        deps = srcs + [os.path.join(_INCLUDE, "adlb", "adlb.h")]
-        newest = max(os.path.getmtime(s) for s in deps)
-        if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= newest:
-            return _LIB
-        tmp = f"{_LIB}.{os.getpid()}.tmp"
-        cmd = [
-            "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-            f"-I{_INCLUDE}", "-o", tmp, *srcs,
-        ]
-        try:
-            subprocess.run(cmd, check=True, capture_output=True, text=True)
-            os.replace(tmp, _LIB)
-        except subprocess.CalledProcessError as e:
-            raise RuntimeError(f"libadlb build failed:\n{e.stderr}") from e
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
-        return _LIB
-
-
-def build_example(src: str, out: Optional[str] = None) -> str:
-    """Compile a C example against libadlb; returns the binary path."""
-    build_libadlb()
-    out = out or os.path.join(
-        tempfile.gettempdir(),
-        "adlb_" + os.path.splitext(os.path.basename(src))[0],
+    """Compile libadlb.so (content-keyed, see native/build.py); returns
+    its path."""
+    return build_artifact(
+        "libadlb.so",
+        ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
+         f"-I{_INCLUDE}", "-o", "{out}", _SRC, _FSRC],
+        [_SRC, _FSRC, _HDR],
     )
-    if os.path.exists(out) and os.path.getmtime(out) >= max(
-        os.path.getmtime(src), os.path.getmtime(_LIB)
-    ):
-        return out
-    cmd = [
-        "gcc", "-O2", f"-I{_INCLUDE}", "-o", out, src,
-        f"-L{_DIR}", "-ladlb", f"-Wl,-rpath,{_DIR}", "-lm",
-    ]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
-    except subprocess.CalledProcessError as e:
-        raise RuntimeError(f"example build failed:\n{e.stderr}") from e
-    return out
+
+
+def build_example(src: str) -> str:
+    """Compile a C example against libadlb; returns the binary path. The
+    binary is keyed by its own source, the header, and the libadlb build
+    it links (whose keyed directory is its rpath) — so a checkout can
+    only ever run a client built from its own sources."""
+    libdir = os.path.dirname(build_libadlb())
+    return build_artifact(
+        os.path.splitext(os.path.basename(src))[0],
+        ["gcc", "-O2", f"-I{_INCLUDE}", "-o", "{out}", src,
+         f"-L{libdir}", "-ladlb", f"-Wl,-rpath,{libdir}", "-lm"],
+        [src, _HDR],
+    )
 
 
 def run_native_probe(
@@ -192,7 +171,11 @@ def run_native_world(
     """Python servers (threads) + native client processes (one per app rank).
 
     Returns (results: list of (returncode, stdout, stderr) per client,
-    server_stats: dict rank -> stats).
+    server_stats: dict rank -> stats). In an all-native tpu world the
+    balancer sidecar — a thread of THIS process, which therefore owns the
+    chip — reports under its pseudo-rank: ``server_stats[nranks]["solver"]``
+    holds its solver facts, the twin of the Python master's entry. A
+    sidecar that dies ends the world and is raised here.
     """
     from adlb_tpu.runtime.debug_server import DebugServer
     from adlb_tpu.runtime.server import Server
@@ -217,7 +200,7 @@ def run_native_world(
     endpoints = {}
     daemons: dict[int, subprocess.Popen] = {}
 
-    sidecar_thread = None
+    sidecar_ep = sidecar_thread = None
     if all_native:
         # all-native world: C clients + C++ server daemons. Daemons bind
         # their own ports, so the rendezvous map is completed from their
@@ -225,7 +208,6 @@ def run_native_world(
         # leak the daemons already spawned.
         from adlb_tpu.native import daemon as daemon_mod
 
-        sidecar_ep = None
         try:
             for rank in world.server_ranks:
                 daemons[rank] = daemon_mod.spawn_daemon(world, cfg, rank)
@@ -240,7 +222,6 @@ def run_native_world(
                 )
                 addr_map[world.nranks] = ("127.0.0.1", sidecar_ep.port)
                 sidecar_ep.addr_map.update(addr_map)
-                endpoints[world.nranks] = sidecar_ep
                 sidecar_thread.start()
             if use_debug_server:
                 # the watchdog stays Python even in all-native worlds;
@@ -266,7 +247,6 @@ def run_native_world(
             if sidecar_ep is not None:
                 from adlb_tpu.balancer.sidecar import stop_sidecar
 
-                endpoints.pop(world.nranks, None)
                 stop_sidecar(sidecar_ep, sidecar_thread, abort_event)
             raise
 
@@ -341,23 +321,44 @@ def run_native_world(
 
     results = []
     deadline = _time.monotonic() + timeout  # shared wall-clock bound
-    try:
-        for p in procs:
-            out, err = p.communicate(
-                timeout=max(deadline - _time.monotonic(), 0.1)
-            )
-            results.append((p.returncode, out, err))
-    except subprocess.TimeoutExpired:
-        abort_event.set()
-        for p in procs:
+
+    def kill_clients() -> None:
+        for p in procs[len(results):]:
             if p.poll() is None:
                 p.kill()
-                out, err = p.communicate()
-                results.append((-9, out, err))
+            out, err = p.communicate()
+            results.append((p.returncode, out, err))
+
+    def world_failed() -> bool:
+        # a server thread or the sidecar died: nobody is left to feed the
+        # clients, so do not wait out the timeout with every worker parked
+        return bool(errors) or (
+            sidecar_thread is not None and sidecar_thread.error is not None
+        )
+
+    try:
+        for p in procs:
+            while not world_failed():
+                try:
+                    out, err = p.communicate(timeout=0.5)
+                except subprocess.TimeoutExpired:
+                    if _time.monotonic() >= deadline:
+                        raise
+                    continue
+                results.append((p.returncode, out, err))
+                break
+        if world_failed():  # the cause is raised below
+            abort_event.set()
+            kill_clients()
+            for p in daemons.values():
+                p.kill()
+    except subprocess.TimeoutExpired:
+        abort_event.set()
+        kill_clients()
         raise TimeoutError(
             f"native world did not finish within {timeout}s; "
             f"client outputs: {results}"
-        )
+        ) from None
     finally:
         for t in threads:
             t.join(timeout=15.0)
@@ -366,7 +367,15 @@ def run_native_world(
             for t in threads:
                 t.join(timeout=5.0)
         if sidecar_thread is not None:
-            sidecar_thread.join(timeout=10.0)  # exits on servers' DS_ENDs
+            from adlb_tpu.balancer.sidecar import stop_sidecar
+
+            try:  # exits on the servers' DS_ENDs
+                server_stats[world.nranks] = {
+                    "solver": stop_sidecar(
+                        sidecar_ep, sidecar_thread, abort_event)
+                }
+            except RuntimeError as e:
+                errors.append(e)
         for ep in endpoints.values():
             ep.close()
         if daemons:

@@ -382,6 +382,12 @@ class ChannelBroker:
 
     def close(self) -> None:
         self._closed = True
+        # shutdown() first: close() alone does not wake the acceptor's
+        # blocked accept() (see TcpEndpoint.close)
+        try:
+            self._listener.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
         try:
             self._listener.close()
         except OSError:

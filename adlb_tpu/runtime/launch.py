@@ -537,8 +537,11 @@ def main(argv=None) -> int:
     # leaves every app blocked in reserve and the launcher waiting forever
     rc_final = 0
     while any(p.poll() is None for p in procs):
-        if failures:
-            for p in procs:
+        if failures or (sidecar is not None
+                        and sidecar[1].error is not None):
+            # daemons too: with the planner gone they would serve parked
+            # clients forever
+            for p in [*procs, *daemons.values()]:
                 if p.poll() is None:
                     p.terminate()
             break
@@ -558,7 +561,13 @@ def main(argv=None) -> int:
     if sidecar is not None:
         from adlb_tpu.balancer.sidecar import stop_sidecar
 
-        stop_sidecar(*sidecar)
+        try:
+            # which path planned, for the operator: a launcher has no
+            # WorldResult to carry it
+            print(f"[adlb_launch] planner: {stop_sidecar(*sidecar)}",
+                  file=sys.stderr)
+        except RuntimeError as e:
+            failures.append(str(e))
     if broker is not None:
         broker.close()
     # best-effort sweep of this world's ring segments/FIFOs: ranks that

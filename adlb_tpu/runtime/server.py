@@ -74,13 +74,17 @@ from adlb_tpu.types import (
 class _BalancerWorker(threading.Thread):
     """The balancer brain, off the reactor thread.
 
-    The solve's device round-trip (notably over a remote-TPU tunnel, where
-    dispatch is milliseconds and first compile is tens of seconds) must never
-    block the master's protocol loop, so the master only *updates snapshots*
-    and wakes this thread; the thread coalesces to the latest state, solves,
-    and sends SS_PLAN_MATCH messages itself (endpoint sends are
-    thread-safe). Plan staleness this introduces is already handled by
-    enactment-time validation.
+    The solve's device round-trip (a dispatch is milliseconds, the first
+    compile seconds) must never block the master's protocol loop, so the
+    master only *updates snapshots* and wakes this thread; the thread
+    coalesces to the latest state, solves, and sends SS_PLAN_MATCH messages
+    itself (endpoint sends are thread-safe). Plan staleness this introduces
+    is already handled by enactment-time validation.
+
+    A solver or device error ends the world: ``run`` keeps it in ``error``
+    and the reactor raises it (``Server._run_loop_inner``). In tpu mode
+    there is no other cross-server matching, so there is nothing to degrade
+    to that the caller would not mistake for the device path.
 
     Re-planning storms are suppressed by remembering when each requester/task
     was last planned: both stay ineligible until a *fresh* snapshot (stamp
@@ -92,12 +96,19 @@ class _BalancerWorker(threading.Thread):
         self.server = server
         self.wake = threading.Event()
         self.stopped = False
+        self.error: Optional[BaseException] = None
 
     def stop(self) -> None:
         self.stopped = True
         self.wake.set()
 
     def run(self) -> None:
+        try:
+            self._run()
+        except BaseException as e:  # noqa: BLE001 — re-raised by the reactor
+            self.error = e
+
+    def _run(self) -> None:
         s = self.server
         from adlb_tpu.balancer.engine import PlanEngine
 
@@ -121,8 +132,7 @@ class _BalancerWorker(threading.Thread):
             max_jobs=s.cfg.balancer_max_jobs,
             job_weights=s.cfg.job_weights,
         )
-        s._solver = engine.solver
-        s._engine = engine  # weights fan-out (set_job_weights) target
+        s._engine = engine  # finalize_stats reads its solver facts
         from adlb_tpu.obs import profile as _profile
 
         _profile.register_thread("balancer")
@@ -141,34 +151,19 @@ class _BalancerWorker(threading.Thread):
             self.wake.clear()
             if self.stopped or s.done:
                 return
-            try:
-                if prof is not None:
-                    prof.set_phase("balancer_tick")
-                gap, produced = self._one_round(engine)
-                if prof is not None:
-                    prof.set_phase("balancer_idle")
-                if gap > 0:
-                    time.sleep(gap)
-                if produced:
-                    # a plan-bearing round usually uncovers follow-on
-                    # work (the drained holder's next snapshot may lag
-                    # the insurance tick); re-arm so the next round runs
-                    # right after the rate-limit gap
-                    self.wake.set()
-            except Exception as e:  # noqa: BLE001
-                # The balancer must survive solver/backend errors — in tpu
-                # mode there is no other cross-server matching mechanism.
-                # Force the numpy host path (no accelerator involvement)
-                # and keep going.
-                import sys as _sys
-
-                print(
-                    f"[adlb balancer] solve failed ({e!r}); forcing host "
-                    f"solve path and retrying",
-                    file=_sys.stderr,
-                )
-                engine.force_host_path()
-                time.sleep(0.05)
+            if prof is not None:
+                prof.set_phase("balancer_tick")
+            gap, produced = self._one_round(engine)
+            if prof is not None:
+                prof.set_phase("balancer_idle")
+            if gap > 0:
+                time.sleep(gap)
+            if produced:
+                # a plan-bearing round usually uncovers follow-on
+                # work (the drained holder's next snapshot may lag
+                # the insurance tick); re-arm so the next round runs
+                # right after the rate-limit gap
+                self.wake.set()
 
     def _one_round(self, engine) -> tuple:
         s = self.server
@@ -575,7 +570,7 @@ class Server:
         from adlb_tpu.balancer.ledger import SnapshotStore
 
         self._snapshots: SnapshotStore = SnapshotStore()
-        self._solver = None
+        self._engine = None  # the balancer thread's PlanEngine, once built
         self._balancer: Optional[_BalancerWorker] = None
         if cfg.balancer == "tpu" and self.is_master:
             self._balancer = _BalancerWorker(self)
@@ -1053,6 +1048,10 @@ class Server:
         prof = self._prof_shared  # None when profiling is off: the
         # phase markers below cost one None check per transition then
         while not self.done:
+            if self._balancer is not None and self._balancer.error is not None:
+                raise RuntimeError(
+                    f"balancer failed: {self._balancer.error!r}"
+                ) from self._balancer.error
             if self._abort_event is not None and self._abort_event.is_set():
                 # every server dumps state on abort (the reference gives a
                 # 10 s grace for exactly this, src/adlb.c:2508-2526)
@@ -8174,4 +8173,15 @@ class Server:
         s[InfoKey.AVG_TIME_ON_RQ] = (
             self._rq_wait_sum / self._rq_wait_n if self._rq_wait_n else 0.0
         )
-        return {int(k): float(v) for k, v in s.items()}
+        out = {int(k): float(v) for k, v in s.items()}
+        if self.is_master:
+            # which path planned (balancer/engine.py solver_facts): the
+            # one non-InfoKey entry, so a caller can tell a device solve
+            # from its numpy twin without reading stderr
+            from adlb_tpu.balancer.engine import NO_PLANNER
+
+            out["solver"] = (
+                self._engine.solver_facts() if self._engine is not None
+                else dict(NO_PLANNER)
+            )
+        return out

@@ -518,10 +518,19 @@ class TcpEndpoint:
                 except OSError:
                     pass
             self._out.clear()
+        # close() alone does not wake a thread blocked in accept() on
+        # Linux: the acceptor — and, in the kernel, the listening socket
+        # with its port — would outlive the endpoint (a pytest process
+        # collected a hundred of them). shutdown() makes accept() return.
+        try:
+            self._listener.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
         try:
             self._listener.close()
         except OSError:
             pass
+        self._acceptor.join(timeout=1.0)
 
 
 def probe_free_ports(count: int, host: str = "127.0.0.1") -> list[int]:
@@ -789,6 +798,12 @@ def spawn_world(
 
     Returns :class:`adlb_tpu.api.WorldResult`. With ``start_method="spawn"``
     the ``app_fn`` must be picklable (module-level).
+
+    Who touches JAX: under ``balancer="tpu"`` the planner runs in the
+    master server's child process (Python servers) or in THIS process as
+    the sidecar thread (native servers). Raises RuntimeError when the
+    planner would live in a child and this process already holds an
+    accelerator backend.
     """
     import multiprocessing as mp
 
@@ -796,6 +811,27 @@ def spawn_world(
     from adlb_tpu.runtime.world import Config, WorldSpec
 
     cfg = cfg or Config()
+    if cfg.balancer == "tpu" and cfg.server_impl != "native":
+        # one process owns a chip. With Python servers the planner lives
+        # in the master rank's CHILD process; a parent that already
+        # brought an accelerator backend up keeps the chip, and the child
+        # would fail or hang inside its first device solve. (Native
+        # servers keep the planner HERE, as the sidecar thread. A CPU
+        # backend in the parent is tolerated — tier-1 forks such worlds —
+        # but only while the child's rounds stay on the numpy twin: a
+        # FORKED child of a process that has run JAX hangs in its first
+        # device solve on any platform; start_method="spawn" does not.)
+        from adlb_tpu.utils.jaxenv import accelerator_held
+
+        held = accelerator_held()
+        if held is not None:
+            raise RuntimeError(
+                f"spawn_world: this process has already initialized the "
+                f"{held} backend, so the master rank's child could not "
+                f"get the chip for its balancer. Start the world from a "
+                f"process that has not touched JAX, or run the planner "
+                f"in this process (run_world, or server_impl='native')."
+            )
     if cfg.server_impl == "native":
         from adlb_tpu.native.build import ensure_serverd
 
@@ -976,7 +1012,15 @@ def spawn_world(
     if sidecar_thread is not None:
         from adlb_tpu.balancer.sidecar import stop_sidecar
 
-        stop_sidecar(sidecar_ep, sidecar_thread, abort_event)
+        try:
+            # the planner's facts under its pseudo-rank, the twin of the
+            # Python master's finalize_stats()["solver"]
+            server_stats[world.nranks] = {
+                "solver": stop_sidecar(sidecar_ep, sidecar_thread,
+                                       abort_event)
+            }
+        except RuntimeError as e:
+            errors.insert(0, str(e))  # the cause, ahead of its collateral
     if broker is not None:
         broker.close()
     # every child is gone: sweep ring segments/FIFOs whose owners died

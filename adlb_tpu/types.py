@@ -59,7 +59,7 @@ class InfoKey(enum.IntEnum):
     NUM_RESERVES = 10
     NUM_RESERVES_PUT_ON_RQ = 11
     MAX_WQ_COUNT = 12
-    # beyond-reference L0 introspection (VERDICT r1 #8): the reference's
+    # beyond-reference L0 introspection: the reference's
     # /proc/self/status memory probe (src/adlb.c:3347-3369) and its
     # MPICH unexpected-message-queue depth (src/adlb.c:3645-3719), whose
     # TCP analogue is the endpoint's received-but-unhandled frame backlog

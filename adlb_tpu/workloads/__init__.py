@@ -28,7 +28,7 @@ benchmark drivers:
   scenario (no reference analogue; the BASELINE.json north-star probe)
 * :mod:`~adlb_tpu.workloads.trickle` — steady single-server work arrival
   with remote-only consumers, isolating dispatch/discovery latency (no
-  reference analogue; the steal-to-exec-latency probe of BASELINE.md)
+  reference analogue; the steal-to-exec-latency probe)
 * :mod:`~adlb_tpu.workloads.hotspot_native` /
   :mod:`~adlb_tpu.workloads.trickle_native` — the two probes above on the
   all-native plane (C clients ``examples/hotspot_c.c`` /

@@ -6,7 +6,7 @@ latency of each Reserve+Get pop in a streaming :class:`RunningStats` (the
 reference's stats.c accumulator pattern) and reports mean/stddev (gathered
 to the producer in the reference via MPI_Gather; here returned through app
 results, along with the raw latencies for driver-side percentiles).
-This is the steal-to-exec latency probe used by BASELINE.md.
+This is the steal-to-exec latency probe (``BASELINE.json`` config 2).
 """
 
 from __future__ import annotations

@@ -9,13 +9,13 @@ reference's answer is qmstat-guided RFR stealing (reference
 solve. Work is a GIL-free sleep so the in-process harness measures balancing,
 not Python compute.
 
-Reports tasks/sec and mean worker busy-fraction (1 - idle%), the BASELINE.md
-metrics.
+Reports tasks/sec and mean worker busy-fraction (1 - idle%).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import struct
 import time
 from typing import Optional
 
@@ -37,6 +37,70 @@ class HotspotResult:
     # (Reserve+Get) — the steal-to-exec quantity, measured directly;
     # 0.0 where the workload does not report it
     wait_pct: float = 0.0
+
+
+def make_app(n_tasks: int, work_time: float, fused: bool = True,
+             batch: int = 4):
+    """The hotspot app function, for any harness that runs worlds
+    (``run`` below in-process; chip_smoke.py over ``spawn_world``). Rank 0
+    puts ``n_tasks`` units, each carrying its index; every other rank
+    consumes until exhaustion and returns ``(t_start, t_last, done, busy,
+    ids)`` — ``ids`` the indices it consumed, so a caller can check that
+    every unit was delivered exactly once."""
+
+    def app(ctx):
+        if ctx.rank == 0:
+            # all tokens land on rank 0's home server
+            t_first = time.monotonic()
+            for i in range(n_tasks):
+                ctx.put(struct.pack("<i", i), TOKEN, work_prio=0)
+            return t_first, t_first, 0, 0.0, []
+        ids: list = []
+        busy = 0.0
+        t_start = time.monotonic()
+        t_last = t_start
+        while True:
+            if fused:
+                rc, got = ctx.get_work_batch([TOKEN], max_units=batch)
+            else:
+                rc, r = ctx.reserve([TOKEN])
+            if rc != ADLB_SUCCESS:
+                # makespan measured to the last completed task; the
+                # exhaustion-termination tail is excluded (it is a constant,
+                # not a balancing cost)
+                return t_start, t_last, len(ids), busy, ids
+            if fused:
+                payloads = [w.payload for w in got]
+            else:
+                rc, buf = ctx.get_reserved(r.handle)
+                payloads = [buf]
+            for payload in payloads:
+                time.sleep(work_time)  # GIL-free "compute"
+                # NOMINAL busy (see hotspot_native: wall-clock busy counts
+                # scheduler/GIL delay inside the sleep as utilization,
+                # which inverts idle% against throughput under contention)
+                busy += work_time
+                ids.append(struct.unpack("<i", payload)[0])
+                t_last = time.monotonic()
+
+    return app
+
+
+def summarize(res) -> HotspotResult:
+    """Reduce a hotspot world's WorldResult to its metrics."""
+    workers = [v for k, v in res.app_results.items() if k != 0 and v]
+    tasks = sum(w[2] for w in workers)
+    t_begin = min(v[0] for v in res.app_results.values())
+    t_end = max(w[1] for w in workers)
+    elapsed = max(t_end - t_begin, 1e-9)
+    busy = sum(w[3] / elapsed for w in workers) / len(workers) if workers else 0.0
+    return HotspotResult(
+        tasks=tasks,
+        elapsed=elapsed,
+        tasks_per_sec=tasks / elapsed,
+        busy_fraction=busy,
+        idle_pct=100.0 * (1.0 - busy),
+    )
 
 
 def run(
@@ -63,52 +127,7 @@ def run(
         put_routing="home",
         exhaust_check_interval=min(base.exhaust_check_interval, 0.2),
     )
-
-    def app(ctx):
-        if ctx.rank == 0:
-            # all tokens land on rank 0's home server
-            t_first = time.monotonic()
-            for i in range(n_tasks):
-                ctx.put(b"w", TOKEN, work_prio=0)
-            return t_first, t_first, 0, 0.0
-        done = 0
-        busy = 0.0
-        t_start = time.monotonic()
-        t_last = t_start
-        while True:
-            if fused:
-                rc, got = ctx.get_work_batch([TOKEN], max_units=batch)
-            else:
-                rc, r = ctx.reserve([TOKEN])
-            if rc != ADLB_SUCCESS:
-                # makespan measured to the last completed task; the
-                # exhaustion-termination tail is excluded (it is a constant,
-                # not a balancing cost)
-                return t_start, t_last, done, busy
-            n_units = len(got) if fused else 1
-            if not fused:
-                rc, buf = ctx.get_reserved(r.handle)
-            for _ in range(n_units):
-                time.sleep(work_time)  # GIL-free "compute"
-                # NOMINAL busy (see hotspot_native: wall-clock busy counts
-                # scheduler/GIL delay inside the sleep as utilization,
-                # which inverts idle% against throughput under contention)
-                busy += work_time
-                done += 1
-                t_last = time.monotonic()
-
-    res = run_world(num_app_ranks, nservers, [TOKEN], app, cfg=cfg,
-                    timeout=timeout)
-    workers = [v for k, v in res.app_results.items() if k != 0 and v]
-    tasks = sum(w[2] for w in workers)
-    t_begin = min(v[0] for v in res.app_results.values())
-    t_end = max(w[1] for w in workers)
-    elapsed = max(t_end - t_begin, 1e-9)
-    busy = sum(w[3] / elapsed for w in workers) / len(workers) if workers else 0.0
-    return HotspotResult(
-        tasks=tasks,
-        elapsed=elapsed,
-        tasks_per_sec=tasks / elapsed,
-        busy_fraction=busy,
-        idle_pct=100.0 * (1.0 - busy),
-    )
+    return summarize(run_world(
+        num_app_ranks, nservers, [TOKEN],
+        make_app(n_tasks, work_time, fused, batch), cfg=cfg, timeout=timeout,
+    ))

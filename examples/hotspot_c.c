@@ -80,9 +80,8 @@ int main(void) {
    * locally is paid for that locality — the quantity this scenario
    * measures.  ADLB_HOT_FETCH=batch:<k> switches to the batched fused
    * fetch (up to k local units per round trip) so the bench can state
-   * the measured single-vs-batch delta on this plane (see BASELINE.md
-   * for the cadence-interaction caveat that keeps single-unit the
-   * default). */
+   * the measured single-vs-batch delta on this plane (single-unit
+   * stays the default). */
   int batch = 0;
   const char *fetch_env = getenv("ADLB_HOT_FETCH");
   if (fetch_env && strncmp(fetch_env, "batch", 5) == 0) {

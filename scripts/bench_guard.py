@@ -2,8 +2,11 @@
 """Bench regression guard: diff a fresh BENCH record against a pinned
 baseline and FAIL on latency regressions of the guarded per-op rows.
 
-    python scripts/bench_guard.py NEW.json [--baseline BENCH_r05.json]
+    python scripts/bench_guard.py NEW.json --baseline OLD.json
                                   [--threshold 0.15]
+
+There is no default baseline: ROADMAP A1(e) re-baselines the guard on
+the first chip ledger line.
 
 Guarded rows (latencies — higher is worse):
 
@@ -245,7 +248,8 @@ def extract(detail: dict, text: str, row: str, idx: int,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("new", help="fresh BENCH record (json)")
-    ap.add_argument("--baseline", default="BENCH_r05.json")
+    ap.add_argument("--baseline", required=True,
+                    help="the record to compare against (json)")
     ap.add_argument("--threshold", type=float, default=0.15,
                     help="allowed fractional regression (0.15 = 15%%)")
     args = ap.parse_args(argv)

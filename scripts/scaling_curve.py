@@ -18,7 +18,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def main() -> None:
@@ -27,14 +26,11 @@ def main() -> None:
                     help="halve task counts (smoke test)")
     ap.add_argument("--plan-sweep", action="store_true",
                     help="run the sharded-balancer planning-latency "
-                         "sweep (8-way virtual mesh, to 1,000 servers / "
-                         "100k parked requesters) instead of the "
-                         "measured-worlds curve")
+                         "sweep (on every device JAX shows) instead of "
+                         "the measured-worlds curve")
     args = ap.parse_args()
 
     if args.plan_sweep:
-        # the sweep re-provisions JAX onto a virtual 8-device CPU mesh,
-        # so it runs before any world touches the accelerator
         from adlb_tpu.balancer import plan_bench
 
         raise SystemExit(
@@ -72,9 +68,8 @@ def main() -> None:
                     # queue runs ~2k deep and the fair-share pump needs
                     # the real total — a 512-cap snapshot understates the
                     # pool and distorts shares (measured: 16r tpu draws
-                    # sag 5-15% under K=512). solver_host_threshold high:
-                    # this sidecar has no local accelerator, so every
-                    # solve belongs on the numpy path.
+                    # sag 5-15% under K=512). solver_host_threshold as
+                    # in bench.py's native rows: ROADMAP A2 decides it.
                     c = Config(balancer="tpu", balancer_max_tasks=2048,
                                balancer_max_requesters=256,
                                solver_host_threshold=10**6)

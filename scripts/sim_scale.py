@@ -42,8 +42,8 @@ Usage: python scripts/sim_scale.py [--plan-sweep]
 
 ``--plan-sweep`` instead runs the MEASURED planning-latency sweep of the
 sharded balancer (snapshot-delta ingest -> sharded solve -> plan
-extracted) on a self-provisioned 8-way virtual mesh, to 1,000 servers /
-100k parked requesters — ROADMAP item 1's sub-10 ms target. The sweep
+extracted) on every device JAX shows (for a CPU mesh set JAX_PLATFORMS=cpu
+and XLA_FLAGS=--xla_force_host_platform_device_count=8). The sweep
 lives in :mod:`adlb_tpu.balancer.plan_bench` (also callable as
 ``python -m adlb_tpu.balancer.plan_bench``).
 """
@@ -55,7 +55,7 @@ import heapq
 import json
 
 # Measured native curve (scripts/scaling_curve.py, 2026-07-31, round 5 —
-# re-measured with the round-5 engine per the round-4 verdict item 3;
+# re-measured with the round-5 engine per the round-4 review item 3;
 # the host ran ~25% slower than the round-4 session, which the fitted
 # constants absorb): {servers: (grain_s, steal_tasks/s, tpu_tasks/s)}.
 # Single source of truth for the shared-core calibration — main() prints
@@ -381,8 +381,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--plan-sweep", action="store_true",
                     help="measured sharded-balancer planning-latency "
-                         "sweep (8-way virtual mesh) instead of the "
-                         "hotspot simulation")
+                         "sweep (on every device JAX shows) instead of "
+                         "the hotspot simulation")
     ap.add_argument("--quick", action="store_true",
                     help="with --plan-sweep: fewer reps/scales")
     args = ap.parse_args()

@@ -1,15 +1,14 @@
-"""Test configuration: force JAX onto a virtual 8-device CPU mesh.
+"""Test configuration: an 8-device virtual CPU mesh.
 
-Multi-chip TPU hardware is not available in CI; sharding correctness is
-validated on XLA's host platform with 8 virtual devices instead. The
-full env/config/backend-reset dance lives in
-``adlb_tpu.utils.jaxenv.force_cpu_devices`` (shared with
-``__graft_entry__.dryrun_multichip``'s self-provisioned subprocess).
+Multi-chip sharding is checked on XLA's host platform with 8 virtual
+devices. The platform itself comes from the test line's environment
+(``JAX_PLATFORMS=cpu``, see ROADMAP.md "Tier-1 verify"); this file only
+asks that platform for 8 devices, before any test imports JAX.
 """
 
-from adlb_tpu.utils.jaxenv import force_cpu_devices
+from adlb_tpu.utils.jaxenv import virtual_cpu_devices
 
-force_cpu_devices(8)
+virtual_cpu_devices(8)
 
 # hang diagnosis lives in pytest.ini (faulthandler_timeout): pytest's
 # built-in plugin dumps to the ORIGINAL stderr fd, surviving --capture,

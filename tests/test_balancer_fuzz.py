@@ -1,5 +1,5 @@
 """Randomized adversarial fuzz of the migration credit/ack state machine
-(round-4 verdict item 6).
+(round-4 review item 6).
 
 The phantom-credit bug class (fixed in commit 3236cc1, regression-tested
 point-wise in test_balancer.py) lives in the snapshot/credit/ack lattice

@@ -2,8 +2,8 @@
 
 The driver's multi-chip gate imports ``__graft_entry__`` and calls
 ``dryrun_multichip(8)`` directly — these tests exercise exactly that path
-so a green suite implies a green gate. Under the conftest's 8-device
-virtual CPU mesh the call proceeds in-process (no subprocess re-exec).
+so a green suite implies a green gate. It runs on the 8 virtual CPU
+devices the conftest asks for; on fewer devices it raises.
 """
 
 import sys

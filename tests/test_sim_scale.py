@@ -1,6 +1,6 @@
 """Sanity tests for the scaling simulator (scripts/sim_scale.py).
 
-The simulator backs BASELINE.md's 256-rank extrapolation, so its core
+The simulator backs the 256-rank extrapolation (ROADMAP C6), so its core
 properties need pinning: work conservation (makespan covers all tasks),
 determinism, and the structural result — per-unit pull saturates the hot
 server's reactor while the batched pump does not.
@@ -44,9 +44,8 @@ def test_shared_core_reproduces_measured_curve_both_columns():
     """The shared-core mode's whole claim is calibration: with the fitted
     constants (t_serve_shared, t_wake_per_busy, wake_busy_floor —
     re-derived by scripts/fit_sim.py against the round-5 curve per the
-    round-4 verdict item 3) it must keep reproducing BOTH columns of the
-    measured scripts/scaling_curve.py run (2026-07-31, BASELINE.md 'sim
-    vs measured') within the host's ±15-30%% draw-noise band. Worst
+    round-4 review item 3) it must keep reproducing BOTH columns of the
+    measured scripts/scaling_curve.py run (2026-07-31) within the host's ±15-30%% draw-noise band. Worst
     fitted cell is 11.1%% (tpu@32r); the pin catches parameter drift —
     including the measured 128-rank rate inversion (0.938), which the
     fit reproduces rather than smooths away."""
