@@ -34,6 +34,7 @@ import time
 from typing import Optional
 
 from adlb_tpu.balancer.jobdim import req_job, task_job
+from adlb_tpu.runtime.trace import span
 
 # Plan-age samples: for every round that produced output, the age of the
 # OLDEST snapshot the plan was computed from (seconds between that
@@ -156,6 +157,7 @@ class PlanEngine:
                 backend=backend,
                 max_jobs=self.max_jobs,
                 job_weights=self._job_weights,
+                metrics=metrics,
                 **kw,
             )
         self.max_malloc_per_server = max_malloc_per_server
@@ -302,10 +304,26 @@ class PlanEngine:
                 del self._planned_in[rank]
 
     def round(self, snapshots: dict, world=None):
-        """One planning round; returns (matches, migrations)."""
+        """One planning round; returns (matches, migrations). Its spans
+        (runtime/trace.py): ``adlb.round`` over the whole of it, gated or
+        not; ``.admit`` up to the gate; ``.plan`` for a round that passed
+        it, with ``.view``, ``adlb.solve``, ``.mark``, ``.migrations`` and
+        ``.account`` inside."""
         if not snapshots:
             return [], []
-        now = time.monotonic()
+        reg = self.metrics
+        with span("adlb.round", reg):
+            now = time.monotonic()
+            with span("adlb.round.admit", reg):
+                cross, pump_due = self._admit(snapshots, now)
+            if not cross and not pump_due:
+                return [], []  # nothing plannable: skip the task-ledger walk
+            with span("adlb.round.plan", reg):
+                return self._plan(snapshots, world, now, cross, pump_due)
+
+    def _admit(self, snapshots: dict, now: float) -> tuple:
+        """Everything up to the gate: sync the ledger, filter requesters,
+        and say whether a solve (``cross``) or a pump could plan anything."""
         self._prune_credits(snapshots, now)
         led = self._ledger
         # incremental resident-state sync (array ledger: O(changed rows),
@@ -382,8 +400,13 @@ class PlanEngine:
             imb = led.maybe_imbalanced(self, snapshots)
             pump_due = self._maybe_imbalanced(snapshots) if imb is None \
                 else imb
-        if not cross and not pump_due:
-            return [], []  # nothing plannable: skip the task-ledger walk
+        return cross, pump_due
+
+    def _plan(self, snapshots: dict, world, now: float, cross: bool,
+              pump_due: bool):
+        """A round that passed the gate: solve, mark, pump, account."""
+        reg = self.metrics
+        led = self._ledger
         if pump_due:
             self._last_pump = now
         # The solver consumes the ledger's resident arrays directly (the
@@ -394,39 +417,53 @@ class PlanEngine:
         # tuples) and for the py twin. Materialization happens BEFORE
         # the plan marks below so the pump sees the same pre-plan
         # filtered view it always did.
-        view = led.view() if getattr(self.solver, "SUPPORTS_VIEW", False) \
-            else None
-        filtered = None
-        if view is None or pump_due:
-            filtered = self._materialize(snapshots, now)
+        with span("adlb.round.view", reg):
+            view = led.view() \
+                if getattr(self.solver, "SUPPORTS_VIEW", False) else None
+            filtered = None
+            if view is None or pump_due:
+                filtered = self._materialize(snapshots, now)
+        pairs = []  # a pump-only round still considers migrations below
         if cross:
-            pairs = self.solver.solve(
-                view if view is not None else filtered, world)
-        else:
-            pairs = []  # still consider migrations below
+            with span("adlb.solve", reg):
+                pairs = self.solver.solve(
+                    view if view is not None else filtered, world)
         t_planned = time.monotonic()
         matches = []
         planned_away: dict[int, set] = {}
         matched_reqs: set = set()
-        for holder, seqno, req_home, for_rank, rqseqno in pairs:
-            planned_away.setdefault(holder, set()).add(seqno)
-            # local pairs are dropped (the data plane matches them), but
-            # their unit already sits in planned_away — the requester is
-            # spoken for either way, so withholding must skip it too
-            matched_reqs.add((req_home, for_rank, rqseqno))
-            if holder == req_home:
-                continue
-            self._planned_reqs[(req_home, for_rank, rqseqno)] = t_planned
-            self._planned_tasks[(holder, seqno)] = t_planned
-            self._rank_planned[holder] = t_planned
-            self._rank_planned[req_home] = t_planned
-            matches.append((holder, seqno, req_home, for_rank, rqseqno))
+        with span("adlb.round.mark", reg):
+            for holder, seqno, req_home, for_rank, rqseqno in pairs:
+                planned_away.setdefault(holder, set()).add(seqno)
+                # local pairs are dropped (the data plane matches them),
+                # but their unit already sits in planned_away — the
+                # requester is spoken for either way, so withholding must
+                # skip it too
+                matched_reqs.add((req_home, for_rank, rqseqno))
+                if holder == req_home:
+                    continue
+                self._planned_reqs[(req_home, for_rank, rqseqno)] = t_planned
+                self._planned_tasks[(holder, seqno)] = t_planned
+                self._rank_planned[holder] = t_planned
+                self._rank_planned[req_home] = t_planned
+                matches.append((holder, seqno, req_home, for_rank, rqseqno))
         migrations = []
         if pump_due:
-            migrations = self._plan_migrations(
-                snapshots, filtered, planned_away, t_planned, matched_reqs,
-                now=now,
-            )
+            with span("adlb.round.migrations", reg):
+                migrations = self._plan_migrations(
+                    snapshots, filtered, planned_away, t_planned,
+                    matched_reqs, now=now,
+                )
+        with span("adlb.round.account", reg):
+            self._account(snapshots, matches, migrations, now, t_planned)
+        return matches, migrations
+
+    def _account(self, snapshots: dict, matches: list, migrations: list,
+                 now: float, t_planned: float) -> None:
+        """What a planning round records of itself: the plan's age, the
+        round's duration, gauges and counters; and the bound on the plan
+        ledgers' memory."""
+        led = self._ledger
         if matches or migrations:
             involved = (
                 {h for h, *_ in matches}
@@ -496,7 +533,6 @@ class PlanEngine:
             for d in (self._planned_reqs, self._planned_tasks):
                 for k in [k for k, v in d.items() if v <= cutoff]:
                     del d[k]
-        return matches, migrations
 
     def _materialize(self, snapshots: dict, now: float) -> dict:
         """The legacy filtered-snapshot dict (exact tuple lists), built

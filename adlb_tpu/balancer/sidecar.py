@@ -119,6 +119,7 @@ def run_sidecar(world, cfg, ep, abort_event=None) -> dict:
     executed (the flight artifact carries both, error or not)."""
     from adlb_tpu.balancer.engine import PlanEngine, round_gap
     from adlb_tpu.obs.metrics import Registry, attach
+    from adlb_tpu.runtime.trace import span
 
     # the sidecar is its own process/thread: it owns its registry (round
     # duration, plan ages, pairs) and instruments its endpoint's per-tag
@@ -199,117 +200,135 @@ def run_sidecar(world, cfg, ep, abort_event=None) -> dict:
                     req_types=req_types, grew=int(grew)),
             )
 
+    def handle(m) -> bool:
+        """Merge one inbox message into the held state; True when a
+        planning round has something new to look at."""
+        dirty = False
+        if m.tag is Tag.SS_STATE:
+            # a fresh snapshot proves the server is alive: resurrect
+            # it if a transient send error wrongly marked it ended
+            # (DS_END is final — an ended-by-DS_END server never
+            # snapshots again, so this cannot resurrect those)
+            ended.discard(m.src)
+            snapshots[m.src] = decode_snapshot(m)
+            broadcast(tracker.update(m.src, snapshots[m.src]["reqs"]))
+            dirty = True
+        elif m.tag is Tag.SS_STATE_DELTA:
+            # put-event: append task(s) to the sender's last full
+            # snapshot (stamp unchanged — requester re-eligibility only
+            # comes from full snapshots; see the server's merge).
+            # Batched shape (parallel lists) since round 4; the
+            # single-unit shape is kept for older daemons.
+            snap = snapshots.get(m.src)
+            if snap is not None:
+                if m.data.get("seqnos") is not None:
+                    # "jobs" (field 106) rides only when some
+                    # unit is non-default; absent -> all job 0
+                    jbs = m.data.get("jobs") or [0] * len(m.seqnos)
+                    units = zip(m.seqnos, m.work_types, m.prios,
+                                m.work_lens, jbs)
+                else:
+                    units = [(m.seqno, m.work_type, m.prio,
+                              m.work_len, 0)]
+                for sq, wt, pr, ln, jb in units:
+                    if len(snap["tasks"]) >= cfg.balancer_max_tasks:
+                        break
+                    if jb:
+                        if not 0 <= jb < cfg.balancer_max_jobs:
+                            continue  # overflow namespace
+                        snap["tasks"].append((sq, wt, pr, ln, jb))
+                    else:
+                        snap["tasks"].append((sq, wt, pr, ln))
+                snap["nbytes"] = m.data.get("nbytes", snap["nbytes"])
+                # in-place append with no stamp bump: the delta
+                # sequence is the change signal the resident
+                # ledgers/solver fast paths key on (the server's
+                # _merge_task_delta has always bumped it; the
+                # sidecar merge was the one spot that didn't)
+                snap["delta_seq"] = snap.get("delta_seq", 0) + 1
+                snapshots.bump(m.src)  # in-place append
+                dirty = True
+        elif m.tag is Tag.DS_END:
+            ended.add(m.src)
+            snapshots.pop(m.src, None)
+            tracker.drop(m.src)
+        elif m.tag is Tag.SS_SERVER_DEAD:
+            # defensive only: TODAY this never fires — the sidecar
+            # plane drives NATIVE daemons, which Config rejects for
+            # on_server_failure="failover", and the Python-plane
+            # fan-out targets only world server ranks. Kept so a
+            # future native failover protocol that does relay the
+            # fan-out retires the dead server's snapshot/tracker
+            # state (like a DS_END) instead of planning onto it.
+            dead_srv = m.rank
+            snapshots.pop(dead_srv, None)
+            tracker.drop(dead_srv)
+            ended.add(dead_srv)
+            dirty = True
+        elif m.tag is Tag.SS_RANK_DEAD:
+            # a worker died under on_worker_failure="reclaim":
+            # retire its parked requests from every held snapshot
+            # so the next plan stops matching/migrating toward it
+            # (stale entries would only cost an UNRESERVE bounce,
+            # but the dead rank must not keep attracting work).
+            # Forward-compat: today reclaim requires python
+            # servers (whose master patches its own snapshots),
+            # so this only fires if a future native plane or an
+            # operator tool relays the death here.
+            dead = m.rank
+            for src, snap in snapshots.items():
+                kept = [r for r in snap["reqs"] if r[0] != dead]
+                if len(kept) != len(snap["reqs"]):
+                    snap["reqs"] = kept
+                    snapshots.bump(src)  # in-place patch
+                    dirty = True
+                    broadcast(tracker.update(src, kept))
+        return dirty
+
+    def ship(matches, migrations) -> None:
+        for holder, seqno, req_home, for_rank, rqseqno in matches:
+            if holder in ended:  # died earlier in this very plan loop
+                continue
+            safe_send(
+                holder,
+                msg(Tag.SS_PLAN_MATCH, me, seqno=seqno, for_rank=for_rank,
+                    req_home=req_home, rqseqno=rqseqno),
+            )
+        for src_rank, dest, seqnos, mig_id in migrations:
+            if src_rank in ended or dest in ended:
+                continue
+            safe_send(
+                src_rank,
+                msg(Tag.SS_PLAN_MIGRATE, me, dest=dest, seqnos=seqnos,
+                    mig_id=mig_id),
+            )
+
+    # the loop's spans (runtime/trace.py; the names are fixed, USERGUIDE
+    # §5): wait, ingest, engine.round's own adlb.round, ship, pace
     try:
         while ended < servers:
             if abort_event is not None and abort_event.is_set():
                 break
-            m = ep.recv(timeout=0.25)
-            while m is not None:
-                if m.tag is Tag.SS_STATE:
-                    # a fresh snapshot proves the server is alive: resurrect
-                    # it if a transient send error wrongly marked it ended
-                    # (DS_END is final — an ended-by-DS_END server never
-                    # snapshots again, so this cannot resurrect those)
-                    ended.discard(m.src)
-                    snapshots[m.src] = decode_snapshot(m)
-                    broadcast(tracker.update(m.src, snapshots[m.src]["reqs"]))
-                    dirty = True
-                elif m.tag is Tag.SS_STATE_DELTA:
-                    # put-event: append task(s) to the sender's last full
-                    # snapshot (stamp unchanged — requester re-eligibility only
-                    # comes from full snapshots; see the server's merge).
-                    # Batched shape (parallel lists) since round 4; the
-                    # single-unit shape is kept for older daemons.
-                    snap = snapshots.get(m.src)
-                    if snap is not None:
-                        if m.data.get("seqnos") is not None:
-                            # "jobs" (field 106) rides only when some
-                            # unit is non-default; absent -> all job 0
-                            jbs = m.data.get("jobs") or [0] * len(m.seqnos)
-                            units = zip(m.seqnos, m.work_types, m.prios,
-                                        m.work_lens, jbs)
-                        else:
-                            units = [(m.seqno, m.work_type, m.prio,
-                                      m.work_len, 0)]
-                        for sq, wt, pr, ln, jb in units:
-                            if len(snap["tasks"]) >= cfg.balancer_max_tasks:
-                                break
-                            if jb:
-                                if not 0 <= jb < cfg.balancer_max_jobs:
-                                    continue  # overflow namespace
-                                snap["tasks"].append((sq, wt, pr, ln, jb))
-                            else:
-                                snap["tasks"].append((sq, wt, pr, ln))
-                        snap["nbytes"] = m.data.get("nbytes", snap["nbytes"])
-                        # in-place append with no stamp bump: the delta
-                        # sequence is the change signal the resident
-                        # ledgers/solver fast paths key on (the server's
-                        # _merge_task_delta has always bumped it; the
-                        # sidecar merge was the one spot that didn't)
-                        snap["delta_seq"] = snap.get("delta_seq", 0) + 1
-                        snapshots.bump(m.src)  # in-place append
-                        dirty = True
-                elif m.tag is Tag.DS_END:
-                    ended.add(m.src)
-                    snapshots.pop(m.src, None)
-                    tracker.drop(m.src)
-                elif m.tag is Tag.SS_SERVER_DEAD:
-                    # defensive only: TODAY this never fires — the sidecar
-                    # plane drives NATIVE daemons, which Config rejects for
-                    # on_server_failure="failover", and the Python-plane
-                    # fan-out targets only world server ranks. Kept so a
-                    # future native failover protocol that does relay the
-                    # fan-out retires the dead server's snapshot/tracker
-                    # state (like a DS_END) instead of planning onto it.
-                    dead_srv = m.rank
-                    snapshots.pop(dead_srv, None)
-                    tracker.drop(dead_srv)
-                    ended.add(dead_srv)
-                    dirty = True
-                elif m.tag is Tag.SS_RANK_DEAD:
-                    # a worker died under on_worker_failure="reclaim":
-                    # retire its parked requests from every held snapshot
-                    # so the next plan stops matching/migrating toward it
-                    # (stale entries would only cost an UNRESERVE bounce,
-                    # but the dead rank must not keep attracting work).
-                    # Forward-compat: today reclaim requires python
-                    # servers (whose master patches its own snapshots),
-                    # so this only fires if a future native plane or an
-                    # operator tool relays the death here.
-                    dead = m.rank
-                    for src, snap in snapshots.items():
-                        kept = [r for r in snap["reqs"] if r[0] != dead]
-                        if len(kept) != len(snap["reqs"]):
-                            snap["reqs"] = kept
-                            snapshots.bump(src)  # in-place patch
-                            dirty = True
-                            broadcast(tracker.update(src, kept))
-                m = ep.recv(timeout=0.0)
-            broadcast(tracker.flush(time.monotonic()))
+            with span("adlb.sidecar.wait", metrics):
+                m = ep.recv(timeout=0.25)
+            with span("adlb.sidecar.ingest", metrics):
+                while m is not None:
+                    dirty |= handle(m)
+                    m = ep.recv(timeout=0.0)
+                broadcast(tracker.flush(time.monotonic()))
             if not dirty or not snapshots:
                 continue
             dirty = False
             matches, migrations = engine.round(snapshots, world)
             rounds += 1
-            for holder, seqno, req_home, for_rank, rqseqno in matches:
-                if holder in ended:  # died earlier in this very plan loop
-                    continue
-                safe_send(
-                    holder,
-                    msg(Tag.SS_PLAN_MATCH, me, seqno=seqno, for_rank=for_rank,
-                        req_home=req_home, rqseqno=rqseqno),
-                )
-            for src_rank, dest, seqnos, mig_id in migrations:
-                if src_rank in ended or dest in ended:
-                    continue
-                safe_send(
-                    src_rank,
-                    msg(Tag.SS_PLAN_MIGRATE, me, dest=dest, seqnos=seqnos,
-                        mig_id=mig_id),
-                )
+            if matches or migrations:
+                with span("adlb.sidecar.ship", metrics):
+                    ship(matches, migrations)
             if cfg.balancer_min_gap > 0:
                 # shared cadence with the in-proc _BalancerWorker
-                time.sleep(round_gap(cfg.balancer_min_gap, matches, migrations))
+                with span("adlb.sidecar.pace", metrics):
+                    time.sleep(
+                        round_gap(cfg.balancer_min_gap, matches, migrations))
     finally:
         # the registry's round/plan-age/traffic numbers become reachable
         # as a flight artifact when the world opted in — written in a

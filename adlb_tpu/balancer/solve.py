@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from adlb_tpu.balancer.jobdim import bias_vector, expand_types
+from adlb_tpu.runtime.trace import span
 
 # Sentinel far below any real priority (int32-safe; real priorities are
 # clipped to +/-1e9, reference priorities are C ints). A plain int, NOT a
@@ -174,7 +175,7 @@ class AssignmentSolver:
         rounds: int = 6,
         host_threshold_reqs: Optional[int] = DEFAULT_HOST_THRESHOLD,
         backend: str = "xla", max_jobs: int = 1,
-        job_weights: Optional[dict] = None,
+        job_weights: Optional[dict] = None, metrics=None,
     ) -> None:
         """backend: "xla" = the jitted lax.scan greedy; "pallas" = the
         VMEM-resident Pallas sweep kernel (adlb_tpu.balancer.pallas_solve),
@@ -184,7 +185,11 @@ class AssignmentSolver:
         backends produce the identical matching. "auto" is resolved lazily
         at the first device solve — probing jax.default_backend() here would
         initialize the accelerator for hosts whose every solve stays on the
-        numpy path."""
+        numpy path.
+
+        metrics: the engine's obs registry, or None; the ``adlb.solve.*``
+        spans (pack, put, call, wait, get | host, extract) observe into
+        it."""
         if backend not in ("auto", "xla", "pallas"):
             raise ValueError(f"unknown solver backend {backend!r}")
         self.base_types = tuple(types)
@@ -200,6 +205,7 @@ class AssignmentSolver:
         self.rounds = rounds
         self.host_threshold_reqs = host_threshold_reqs
         self.backend = backend
+        self.metrics = metrics
         self._device_fn = None  # lazily resolved (pallas import is deferred)
         # which program answers device solves, once one has been built:
         # "numpy" until then (every solve so far ran the host twin), then
@@ -258,15 +264,24 @@ class AssignmentSolver:
         """One device solve, read back to numpy. A failure is counted and
         re-raised: nothing below the caller turns it into a host solve."""
         t0 = time.perf_counter()
+        reg = self.metrics
         try:
-            assign = np.asarray(
-                self._device_assign()(
+            fn = self._device_assign()
+            with span("adlb.solve.put", reg):
+                args = (
                     jnp.asarray(task_prio),
                     jnp.asarray(task_type),
                     jnp.asarray(req_mask),
                     jnp.asarray(req_valid),
                 )
-            )
+            with span("adlb.solve.call", reg):
+                out = fn(*args)
+            # the read-back below would block here anyway: the wait only
+            # parts the device's time from the copy's
+            with span("adlb.solve.wait", reg):
+                out.block_until_ready()
+            with span("adlb.solve.get", reg):
+                assign = np.asarray(out)
         except Exception:
             self.device_failures += 1
             raise
@@ -297,10 +312,40 @@ class AssignmentSolver:
         """
         if getattr(snapshots, "is_array", False):
             return self._solve_view(snapshots)
+        reg = self.metrics
+        with span("adlb.solve.pack", reg):
+            packed = self._pack(snapshots)
+        if packed is None:
+            return []
+        host, task_prio, task_type, req_mask, req_valid, task_ref, req_ref \
+            = packed
+        if host:
+            with span("adlb.solve.host", reg):
+                assign = _host_greedy(
+                    task_prio, task_type, req_mask, req_valid)
+            self.host_solve_count += 1
+        else:
+            assign = self._device_solve(
+                task_prio, task_type, req_mask, req_valid)
+        self.solve_count += 1
+
+        pairs = []
+        with span("adlb.solve.extract", reg):
+            for i, t in enumerate(assign):
+                if t < 0 or req_ref[i] is None or task_ref[t] is None:
+                    continue
+                holder, seqno = task_ref[t]
+                req_home, for_rank, rqseqno = req_ref[i]
+                pairs.append((holder, seqno, req_home, for_rank, rqseqno))
+        return pairs
+
+    def _pack(self, snapshots):
+        """The dict packer: ``(host, task_prio, task_type, req_mask,
+        req_valid, task_ref, req_ref)``, or None when nothing can match."""
         servers = sorted(snapshots)
         S, K, R, T = len(servers), self.K, self.R, len(self.types)
         if S == 0:
-            return []
+            return None
         req_mask = np.zeros((S * R, T), dtype=bool)
         req_valid = np.zeros((S * R,), dtype=bool)
         req_ref: list = [None] * (S * R)
@@ -332,7 +377,7 @@ class AssignmentSolver:
                 req_ref[i] = (s, rank, rqseqno)
         n_reqs = int(req_valid.sum())
         if n_reqs == 0:
-            return []
+            return None
 
         host = self._place_on_host(n_reqs)
         if host:
@@ -358,11 +403,9 @@ class AssignmentSolver:
                     ttypes.append(ti)
                     task_ref.append((s, seqno))
             if not task_ref:
-                return []
+                return None
             task_prio = np.asarray(prios, dtype=np.int32)
             task_type = np.asarray(ttypes, dtype=np.int32)
-            assign = _host_greedy(task_prio, task_type, req_mask, req_valid)
-            self.host_solve_count += 1
         else:
             task_prio = np.full((S * K,), int(_NEG), dtype=np.int32)
             task_type = np.full((S * K,), -1, dtype=np.int32)
@@ -380,19 +423,9 @@ class AssignmentSolver:
                         wtype if J <= 1 else (jb, wtype), -1)
                     task_ref[i] = (s, seqno)
             if (task_type < 0).all():
-                return []
-            assign = self._device_solve(
-                task_prio, task_type, req_mask, req_valid)
-        self.solve_count += 1
-
-        pairs = []
-        for i, t in enumerate(assign):
-            if t < 0 or req_ref[i] is None or task_ref[t] is None:
-                continue
-            holder, seqno = task_ref[t]
-            req_home, for_rank, rqseqno = req_ref[i]
-            pairs.append((holder, seqno, req_home, for_rank, rqseqno))
-        return pairs
+                return None
+        return host, task_prio, task_type, req_mask, req_valid, task_ref, \
+            req_ref
 
     def _solve_view(self, view) -> list:
         """The array-ledger fast path: identical greedy matching over the
@@ -407,19 +440,23 @@ class AssignmentSolver:
         S = slots.size
         if S == 0:
             return []
-        req_valid = view.pk_rv[slots].reshape(-1)
-        n_reqs = int(req_valid.sum())
-        if n_reqs == 0:
-            return []
-        req_mask = view.pk_rm[slots].reshape(S * R, T)
-        task_prio = view.pk_tp[slots].reshape(-1)
-        task_type = view.pk_tt[slots].reshape(-1)
+        reg = self.metrics
+        with span("adlb.solve.pack", reg):
+            req_valid = view.pk_rv[slots].reshape(-1)
+            n_reqs = int(req_valid.sum())
+            if n_reqs == 0:
+                return []
+            req_mask = view.pk_rm[slots].reshape(S * R, T)
+            task_prio = view.pk_tp[slots].reshape(-1)
+            task_type = view.pk_tt[slots].reshape(-1)
         host = self._place_on_host(n_reqs)
         if host:
             # _host_greedy's internal wanted/live filter makes the
             # compacted pre-pack of the dict path unnecessary: same
             # candidates, same stable order, same matching
-            assign = _host_greedy(task_prio, task_type, req_mask, req_valid)
+            with span("adlb.solve.host", reg):
+                assign = _host_greedy(
+                    task_prio, task_type, req_mask, req_valid)
             self.host_solve_count += 1
             if not (assign >= 0).any():
                 return []
@@ -430,15 +467,16 @@ class AssignmentSolver:
                 task_prio, task_type, req_mask, req_valid)
         self.solve_count += 1
         pairs = []
-        slot_list = slots.tolist()
-        trefs, rrefs = view.pk_trefs, view.pk_rrefs
-        for i in np.flatnonzero(assign >= 0).tolist():
-            t = int(assign[i])
-            tref = trefs[slot_list[t // K]][t % K]
-            rref = rrefs[slot_list[i // R]][i % R]
-            if tref is None or rref is None:
-                continue
-            holder, seqno = tref
-            req_home, for_rank, rqseqno = rref
-            pairs.append((holder, seqno, req_home, for_rank, rqseqno))
+        with span("adlb.solve.extract", reg):
+            slot_list = slots.tolist()
+            trefs, rrefs = view.pk_trefs, view.pk_rrefs
+            for i in np.flatnonzero(assign >= 0).tolist():
+                t = int(assign[i])
+                tref = trefs[slot_list[t // K]][t % K]
+                rref = rrefs[slot_list[i // R]][i % R]
+                if tref is None or rref is None:
+                    continue
+                holder, seqno = tref
+                req_home, for_rank, rqseqno = rref
+                pairs.append((holder, seqno, req_home, for_rank, rqseqno))
         return pairs

@@ -160,7 +160,9 @@ def _key(name: str, labels: dict) -> tuple:
 class Registry:
     """One rank's metric store. Instruments are created on first use and
     cached by (name, labels); hot paths should hold the returned object
-    instead of re-looking it up per event."""
+    instead of re-looking it up per event. The instrument's name is
+    positional-only, so ``name=`` is free to be a label
+    (``span_s{name=...}``, runtime/trace.py)."""
 
     def __init__(self, rank: int = -1) -> None:
         self.rank = rank
@@ -172,7 +174,7 @@ class Registry:
 
     # -- get-or-create ------------------------------------------------------
 
-    def counter(self, name: str, **labels) -> Counter:
+    def counter(self, name: str, /, **labels) -> Counter:
         k = _key(name, labels)
         c = self._counters.get(k)
         if c is None:
@@ -180,7 +182,7 @@ class Registry:
                 c = self._counters.setdefault(k, Counter())
         return c
 
-    def gauge(self, name: str, **labels) -> Gauge:
+    def gauge(self, name: str, /, **labels) -> Gauge:
         k = _key(name, labels)
         g = self._gauges.get(k)
         if g is None:
@@ -191,6 +193,7 @@ class Registry:
     def histogram(
         self,
         name: str,
+        /,
         base: float = _DEF_BASE,
         mult: float = _DEF_MULT,
         nbuckets: int = _DEF_NBUCKETS,

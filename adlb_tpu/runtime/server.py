@@ -40,7 +40,7 @@ from adlb_tpu.obs.metrics import Registry, attach, quantile_of
 from adlb_tpu.runtime.debug import aprintf, self_diagnosis
 from adlb_tpu.runtime.hedge import HedgeManager, should_hedge
 from adlb_tpu.runtime.messages import Msg, Tag, msg
-from adlb_tpu.runtime.trace import PID_SERVER, Tracer
+from adlb_tpu.runtime.trace import PID_SERVER, Tracer, span
 from adlb_tpu.runtime.queues import (
     CommonStore,
     LeaseTable,
@@ -179,10 +179,7 @@ class _BalancerWorker(threading.Thread):
         # snapshot swap mid-round could silently drop a match's flag.
         # fork() carries the store's version marks so the ledger's sync
         # only touches ranks that changed since the previous round
-        if s.tracer is not None:
-            with s.tracer.span("balancer:round"):
-                matches, migrations = engine.round(snaps, s.world)
-        else:
+        with span("balancer:round", tracer=s.tracer):
             matches, migrations = engine.round(snaps, s.world)
         if matches:
             # whether each planned requester's park is a fused reserve

@@ -22,6 +22,12 @@ as ``pid=1`` ("servers"), so one merged Perfetto/chrome://tracing file
 shows both sides of every reserve as two process lanes on a shared
 clock (all ranks in one ``run_world`` share ``time.monotonic``).
 
+Every span goes through one primitive, :class:`span`: it also writes the
+span into a running ``jax.profiler`` session (the device trace's clock)
+when JAX is loaded, and observes its duration in the ``span_s{name=...}``
+histogram of an obs registry. The planner's loop, round and solve carry a
+fixed set of ``adlb.*`` spans through it (docs/USERGUIDE.md §5).
+
 Events use the Chrome trace-event format (``ph: "X"``, microsecond
 timestamps, ``tid`` = world rank) so a merged dump loads directly in
 Perfetto / chrome://tracing. :func:`merge` combines per-rank tracers;
@@ -31,8 +37,8 @@ Perfetto / chrome://tracing. :func:`merge` combines per-rank tracers;
 from __future__ import annotations
 
 import json
+import sys
 import time
-from contextlib import contextmanager
 from typing import Iterable, Optional
 
 PID_APP = 0
@@ -41,6 +47,63 @@ PID_SERVER = 1
 
 def _now_us() -> float:
     return time.monotonic() * 1e6
+
+
+class span:
+    """``with span(name, metrics, tracer):`` — one span, up to three sinks.
+
+    * If JAX is already loaded in this process, a
+      ``jax.profiler.TraceAnnotation``: inside a profiler session the span
+      lands on the host plane of the same ``.xplane.pb`` as the device
+      planes, on their clock; with no session it is one activity check.
+      This module never imports JAX itself (clients import it).
+    * ``metrics`` (an obs ``Registry``): the duration is observed in the
+      histogram ``span_s{name=<name>}``, so a flight artefact or
+      ``/metrics`` carries count, sum and buckets with no profiler at all.
+    * ``tracer`` (a :class:`Tracer`): the Chrome-trace event, with ``args``.
+    """
+
+    __slots__ = ("name", "_hist", "_tracer", "_args", "_t0", "_ann")
+
+    def __init__(self, name: str, metrics=None, tracer=None, **args) -> None:
+        self.name = name
+        self._hist = (
+            metrics.histogram("span_s", name=name)
+            if metrics is not None else None
+        )
+        self._tracer = tracer
+        self._args = args
+
+    def __enter__(self) -> "span":
+        # a jax still half-way through its import has no profiler yet
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        self._ann = None
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t0 = self._t0
+        dur = time.monotonic() - t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._hist is not None:
+            self._hist.observe(dur)
+        tr = self._tracer
+        if tr is not None:
+            tr._emit(
+                {
+                    "name": self.name,
+                    "ph": "X",
+                    "ts": t0 * 1e6,
+                    "dur": dur * 1e6,
+                    "pid": tr.pid,
+                    "tid": tr.rank,
+                    **({"args": self._args} if self._args else {}),
+                }
+            )
 
 
 class Tracer:
@@ -83,23 +146,8 @@ class Tracer:
             return
         self.events.append(ev)
 
-    @contextmanager
-    def span(self, name: str, **args):
-        t0 = _now_us()
-        try:
-            yield
-        finally:
-            self._emit(
-                {
-                    "name": name,
-                    "ph": "X",
-                    "ts": t0,
-                    "dur": _now_us() - t0,
-                    "pid": self.pid,
-                    "tid": self.rank,
-                    **({"args": args} if args else {}),
-                }
-            )
+    def span(self, name: str, **args) -> span:
+        return span(name, tracer=self, **args)
 
     def instant(self, name: str, **args) -> None:
         self._emit(
