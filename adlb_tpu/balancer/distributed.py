@@ -585,7 +585,11 @@ class DistributedAssignmentSolver:
         # when membership changes — the requester tie-break, like the
         # task gid, must follow rank order, not physical slot order
         self._row_perm: Optional[np.ndarray] = None
-        self._task_ref: list = [[None] * self.K for _ in range(self.S)]
+        # sequence numbers of the resident task rows, and how many of a
+        # row's K are filled: the (rank, seqno) pair is made only for
+        # the rows a plan returns
+        self._task_seq = np.zeros((self.S, self.K), dtype=np.int64)
+        self._task_n = np.zeros((self.S,), dtype=np.int64)
         self._req_ref: list = [None] * NR
         self._reqs_dirty = True
         self._full_reload = False
@@ -753,7 +757,7 @@ class DistributedAssignmentSolver:
             self._dropped_ranks.add(int(s))
         self._tp[si, :] = int(_NEG)
         self._tt[si, :] = -1
-        self._task_ref[si] = [None] * self.K
+        self._task_n[si] = 0
         base = si * self.R
         if self._req_valid[base:base + self.R].any():
             self._req_valid[base:base + self.R] = False
@@ -775,9 +779,7 @@ class DistributedAssignmentSolver:
         row_t = self._tt[si]
         row_p.fill(int(_NEG))
         row_t.fill(-1)
-        ref = self._task_ref[si]
-        for ki in range(self.K):
-            ref[ki] = None
+        row_s = self._task_seq[si]
         # task tuples are (seqno, type, prio, len) — a 5th (job)
         # element rides along under multi-job planning; index, don't
         # unpack. The composite index / weight bias handling is the
@@ -790,7 +792,8 @@ class DistributedAssignmentSolver:
             row_p[ki] = max(-_PRIO_CLIP, min(_PRIO_CLIP, prio)) + b
             row_t[ki] = self.type_index.get(
                 wtype if J <= 1 else (jb, wtype), -1)
-            ref[ki] = (s, seqno)
+            row_s[ki] = seqno
+        self._task_n[si] = min(len(tasks), self.K)
         self._task_cache[s] = tasks
 
     def _pack_reqs(self, s: int, reqs: tuple) -> None:
@@ -1014,7 +1017,8 @@ class DistributedAssignmentSolver:
                 continue  # freed slot, or beyond-capacity extra
             self._tp[si, :] = view.pk_tp[slot]
             self._tt[si, :] = view.pk_tt[slot]
-            self._task_ref[si] = list(view.pk_trefs[slot])
+            self._task_seq[si, :] = view.pk_ts[slot]
+            self._task_n[si] = view.pk_tn[slot]
             changed.append(si)
         for slot in np.flatnonzero(
                 view.r_gen != self._seen_rgen).tolist():
@@ -1185,11 +1189,11 @@ class DistributedAssignmentSolver:
         for g, rid in zip(gids, rids):
             rank, ki = divmod(int(g), K)
             si = self._si.get(rank)
-            tref = self._task_ref[si][ki] if si is not None else None
             rref = self._req_ref[rid]
-            if tref is None or rref is None:
+            if si is None or ki >= self._task_n[si] or rref is None:
                 continue
-            holder, seqno = tref
+            # a gid carries its holder's rank (rank * K + row)
+            holder, seqno = rank, int(self._task_seq[si, ki])
             req_home, for_rank, rqseqno = rref
             pairs.append((holder, seqno, req_home, for_rank, rqseqno))
             self._planned_servers.add(holder)

@@ -114,6 +114,8 @@ class PlanEngine:
         # solver_resweeps) without the engine owning the source counts
         self._obs_resync: dict[str, int] = {}
         self._obs_resweep: dict[str, int] = {}
+        self._obs_syncs: dict[str, int] = {}
+        self._obs_rows = 0
 
         self.solver = None
         if use_mesh:
@@ -181,17 +183,19 @@ class PlanEngine:
         # per-round filter, suppression budgets, the cross-feasibility
         # gate, the pump pre-check, the solver's packed inputs) resident
         # in numpy columns (balancer/ledger.py, host_ledger="array",
-        # default) so round admission costs O(changed rows); the
-        # pure-Python twin ("py") is the retained reference semantics,
-        # fuzz-proven identical by tests/test_ledger_parity.py. The
-        # dicts below stay the authoritative mark store either way —
-        # the array ledger's columns cache them via mutation hooks.
+        # default) so round admission is array operations over the
+        # servers that changed; the pure-Python twin ("py") is the
+        # retained reference semantics, fuzz-proven identical by
+        # tests/test_ledger_parity.py. The dicts below stay the
+        # authoritative mark store either way — the array ledger's
+        # columns cache them via mutation hooks. Both are _Marks:
+        # ordered by last write, which _expire_marks relies on.
         if host_ledger not in ("array", "py"):
             raise ValueError(f"unknown host_ledger {host_ledger!r}")
         from adlb_tpu.balancer.ledger import ArrayLedger, PyLedger, _Marks
 
-        self._planned_reqs: dict[tuple, float] = {}
-        self._planned_tasks: dict[tuple, float] = {}
+        self._planned_reqs: dict[tuple, float] = _Marks()
+        self._planned_tasks: dict[tuple, float] = _Marks()
         if host_ledger == "array":
             led = ArrayLedger(self, tuple(types), max_tasks, max_requesters,
                               max_jobs=self.max_jobs,
@@ -326,10 +330,22 @@ class PlanEngine:
         and say whether a solve (``cross``) or a pump could plan anything."""
         self._prune_credits(snapshots, now)
         led = self._ledger
-        # incremental resident-state sync (array ledger: O(changed rows),
-        # keyed on the same stamp/delta_seq/req_seq change keys the
-        # sharded solver's ingest fast path uses; py twin: no-op)
-        led.sync(snapshots, now)
+        # incremental resident-state sync (array ledger: O(rows of the
+        # servers that changed), by array operations; keyed on the same
+        # stamp/delta_seq/req_seq change keys the sharded solver's
+        # ingest fast path uses; py twin: no-op)
+        with span("adlb.round.admit.sync", self.metrics):
+            led.sync(snapshots, now)
+        if self.metrics is not None and led.is_array:
+            # task-side rebuilds by the shape their table arrived in,
+            # and the rows they read: every round, so a gated round's
+            # rebuilds are counted too
+            self._count_deltas(
+                "ledger_syncs", "input", led.syncs_by_input, self._obs_syncs)
+            d = led.rows_synced - self._obs_rows
+            if d > 0:
+                self.metrics.counter("ledger_rows_synced").inc(d)
+                self._obs_rows = led.rows_synced
         # raw-park recency, stamped with the SNAPSHOT's capture time, not
         # now: the master re-reads the same snapshot every round, and a
         # satisfied park must age out, not stay forever "recent". The
@@ -501,22 +517,14 @@ class PlanEngine:
                 self.metrics.gauge("ledger_patch_us").set(
                     round(led.last_sync_us, 1))
             # O(Δ)-steady-state monitors: full ledger rebuilds and full
-            # shard re-sweeps, labelled by why they happened. Emitted as
-            # deltas of the source dicts so the counters stay monotone.
-            for fam, src, seen in (
-                ("ledger_resyncs",
-                 getattr(led, "resync_reasons", None), self._obs_resync),
-                ("solver_resweeps",
-                 getattr(self.solver, "sweep_reasons", None),
-                 self._obs_resweep),
-            ):
-                if src:
-                    for reason, total in src.items():
-                        d = total - seen.get(reason, 0)
-                        if d > 0:
-                            self.metrics.counter(
-                                fam, reason=reason).inc(d)
-                            seen[reason] = total
+            # shard re-sweeps, labelled by why they happened.
+            self._count_deltas(
+                "ledger_resyncs", "reason",
+                getattr(led, "resync_reasons", None), self._obs_resync)
+            self._count_deltas(
+                "solver_resweeps", "reason",
+                getattr(self.solver, "sweep_reasons", None),
+                self._obs_resweep)
             if matches:
                 self.metrics.counter("balancer_pairs").inc(len(matches))
             if migrations:
@@ -526,13 +534,42 @@ class PlanEngine:
                 self.metrics.counter("balancer_migrated_units").inc(
                     sum(len(mv[2]) for mv in migrations)
                 )
-        # bound the memory of the plan ledgers (per-key deletes so the
-        # array ledger's mark hooks keep its columns coherent)
+        # bound the memory of the plan ledgers
         if len(self._planned_reqs) > 4096 or len(self._planned_tasks) > 4096:
             cutoff = t_planned - 5.0
-            for d in (self._planned_reqs, self._planned_tasks):
-                for k in [k for k, v in d.items() if v <= cutoff]:
-                    del d[k]
+            self._expire_marks(self._planned_reqs, cutoff)
+            self._expire_marks(self._planned_tasks, cutoff)
+
+    @staticmethod
+    def _expire_marks(marks: dict, cutoff: float) -> None:
+        """Delete the marks planned at or before ``cutoff``. Marks are
+        written with a non-decreasing plan time and the dict is ordered
+        by last write (``ledger._Marks``), so the expired ones are its
+        old end: stop at the first live mark, visit no other. Per-key
+        deletes, so the array ledger's mark hooks keep its columns
+        coherent. (A mark poked in with a later time than those written
+        after it shields them until it expires itself; the bound is on
+        memory, and holds.)"""
+        dead = []
+        for k, v in marks.items():
+            if v > cutoff:
+                break
+            dead.append(k)
+        for k in dead:
+            del marks[k]
+
+    def _count_deltas(self, family: str, label: str, totals, seen: dict,
+                      ) -> None:
+        """Mirror a dict of running totals onto labelled counters of the
+        registry, as the growth since the last call, so the counters
+        stay monotone without the engine owning the source counts."""
+        if not totals:
+            return
+        for key, total in totals.items():
+            d = total - seen.get(key, 0)
+            if d > 0:
+                self.metrics.counter(family, **{label: key}).inc(d)
+                seen[key] = total
 
     def _materialize(self, snapshots: dict, now: float) -> dict:
         """The legacy filtered-snapshot dict (exact tuple lists), built

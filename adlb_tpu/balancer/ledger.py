@@ -1,4 +1,4 @@
-"""Array-resident host ledger: O(changed rows) round admission.
+"""Array-resident host ledger: round admission by array operations.
 
 The plan engine's per-round admission work — the requester ledger filter
 (plan-suppression staleness checks), credit-suppression budgets, the
@@ -25,6 +25,15 @@ Per round the admission work is then a handful of vectorized column
 operations (bool masks over resident columns, [S, T] aggregate
 compares), with a full rebuild only on resync — mirroring the sharded
 solver's sweep/patch split (``LEDGER_RESYNC_INTERVAL``).
+
+The sync costs O(rows of the servers whose keys moved), not O(changed
+rows): a server whose stamp, sequence or length moved has its columns
+rebuilt whole. The task side of that rebuild is array operations from
+the snapshot's task table to the columns — a :class:`TaskTable` (what
+the sidecar decodes a native ``SS_STATE`` into) is used as the int64
+array it is; a list of tuples (Python servers, unit tests) becomes one
+with a single ``np.array`` call and takes the same path. No step of it
+walks rows in Python.
 
 Two interchangeable implementations behind one interface:
 
@@ -55,6 +64,7 @@ from __future__ import annotations
 
 import bisect
 import time
+from itertools import repeat
 from typing import Optional
 
 import numpy as np
@@ -72,7 +82,12 @@ class _Marks(dict):
     """The engine's plan-mark dicts (``_planned_reqs``/``_planned_tasks``)
     with mutation hooks, so the array ledger's resident columns stay
     coherent even when a test (or future code) pokes the dict directly.
-    Only the mutators the engine and tests actually use are hooked."""
+    Only the mutators the engine and tests actually use are hooked.
+
+    A key that is set again moves to the end, so the dict's order is the
+    order of the last write: the engine stamps marks with a
+    non-decreasing plan time, and bounds the ledgers by expiring from
+    the old end up to the first live mark (``PlanEngine._account``)."""
 
     __slots__ = ("_on_set", "_on_del")
 
@@ -82,6 +97,7 @@ class _Marks(dict):
         self._on_del = on_del
 
     def __setitem__(self, key, value):
+        dict.pop(self, key, None)
         dict.__setitem__(self, key, value)
         if self._on_set is not None:
             self._on_set(key, value)
@@ -188,6 +204,91 @@ class SnapshotStore(dict):
         return f
 
 
+def _row_tuples(rows: np.ndarray) -> list:
+    """``[n, 4|5]`` int64 rows as the snapshot's task tuples (Python
+    ints). The wire rule holds: the 5th (job) element rides only when
+    the unit is outside the default namespace."""
+    if rows.shape[1] > 4:
+        return [tuple(r) if r[4] else tuple(r[:4]) for r in rows.tolist()]
+    return list(zip(*rows.T.tolist()))
+
+
+def _task_rows(tasks: list) -> np.ndarray:
+    """A list of task tuples as ``[n, 4|5]`` int64 rows, in one
+    ``np.array`` call. Only a list that mixes 4- and 5-wide tuples (a
+    multi-job world on the Python plane) or holds a priority beyond
+    int64 is padded and clipped tuple by tuple first."""
+    if not tasks:
+        return np.zeros((0, 4), np.int64)
+    try:
+        return np.array(tasks, np.int64)
+    except (ValueError, OverflowError):
+        return np.array(
+            [(t[0], t[1], max(-_PRIO_CLIP, min(_PRIO_CLIP, t[2])), t[3],
+              t[4] if len(t) > 4 else 0) for t in tasks], np.int64)
+
+
+class TaskTable:
+    """A snapshot's task table held as an int64 array: ``rows`` is the
+    ``[n, 4|5]`` array the array ledger fills its columns from, and
+    indexing or iterating yields the same ``(seqno, type, prio, len[,
+    job])`` tuples of Python ints a list of tuples would hold, made on
+    demand. ``extend`` appends rows in place (amortised growth); rows
+    already handed out are never rewritten, so a ``rows`` view taken
+    earlier stays what it was."""
+
+    __slots__ = ("_buf", "_n")
+
+    def __init__(self, rows) -> None:
+        rows = np.asarray(rows, np.int64)
+        self._buf = rows.reshape(-1, 4) if rows.ndim != 2 else rows
+        self._n = self._buf.shape[0]
+
+    @property
+    def rows(self) -> np.ndarray:
+        return self._buf[:self._n]
+
+    def extend(self, rows: np.ndarray) -> None:
+        m = rows.shape[0]
+        if m == 0:
+            return
+        buf, n = self._buf, self._n
+        width = max(buf.shape[1], rows.shape[1])
+        if (
+            n + m > buf.shape[0]
+            or width != buf.shape[1]
+            or not buf.flags.writeable
+        ):
+            # a decoded frame's array is read-only and exactly full: the
+            # first append moves it into a buffer with room to grow
+            grown = np.zeros((max(2 * (n + m), 64), width), np.int64)
+            grown[:n, :buf.shape[1]] = buf[:n]
+            self._buf = buf = grown
+        buf[n:n + m, :rows.shape[1]] = rows
+        self._n = n + m
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __iter__(self):
+        return iter(_row_tuples(self.rows))
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return _row_tuples(self.rows[i])
+        return _row_tuples(self.rows[i][None, :])[0]
+
+    def __eq__(self, other) -> bool:
+        if isinstance(other, (TaskTable, list, tuple)):
+            return list(self) == list(other)
+        return NotImplemented
+
+    __hash__ = None
+
+    def __repr__(self) -> str:
+        return f"TaskTable({list(self)!r})"
+
+
 class PyLedger:
     """The pure-Python twin: the engine's pre-vectorization per-round
     filter, verbatim.  Stateless across rounds beyond the engine's own
@@ -274,8 +375,8 @@ class _Srv:
         "r_mask", "r_planned", "r_elig", "r_index", "r_dups", "r_unknown",
         "round_sup",
         # task side
-        "tasks", "t_n", "t_stamp", "t_key", "t_seq", "t_tix", "t_prio",
-        "t_planned", "t_elig", "t_index", "t_dups",
+        "tasks", "t_rows", "t_n", "t_stamp", "t_key", "t_seq", "t_tix",
+        "t_prio", "t_planned", "t_elig", "t_index", "t_dups",
     )
 
     def __init__(self, rank: int, slot: int) -> None:
@@ -296,7 +397,11 @@ class _Srv:
         self.r_dups = False
         self.r_unknown = False
         self.round_sup = _EMPTY_I8
+        # the snapshot's own tuples when it came as a list (readers get
+        # them back as they were); None when it came as an array, and
+        # tuples are then made from t_rows for the rows asked for
         self.tasks = []
+        self.t_rows = _NO_ROWS
         self.t_n = 0
         self.t_stamp = None
         self.t_key = None
@@ -305,7 +410,9 @@ class _Srv:
         self.t_prio = _EMPTY_I8
         self.t_planned = _EMPTY_F8
         self.t_elig = _EMPTY_B
-        self.t_index = {}
+        # {seqno: row} and the duplicate flag: built when a plan mark
+        # first asks for a row of this rebuild (_task_index), else None
+        self.t_index = None
         self.t_dups = False
 
 
@@ -313,6 +420,7 @@ _EMPTY_I8 = np.zeros(0, np.int64)
 _EMPTY_I4 = np.zeros(0, np.int32)
 _EMPTY_F8 = np.zeros(0, np.float64)
 _EMPTY_B = np.zeros(0, bool)
+_NO_ROWS = np.zeros((0, 4), np.int64)
 
 
 class ArrayLedger:
@@ -321,11 +429,13 @@ class ArrayLedger:
     exposure; the packed arrays ARE the resident state).
 
     Solver-facing surface (the "view"): ``servers`` (sorted live ranks),
-    ``slot_order`` (their slots), ``pk_tp``/``pk_tt``/``pk_trefs``
-    (per-slot [K] task rows, clipped int32 priorities / type indices /
-    ``(rank, seqno)`` refs), ``pk_rv``/``pk_rm``/``pk_rrefs`` (per-slot
-    [R] kept-requester rows), and per-slot generation counters
-    ``t_gen``/``r_gen`` a stateful consumer diffs against.
+    ``slot_order`` (their slots), ``pk_tp``/``pk_tt``/``pk_ts`` (per-slot
+    [K] task rows: clipped int32 priorities / type indices / int64
+    sequence numbers, ``pk_tn`` of them packed; :meth:`task_ref` makes
+    the ``(rank, seqno)`` pair of the rows a solve returns),
+    ``pk_rv``/``pk_rm``/``pk_rrefs`` (per-slot [R] kept-requester rows),
+    and per-slot generation counters ``t_gen``/``r_gen`` a stateful
+    consumer diffs against.
     """
 
     is_array = True
@@ -349,6 +459,18 @@ class ArrayLedger:
         self.tix = {t: i for i, t in enumerate(self.types)}
         self.T = max(len(self.types), 1)
         self.job_bias = bias_vector(job_weights, self.max_jobs)
+        # the task columns' type lookup, built once: the base types'
+        # values sorted, and the base index each stands for (work types
+        # are integers, the wire's i64; tix's rule for a type listed
+        # twice — the last index — is kept)
+        base_ix = {t: i for i, t in enumerate(self.base_types)}
+        self._type_vals = np.array(sorted(base_ix), np.int64)
+        self._type_ix = np.array(
+            [base_ix[t] for t in sorted(base_ix)], np.int32)
+        # the engine's task marks by rank, {rank: {seqno: plan time}},
+        # kept by the mark hooks: a rebuild reads one rank's marks and
+        # skips the lookup for a rank that has none
+        self._tmarks: dict[int, dict] = {}
         self.K = max_tasks
         self.R = max_requesters
         self._srv: dict[int, _Srv] = {}
@@ -368,6 +490,9 @@ class ArrayLedger:
         self._any_unknown_req = False
         self._unknown_n = 0
         self._parked: list = []
+        # task-side rebuilds a sync has found due: (srv, snap, task
+        # stamp, delta_seq), done together at its end
+        self._pending: list = []
         # SnapshotStore consumption state: the store lineage plus the
         # version and membership version this ledger has fully absorbed
         self._seen_ver = 0
@@ -385,6 +510,11 @@ class ArrayLedger:
         # stats surfaced by bench / CI smoke / obs gauges
         self.patch_count = 0     # incremental per-server (re)builds
         self.resync_count = 0    # full rebuilds (cold + cadence)
+        # task-side rebuilds by the shape the table arrived in, and the
+        # rows they filled columns from (the engine mirrors both onto
+        # /metrics as ledger_syncs{input=} and ledger_rows_synced)
+        self.syncs_by_input: dict = {"array": 0, "tuples": 0}
+        self.rows_synced = 0
         # why each full pass ran — "cadence" is the periodic safety
         # rebuild; store-backed rounds also classify "cold" (new store
         # lineage / first sync) and "membership" (join/drain/failover
@@ -433,12 +563,13 @@ class ArrayLedger:
             self.g_consumers = np.zeros(cap, np.int64)
             self.pk_tp = np.full((cap, K), _NEG, np.int32)
             self.pk_tt = np.full((cap, K), -1, np.int32)
+            self.pk_ts = np.zeros((cap, K), np.int64)
+            self.pk_tn = np.zeros(cap, np.int64)
             self.pk_rv = np.zeros((cap, R), bool)
             self.pk_rm = np.zeros((cap, R, T), bool)
             self.t_gen = np.zeros(cap, np.int64)
             self.r_gen = np.zeros(cap, np.int64)
             self.slot_rank = np.full(cap, -1, np.int64)
-            self.pk_trefs = [[None] * K for _ in range(cap)]
             self.pk_rrefs = [[None] * R for _ in range(cap)]
         else:
             for name, fill in (
@@ -446,14 +577,13 @@ class ArrayLedger:
                 ("g_taskcnt", 0), ("g_eligtask", 0),
                 ("g_planned_away", True), ("g_hasreqs", False),
                 ("g_consumers", 0), ("pk_tp", _NEG), ("pk_tt", -1),
-                ("pk_rv", False), ("pk_rm", False), ("t_gen", 0),
+                ("pk_ts", 0), ("pk_tn", 0), ("pk_rv", False), ("pk_rm", False), ("t_gen", 0),
                 ("r_gen", 0), ("slot_rank", -1),
             ):
                 a = getattr(self, name)
                 n = np.full((cap,) + a.shape[1:], fill, a.dtype)
                 n[:old] = a
                 setattr(self, name, n)
-            self.pk_trefs.extend([None] * self.K for _ in range(cap - old))
             self.pk_rrefs.extend([None] * self.R for _ in range(cap - old))
         self._free.extend(range(old, cap))
         self._cap = cap
@@ -462,6 +592,7 @@ class ArrayLedger:
         if not self._free:
             self._alloc(self._cap * 2)
         srv = _Srv(rank, self._free.pop())
+        srv.r_mask = np.zeros((0, self.T), bool)
         self._srv[rank] = srv
         self.slot_rank[srv.slot] = rank
         self.member_gen += 1
@@ -482,9 +613,9 @@ class ArrayLedger:
         self.g_consumers[s] = 0
         self.pk_tp[s] = _NEG
         self.pk_tt[s] = -1
+        self.pk_tn[s] = 0
         self.pk_rv[s] = False
         self.pk_rm[s] = False
-        self.pk_trefs[s] = [None] * self.K
         self.pk_rrefs[s] = [None] * self.R
         self.t_gen[s] = self._bump()
         self.r_gen[s] = self._bump()
@@ -568,6 +699,10 @@ class ArrayLedger:
                 self._seen_ver = ver
                 self._seen_member_ver = snapshots.member_ver
                 self._seen_lineage = getattr(snapshots, "lineage", None)
+        if self._pending:
+            # the task sides found changed above, rebuilt in one pass
+            self._rebuild_tasks(self._pending, now)
+            self._pending = []
         if self._order_stale:
             self.servers = sorted(self._srv)
             self._order = np.fromiter(
@@ -608,8 +743,8 @@ class ArrayLedger:
             or srv.t_key != snap.get("delta_seq", 0)
             or srv.t_n != len(snap["tasks"])
         ):
-            self._rebuild_tasks(srv, snap, tstamp,
-                                snap.get("delta_seq", 0), now)
+            self._pending.append(
+                (srv, snap, tstamp, snap.get("delta_seq", 0)))
             self.patch_count += 1
         c = snap.get("consumers", 0)
         if srv.consumers != c:
@@ -624,10 +759,14 @@ class ArrayLedger:
                       now: float) -> None:
         reqs = list(snap["reqs"])
         n = len(reqs)
-        srv.reqs = reqs
-        srv.r_n = n
         srv.r_stamp = stamp
         srv.r_key = rseq
+        if n == 0 and srv.r_n == 0:
+            # nobody parked here before or now: the empty columns, the
+            # aggregates and the packed rows stand as they are
+            return
+        srv.reqs = reqs
+        srv.r_n = n
         if n:
             # raw-park recency feed for the engine's _last_parked (the
             # pump's window-growth signal): a rank's park stamp can only
@@ -694,53 +833,126 @@ class ArrayLedger:
         self._req_aggregate(srv)
         self._pack_reqs(srv)
 
-    def _rebuild_tasks(self, srv: _Srv, snap: dict, tstamp, tseq,
-                       now: float) -> None:
-        tasks = list(snap["tasks"])
-        n = len(tasks)
-        srv.tasks = tasks
-        srv.t_n = n
-        srv.t_stamp = tstamp
-        srv.t_key = tseq
-        tix = self.tix
-        planned = self.engine._planned_tasks
-        rank = srv.rank
-        t_seq = np.empty(n, np.int64)
-        t_tix = np.empty(n, np.int32)
-        t_prio = np.empty(n, np.int64)
-        t_planned = np.empty(n, np.float64)
-        index: dict = {}
-        dups = False
+    def _rebuild_tasks(self, pending: list, now: float) -> None:
+        """Fill the task columns of every server of ``pending`` (``(srv,
+        snap, task stamp, delta_seq)`` of this sync) from its snapshot's
+        table, all of them in one pass of array operations: the cost is
+        the rows', not a fixed price a server. A table that is already
+        an array (:class:`TaskTable`) is used as it is; a list of tuples
+        becomes one first (``_task_rows``). The servers' columns are
+        slices of the pass's arrays."""
+        m = len(pending)
+        tables = []
+        for srv, snap, tstamp, tseq in pending:
+            tasks = snap["tasks"]
+            rows = getattr(tasks, "rows", None)
+            if rows is None:
+                tasks = list(tasks)
+                rows = _task_rows(tasks)
+                self.syncs_by_input["tuples"] += 1
+            else:
+                tasks = None
+                self.syncs_by_input["array"] += 1
+            srv.tasks = tasks
+            srv.t_rows = rows
+            srv.t_n = rows.shape[0]
+            srv.t_stamp = tstamp
+            srv.t_key = tseq
+            srv.t_index = None
+            srv.t_dups = False
+            tables.append(rows)
+        counts = np.array([t.shape[0] for t in tables], np.int64)
+        ends = np.cumsum(counts)
+        N = int(ends[-1])
+        self.rows_synced += N
         J = self.max_jobs
-        bias = self.job_bias
-        nb = len(bias)
-        for i, t in enumerate(tasks):
-            sq = t[0]
-            t_seq[i] = sq
-            jb = (t[4] if len(t) > 4 else 0) if J > 1 else 0
-            t_tix[i] = tix.get(t[1] if J <= 1 else (jb, t[1]), -1)
-            # weight bias folds into the clipped prio at pack time —
-            # identically in every packer twin (jobdim.weight_bias
-            # keeps the sum int32-safe and above the _NEG sentinel)
-            b = bias[jb] if 0 <= jb < nb else 0
-            t_prio[i] = max(-_PRIO_CLIP, min(_PRIO_CLIP, t[2])) + b
-            if sq in index:
-                dups = True
-            index[sq] = i
-            t_planned[i] = planned.get((rank, sq), -1.0)
-        srv.t_seq, srv.t_tix, srv.t_prio = t_seq, t_tix, t_prio
-        srv.t_planned = t_planned
-        srv.t_index = index
-        srv.t_dups = dups
-        srv.t_elig = t_planned < (now if tstamp is None else tstamp)
-        s = srv.slot
-        self.g_taskcnt[s] = n
-        known = t_tix[t_tix >= 0]
-        self.g_sup[s] = np.bincount(known, minlength=self.T) if known.size \
-            else 0
-        self.g_eligtask[s] = int(srv.t_elig.sum())
-        self.g_planned_away[s] = self._task_away(srv)
-        self._pack_tasks(srv)
+        jobs = J > 1 and any(t.shape[1] > 4 for t in tables)
+        rows = np.zeros((N, 5 if jobs else 4), np.int64)
+        for t, b in zip(tables, ends.tolist()):
+            w = min(t.shape[1], rows.shape[1])
+            rows[b - t.shape[0]:b, :w] = t[:, :w]
+        # composite type index and weight bias, identically in every
+        # packer twin (solve.py's dict packer, distributed._pack_tasks):
+        # the job column selects the (job, type) slot and the bias;
+        # unknown types and overflow jobs pack as -1, never matched
+        # (jobdim.weight_bias keeps prio + bias int32-safe and above the
+        # _NEG sentinel)
+        seq = np.ascontiguousarray(rows[:, 0])
+        wt = rows[:, 1]
+        vals = self._type_vals
+        if vals.size:
+            pos = np.minimum(np.searchsorted(vals, wt), vals.size - 1)
+            tix = np.where(vals[pos] == wt, self._type_ix[pos],
+                           np.int32(-1))
+        else:
+            tix = np.full(N, -1, np.int32)
+        if jobs:
+            jb = rows[:, 4]
+            planned_job = (jb >= 0) & (jb < J)
+            tix = np.where(
+                planned_job & (tix >= 0), jb * self.base_T + tix, -1
+            ).astype(np.int32)
+            bias = np.where(
+                planned_job,
+                np.array(self.job_bias, np.int64)[np.where(planned_job, jb, 0)],
+                0)
+        else:
+            bias = self.job_bias[0]
+        prio = np.minimum(np.maximum(rows[:, 2], -_PRIO_CLIP), _PRIO_CLIP)
+        prio += bias
+        planned = np.full(N, -1.0)
+        seg = np.repeat(np.arange(m), counts)
+        refs = np.empty(m)
+        slots = np.empty(m, np.int64)
+        ends = ends.tolist()
+        for i, (b, (srv, _snap, tstamp, _tseq)) in enumerate(
+                zip(ends, pending)):
+            a = b - srv.t_n
+            srv.t_seq = seq[a:b]
+            srv.t_tix = tix[a:b]
+            srv.t_prio = prio[a:b]
+            srv.t_planned = planned[a:b]
+            if srv.rank in self._tmarks:
+                planned[a:b] = self._planned_column(srv)
+            slots[i] = srv.slot
+            refs[i] = now if tstamp is None else tstamp
+        elig = planned < refs[seg]
+        n_elig = np.bincount(seg[elig], minlength=m)
+        known = tix >= 0
+        T = self.T
+        self.g_taskcnt[slots] = counts
+        self.g_sup[slots] = np.bincount(
+            seg[known] * T + tix[known], minlength=m * T).reshape(m, T)
+        self.g_eligtask[slots] = n_elig
+        # every listed task marked at or after the task view: for a
+        # stamped server that is "none eligible" (the same compare)
+        self.g_planned_away[slots] = n_elig == 0
+        for b, (srv, _snap, tstamp, _tseq) in zip(ends, pending):
+            srv.t_elig = elig[b - srv.t_n:b]
+            if tstamp is None:
+                self.g_planned_away[srv.slot] = self._task_away(srv)
+        self._pack_tasks([p[0] for p in pending])
+
+    def _planned_column(self, srv: _Srv) -> np.ndarray:
+        """``t_planned``: -1 everywhere, and the plan time at the rows
+        this rank has marks for (one C-level pass of dict probes over
+        the sequence column, only for a rank that has marks at all)."""
+        marks = self._tmarks.get(srv.rank)
+        if not marks or not srv.t_n:
+            return np.full(srv.t_n, -1.0)
+        return np.fromiter(
+            map(marks.get, srv.t_seq.tolist(), repeat(-1.0)),
+            np.float64, srv.t_n)
+
+    def _task_index(self, srv: _Srv) -> dict:
+        """``{seqno: row}`` of the resident rows, and with it whether a
+        sequence number is listed twice."""
+        index = srv.t_index
+        if index is None:
+            index = srv.t_index = dict(
+                zip(srv.t_seq.tolist(), range(srv.t_n)))
+            srv.t_dups = len(index) != srv.t_n
+        return index
 
     def _task_away(self, srv: _Srv) -> bool:
         """Twin of ``PlanEngine._only_planned_away``: tstamp defaults to
@@ -783,24 +995,39 @@ class ArrayLedger:
             self._stale_rq.add(srv.rank)
 
     def on_task_mark(self, key, value=None) -> None:
-        srv = self._srv.get(key[0])
+        rank, sq = key
+        v = value  # None: the mark was deleted
+        marks = self._tmarks.get(rank)
+        if v is not None:
+            if marks is None:
+                marks = self._tmarks[rank] = {}
+            marks[sq] = v
+        else:
+            v = -1.0
+            if marks is not None and marks.pop(sq, None) is not None \
+                    and not marks:
+                del self._tmarks[rank]
+        srv = self._srv.get(rank)
         if srv is None:
             return
+        row = self._task_index(srv).get(sq)
         if srv.t_dups:
             self._recompute_task_planned(srv)
             return
-        row = srv.t_index.get(key[1])
         if row is None:
             return
-        v = self.engine._planned_tasks.get(key, -1.0)
         srv.t_planned[row] = v
         tstamp = srv.t_stamp
-        elig = True if tstamp is None else bool(v < tstamp)
-        if elig != bool(srv.t_elig[row]):
+        s = srv.slot
+        elig = True if tstamp is None else v < tstamp
+        if elig != srv.t_elig[row]:
             srv.t_elig[row] = elig
-            self.g_eligtask[srv.slot] = int(srv.t_elig.sum())
-            self._stale_tk.add(srv.rank)
-        self.g_planned_away[srv.slot] = self._task_away(srv)
+            self.g_eligtask[s] += 1 if elig else -1
+            self._stale_tk.add(rank)
+        # planned away: for a stamped server "none eligible" (the same
+        # compare, see _rebuild_tasks)
+        self.g_planned_away[s] = self._task_away(srv) if tstamp is None \
+            else self.g_eligtask[s] == 0
 
     def _recompute_req_planned(self, srv: _Srv) -> None:
         planned = self.engine._planned_reqs
@@ -816,16 +1043,14 @@ class ArrayLedger:
         self._stale_rq.add(rank)
 
     def _recompute_task_planned(self, srv: _Srv) -> None:
-        planned = self.engine._planned_tasks
         rank = srv.rank
-        for i, t in enumerate(srv.tasks):
-            srv.t_planned[i] = planned.get((rank, t[0]), -1.0)
+        srv.t_planned = self._planned_column(srv)
         tstamp = srv.t_stamp
         srv.t_elig = (
             np.ones(srv.t_n, bool) if tstamp is None
             else srv.t_planned < tstamp
         )
-        self.g_eligtask[srv.slot] = int(srv.t_elig.sum())
+        self.g_eligtask[srv.slot] = np.count_nonzero(srv.t_elig)
         self.g_planned_away[srv.slot] = self._task_away(srv)
         self._stale_tk.add(rank)
 
@@ -970,27 +1195,51 @@ class ArrayLedger:
 
     def elig_tasks(self, rank: int) -> list:
         srv = self._srv[rank]
+        idx = np.flatnonzero(srv.t_elig)
         tasks = srv.tasks
-        return [tasks[i] for i in np.flatnonzero(srv.t_elig).tolist()]
+        if tasks is None:
+            # an array-shaped table: only the rows asked for become tuples
+            return _row_tuples(srv.t_rows[idx])
+        return [tasks[i] for i in idx.tolist()]
 
     # -- solver view -------------------------------------------------------
 
-    def _pack_tasks(self, srv: _Srv) -> None:
-        s = srv.slot
+    def _pack_tasks(self, srvs: list) -> None:
+        """Pack the first K eligible task rows of each of ``srvs`` into
+        its slot of the view, all in one pass."""
+        m = len(srvs)
         K = self.K
-        kidx = np.flatnonzero(srv.t_elig)[:K]
-        k = kidx.size
-        self.pk_tp[s, :] = _NEG
-        self.pk_tt[s, :] = -1
-        if k:
-            self.pk_tp[s, :k] = srv.t_prio[kidx]
-            self.pk_tt[s, :k] = srv.t_tix[kidx]
-        refs = self.pk_trefs[s]
-        rank = srv.rank
-        seqs = srv.t_seq
-        for i in range(K):
-            refs[i] = (rank, int(seqs[kidx[i]])) if i < k else None
-        self.t_gen[s] = self._bump()
+        slots = np.array([srv.slot for srv in srvs], np.int64)
+        counts = np.array([srv.t_n for srv in srvs], np.int64)
+        one = m == 1
+        rows = np.flatnonzero(
+            srvs[0].t_elig if one
+            else np.concatenate([srv.t_elig for srv in srvs]))
+        seg = np.repeat(np.arange(m), counts)[rows]
+        n_elig = np.bincount(seg, minlength=m)
+        # a row's place among its server's eligible rows
+        pos = np.arange(rows.size) - (np.cumsum(n_elig) - n_elig)[seg]
+        if rows.size and int(n_elig.max()) > K:
+            keep = pos < K
+            rows, seg, pos = rows[keep], seg[keep], pos[keep]
+        dst = slots[seg]
+        self.pk_tp[slots] = _NEG
+        self.pk_tt[slots] = -1
+        for packed, name in ((self.pk_tp, "t_prio"), (self.pk_tt, "t_tix"),
+                             (self.pk_ts, "t_seq")):
+            col = getattr(srvs[0], name) if one else np.concatenate(
+                [getattr(srv, name) for srv in srvs])
+            packed[dst, pos] = col[rows]
+        self.pk_tn[slots] = np.minimum(n_elig, K)
+        self.t_gen[slots] = np.arange(self._gen + 1, self._gen + 1 + m)
+        self._gen += m
+
+    def task_ref(self, slot: int, ki: int) -> Optional[tuple]:
+        """``(rank, seqno)`` of packed task row ``ki`` of ``slot``, None
+        past the rows packed: made for the rows a solve returns only."""
+        if ki >= self.pk_tn[slot]:
+            return None
+        return int(self.slot_rank[slot]), int(self.pk_ts[slot, ki])
 
     def _pack_reqs(self, srv: _Srv) -> None:
         s = srv.slot
@@ -1020,10 +1269,9 @@ class ArrayLedger:
         """Freshen the packed rows of every server whose eligibility or
         suppression changed since the last view, then hand out the
         resident arrays (self doubles as the view object)."""
-        for rank in self._stale_tk:
-            srv = self._srv.get(rank)
-            if srv is not None:
-                self._pack_tasks(srv)
+        stale = [self._srv[r] for r in self._stale_tk if r in self._srv]
+        if stale:
+            self._pack_tasks(stale)
         for rank in self._stale_rq:
             srv = self._srv.get(rank)
             if srv is not None:

@@ -19,6 +19,9 @@ from __future__ import annotations
 import threading
 import time
 
+import numpy as np
+
+from adlb_tpu.balancer.ledger import SnapshotStore, TaskTable
 from adlb_tpu.runtime.messages import Tag, msg
 
 
@@ -81,11 +84,13 @@ def stop_sidecar(ep, thread, abort_event=None, timeout: float = 10.0) -> dict:
 
 
 def decode_snapshot(m) -> dict:
-    """Unflatten a native SS_STATE frame into the engine's snapshot shape."""
-    tf = m.data.get("tasks_flat") or []
-    tasks = [
-        (tf[i], tf[i + 1], tf[i + 2], tf[i + 3]) for i in range(0, len(tf), 4)
-    ]
+    """Unflatten a native SS_STATE frame into the engine's snapshot
+    shape. The task table stays the int64 array the codec read off the
+    frame (``tasks_flat``, one of its ARRAY_FIELDS), four columns a unit:
+    the ledger fills its columns from it, and whoever indexes or
+    iterates it gets the ``(seqno, type, prio, len)`` tuples."""
+    tf = m.data.get("tasks_flat")
+    tasks = TaskTable(() if tf is None else tf)
     rf = m.data.get("reqs_flat") or []
     reqs = []
     i = 0
@@ -111,6 +116,34 @@ def decode_snapshot(m) -> dict:
             if (ma := m.data.get("mig_acks")) is not None else None
         ),
     }
+
+
+def merge_delta(snap: dict, m, max_tasks: int, max_jobs: int) -> None:
+    """Append the units of an SS_STATE_DELTA frame to the sender's last
+    full snapshot, as rows of its task table, up to ``max_tasks`` rows
+    (``balancer_max_tasks``). Batched shape (parallel lists) since round
+    4; the single-unit shape is kept for older daemons. "jobs" (field
+    106) rides only when some unit is non-default: the rows are then five
+    wide, and a unit of an overflow namespace (beyond the planner's job
+    axis) stays off the table. The stamp is left as it is — requester
+    re-eligibility only comes from full snapshots; see the server's
+    merge — so ``delta_seq`` is the change signal the resident ledger
+    and the solvers' fast paths key on."""
+    d = m.data
+    if d.get("seqnos") is None:
+        cols = [[m.seqno], [m.work_type], [m.prio], [m.work_len]]
+    else:
+        cols = [m.seqnos, m.work_types, m.prios, m.work_lens]
+        jobs = d.get("jobs")
+        if jobs is not None and any(jobs):
+            cols.append(jobs)
+    rows = np.array(cols, np.int64).T
+    if rows.shape[1] > 4:
+        rows = rows[(rows[:, 4] >= 0) & (rows[:, 4] < max_jobs)]
+    tasks = snap["tasks"]
+    tasks.extend(rows[:max(max_tasks - len(tasks), 0)])
+    snap["nbytes"] = d.get("nbytes", snap["nbytes"])
+    snap["delta_seq"] = snap.get("delta_seq", 0) + 1
 
 
 def run_sidecar(world, cfg, ep, abort_event=None) -> dict:
@@ -154,8 +187,6 @@ def run_sidecar(world, cfg, ep, abort_event=None) -> dict:
     # touches only ranks whose snapshots changed since the last round.
     # The sidecar loop is single-threaded, so the engine reads the live
     # store (no fork needed); in-place merges below bump() it.
-    from adlb_tpu.balancer.ledger import SnapshotStore
-
     snapshots: SnapshotStore = SnapshotStore()
     ended: set[int] = set()
     servers = set(world.server_ranks)
@@ -215,38 +246,12 @@ def run_sidecar(world, cfg, ep, abort_event=None) -> dict:
             dirty = True
         elif m.tag is Tag.SS_STATE_DELTA:
             # put-event: append task(s) to the sender's last full
-            # snapshot (stamp unchanged — requester re-eligibility only
-            # comes from full snapshots; see the server's merge).
-            # Batched shape (parallel lists) since round 4; the
-            # single-unit shape is kept for older daemons.
+            # snapshot, in place
             snap = snapshots.get(m.src)
             if snap is not None:
-                if m.data.get("seqnos") is not None:
-                    # "jobs" (field 106) rides only when some
-                    # unit is non-default; absent -> all job 0
-                    jbs = m.data.get("jobs") or [0] * len(m.seqnos)
-                    units = zip(m.seqnos, m.work_types, m.prios,
-                                m.work_lens, jbs)
-                else:
-                    units = [(m.seqno, m.work_type, m.prio,
-                              m.work_len, 0)]
-                for sq, wt, pr, ln, jb in units:
-                    if len(snap["tasks"]) >= cfg.balancer_max_tasks:
-                        break
-                    if jb:
-                        if not 0 <= jb < cfg.balancer_max_jobs:
-                            continue  # overflow namespace
-                        snap["tasks"].append((sq, wt, pr, ln, jb))
-                    else:
-                        snap["tasks"].append((sq, wt, pr, ln))
-                snap["nbytes"] = m.data.get("nbytes", snap["nbytes"])
-                # in-place append with no stamp bump: the delta
-                # sequence is the change signal the resident
-                # ledgers/solver fast paths key on (the server's
-                # _merge_task_delta has always bumped it; the
-                # sidecar merge was the one spot that didn't)
-                snap["delta_seq"] = snap.get("delta_seq", 0) + 1
-                snapshots.bump(m.src)  # in-place append
+                merge_delta(snap, m, cfg.balancer_max_tasks,
+                            cfg.balancer_max_jobs)
+                snapshots.bump(m.src)
                 dirty = True
         elif m.tag is Tag.DS_END:
             ended.add(m.src)

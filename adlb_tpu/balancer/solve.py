@@ -469,10 +469,10 @@ class AssignmentSolver:
         pairs = []
         with span("adlb.solve.extract", reg):
             slot_list = slots.tolist()
-            trefs, rrefs = view.pk_trefs, view.pk_rrefs
+            rrefs = view.pk_rrefs
             for i in np.flatnonzero(assign >= 0).tolist():
                 t = int(assign[i])
-                tref = trefs[slot_list[t // K]][t % K]
+                tref = view.task_ref(slot_list[t // K], t % K)
                 rref = rrefs[slot_list[i // R]][i % R]
                 if tref is None or rref is None:
                     continue

@@ -57,6 +57,10 @@ PyObject* g_field_ids = nullptr;
 struct FieldInfo {
     PyObject* name;
     int kind;
+    // codec.py ARRAY_FIELDS: callable(bytes) -> object (owned) that a
+    // list field of this id is handed to whole, instead of becoming a
+    // list of ints; nullptr for every other field
+    PyObject* as_array;
 };
 FieldInfo g_by_fid[256];
 
@@ -328,6 +332,23 @@ struct Dec {
     }
 };
 
+// a list field of codec.py's ARRAY_FIELDS (already past fid/kind): the
+// values' bytes go to the field's callable as they stand in the frame,
+// no PyLong a value; returns new ref or NULL
+PyObject* read_array(Dec* d, PyObject* as_array) {
+    if (!d->need(2)) return nullptr;
+    uint16_t cnt = d->u16();
+    Py_ssize_t n = static_cast<Py_ssize_t>(cnt) * 8;
+    if (!d->need(n)) return nullptr;
+    PyObject* raw = PyBytes_FromStringAndSize(
+        reinterpret_cast<const char*>(d->p + d->off), n);
+    if (!raw) return nullptr;
+    d->off += n;
+    PyObject* out = PyObject_CallOneArg(as_array, raw);
+    Py_DECREF(raw);
+    return out;
+}
+
 // one field's VALUE (already past fid/kind); returns new ref or NULL
 PyObject* read_value(Dec* d, int kind) {
     switch (kind) {
@@ -438,6 +459,7 @@ int setup_tables(PyObject* fields, int inline_max) {
     if (!ids) return -1;
     for (auto& fi : g_by_fid) {
         Py_CLEAR(fi.name);
+        Py_CLEAR(fi.as_array);
         fi.kind = -1;
     }
     PyObject *key, *val;
@@ -542,7 +564,10 @@ PyObject* decode_raw(PyObject* body) {
             }
             uint8_t fid = d.u8();
             uint8_t kind = d.u8();
-            PyObject* value = read_value(&d, kind);
+            const FieldInfo& fi = g_by_fid[fid];
+            PyObject* value = (kind == K_LIST && fi.as_array != nullptr)
+                                  ? read_array(&d, fi.as_array)
+                                  : read_value(&d, kind);
             if (!value) {
                 ok = false;
                 break;
@@ -552,7 +577,6 @@ PyObject* decode_raw(PyObject* body) {
             // Python twin's exact rule (FIELD_FOR_WIRE.get, no kind
             // cross-check), kept bug-for-bug so the fuzz can hold the
             // twins identical
-            const FieldInfo& fi = g_by_fid[fid];
             if (fi.name != nullptr) {
                 ok = PyDict_SetItem(dict, fi.name, value) == 0;
             }
@@ -580,15 +604,28 @@ PyObject* decode_raw(PyObject* body) {
 
 PyObject* py_setup(PyObject*, PyObject* const* args, Py_ssize_t nargs) {
     // (fields, inline_max, wire_tag: dict Tag->int,
-    //  tag_for_wire: dict int->Tag, msg_cls)
-    if (nargs != 5) {
-        PyErr_SetString(PyExc_TypeError, "setup expects 5 arguments");
+    //  tag_for_wire: dict int->Tag, msg_cls,
+    //  array_fields: dict fid -> callable(bytes))
+    if (nargs != 6) {
+        PyErr_SetString(PyExc_TypeError, "setup expects 6 arguments");
         return nullptr;
     }
     long inline_max = PyLong_AsLong(args[1]);
     if (inline_max == -1 && PyErr_Occurred()) return nullptr;
     if (setup_tables(args[0], static_cast<int>(inline_max)) != 0)
         return nullptr;
+    {
+        PyObject *fid_o, *fn;
+        Py_ssize_t apos = 0;
+        while (PyDict_Next(args[5], &apos, &fid_o, &fn)) {
+            long fid = PyLong_AsLong(fid_o);
+            if (fid == -1 && PyErr_Occurred()) return nullptr;
+            if (fid >= 0 && fid < 256) {
+                Py_INCREF(fn);
+                g_by_fid[fid].as_array = fn;
+            }
+        }
+    }
     Py_XDECREF(g_wire_tag);
     g_wire_tag = args[2];
     Py_INCREF(g_wire_tag);
@@ -693,7 +730,8 @@ PyMethodDef codec_methods[] = {
     {"setup", reinterpret_cast<PyCFunction>(
                   reinterpret_cast<void*>(py_setup)),
      METH_FASTCALL,
-     "setup(fields, inline_max, wire_tag, tag_for_wire, msg_cls)"},
+     "setup(fields, inline_max, wire_tag, tag_for_wire, msg_cls, "
+     "array_fields)"},
     {"encode_iov", py_encode_iov, METH_O,
      "scatter-gather TLV encode of a Msg -> list of body parts"},
     {"decode", py_decode, METH_O, "TLV body -> Msg"},

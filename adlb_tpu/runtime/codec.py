@@ -382,6 +382,23 @@ FIELDS: dict[str, tuple[int, int]] = {
 }
 FIELD_FOR_WIRE = {v[0]: (k, v[1]) for k, v in FIELDS.items()}
 
+# list fields a decoder hands over as ONE int64 numpy array instead of a
+# list of ints: a native daemon's SS_STATE task table is up to 2,048 x 4
+# values, read by the balancer sidecar as an array from here to the
+# ledger's columns (balancer/sidecar.py::decode_snapshot). Every other
+# list field stays a list; encoding takes either.
+ARRAY_FIELDS = frozenset({"tasks_flat"})
+
+
+def i64_array(raw: bytes):
+    """A list field's values (little-endian i64, ``raw`` the bytes as
+    they stood in the frame) as a read-only int64 array: no per-value
+    object. numpy is loaded on the first such frame, so only a process
+    that decodes one (the sidecar) pays for the import."""
+    import numpy as np
+
+    return np.frombuffer(raw, dtype="<i8")
+
 _HDR = struct.Struct("<BHiH")  # magic, tag, src, nfields
 _I64 = struct.Struct("<q")
 _F64 = struct.Struct("<d")
@@ -504,9 +521,14 @@ def decode_binary_py(body) -> Msg:
         elif kind == _KIND_LIST:
             (cnt,) = _U16.unpack_from(body, off)
             off += 2
-            value = [
-                _I64.unpack_from(body, off + 8 * i)[0] for i in range(cnt)
-            ]
+            if off + 8 * cnt > len(body):
+                raise ValueError("truncated list field in binary frame")
+            entry = FIELD_FOR_WIRE.get(fid)
+            if entry is not None and entry[0] in ARRAY_FIELDS:
+                value = i64_array(bytes(body[off:off + 8 * cnt]))
+            else:
+                # one struct read for the whole field
+                value = list(struct.unpack_from(f"<{cnt}q", body, off))
             off += 8 * cnt
         elif kind == _KIND_F64:
             (value,) = _F64.unpack_from(body, off)
@@ -572,7 +594,8 @@ def _load_c_codec() -> bool:
         return False
     # hand the C core the live protocol tables — same objects, so the
     # twins cannot drift within a process
-    mod.setup(FIELDS, IOV_INLINE_MAX, WIRE_TAG, TAG_FOR_WIRE, Msg)
+    mod.setup(FIELDS, IOV_INLINE_MAX, WIRE_TAG, TAG_FOR_WIRE, Msg,
+              {FIELDS[name][0]: i64_array for name in ARRAY_FIELDS})
     _c_encode_iov = mod.encode_iov
     _c_decode = mod.decode
     return True

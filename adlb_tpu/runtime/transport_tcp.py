@@ -548,7 +548,12 @@ def probe_free_ports(count: int, host: str = "127.0.0.1") -> list[int]:
     counter), so concurrent worlds — distinct processes by
     construction — probe well-separated subranges instead of relying on
     lucky random draws; the bind check still skips any port someone else
-    actually holds.
+    actually holds. It binds the WILDCARD address, as the native client
+    does (``libadlb.cpp`` binds INADDR_ANY): a port that another process
+    holds on any one of the host's addresses — on a TPU VM the runtime
+    listens on 8431 of the VM's own address, inside this range — is free
+    on ``host`` alone, and the rank handed it died on bind, leaving a
+    world that could never count it parked or finalized.
     """
     import os
 
@@ -597,7 +602,7 @@ def probe_free_ports(count: int, host: str = "127.0.0.1") -> list[int]:
         s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         try:
-            s.bind((host, port))
+            s.bind(("", port))
         except OSError:
             s.close()
             continue

@@ -294,7 +294,8 @@ class Config:
     balancer_auction: str = "device"
     # host tier of the plan engine (balancer/ledger.py): "array" keeps
     # parked requesters / snapshot tasks resident in numpy columns so
-    # round admission costs O(changed rows); "py" is the pure-Python
+    # round admission is array operations over the servers that
+    # changed; "py" is the pure-Python
     # twin (exact reference semantics, fuzz-proven identical — an
     # escape hatch, not a feature switch)
     host_ledger: str = "array"

@@ -9,10 +9,12 @@ The C leg skips with a note when the toolchain cannot build the .so
 
 import random
 
+import numpy as np
 import pytest
 
 from adlb_tpu.runtime import codec as codec_mod
 from adlb_tpu.runtime.codec import (
+    ARRAY_FIELDS,
     FIELDS,
     IOV_INLINE_MAX,
     decode_binary_py,
@@ -93,6 +95,23 @@ def _flat(parts) -> bytes:
     return b"".join(bytes(p) for p in parts)
 
 
+def _same(a: Msg, b: Msg) -> bool:
+    """Msg equality that also holds for the fields a decoder hands over
+    as int64 arrays (codec.ARRAY_FIELDS): same type, dtype and values."""
+    if (a.tag, a.src, list(a.data)) != (b.tag, b.src, list(b.data)):
+        return False
+    for name, va in a.data.items():
+        vb = b.data[name]
+        if name in ARRAY_FIELDS:
+            if not (isinstance(va, np.ndarray) and isinstance(vb, np.ndarray)
+                    and va.dtype == vb.dtype == np.int64
+                    and np.array_equal(va, vb)):
+                return False
+        elif type(va) is not type(vb) or va != vb:
+            return False
+    return True
+
+
 @needs_c
 def test_parity_fuzz_roundtrip():
     """1,000 randomized frames: identical bytes out of both encoders,
@@ -106,8 +125,11 @@ def test_parity_fuzz_roundtrip():
         assert py == c, f"frame {i} ({m.tag.name}): encode bytes differ"
         d_py = decode_binary_py(c)
         d_c = codec_mod._c_decode(py)
-        assert d_py == d_c, f"frame {i} ({m.tag.name}): decode differs"
+        assert _same(d_py, d_c), f"frame {i} ({m.tag.name}): decode differs"
         assert d_py.tag is m.tag and d_py.src == m.src
+        # an array field carries the values that went in
+        for name in ARRAY_FIELDS & set(d_py.data):
+            assert d_py.data[name].tolist() == list(m.data[name])
 
 
 @needs_c
@@ -142,13 +164,19 @@ def test_parity_known_corpus():
             work_lens=[64] * 1000, nbytes=64000),
         msg(Tag.FA_LOCAL_APP_DONE, 9),
         msg(Tag.TA_INFO_GET_RESP, 6, rc=1, value=3.5),
+        # a native daemon's snapshot at its cap: the task table is
+        # handed over as one int64 array, the requesters as a list
+        msg(Tag.SS_STATE, 5, tasks_flat=list(range(-4, 4 * 2048 - 4)),
+            reqs_flat=[7, 1, -1, 8, 2, 2, 1, 3], nbytes=1 << 20,
+            consumers=4, mig_acks=[5, 17]),
+        msg(Tag.SS_STATE, 5, tasks_flat=[], reqs_flat=[]),
     ]
     for m in corpus:
         assert encodable(m), m.tag
         py = _flat(encode_binary_iov_py(m))
         c = _flat(codec_mod._c_encode_iov(m))
         assert py == c, m.tag
-        assert decode_binary_py(c) == codec_mod._c_decode(py)
+        assert _same(decode_binary_py(c), codec_mod._c_decode(py))
 
 
 @needs_c

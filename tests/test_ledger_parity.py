@@ -482,3 +482,327 @@ def test_unsynced_direct_call_falls_back():
     }
     assert eng._ledger.maybe_imbalanced(eng, snaps) is None
     assert isinstance(eng._maybe_imbalanced(snaps), bool)
+
+
+# --------------------------------------------------------------------------
+# The two input shapes (PR 27): a snapshot's task table arrives either as
+# a list of tuples (Python servers, hand-built dicts) or as an int64 array
+# (``TaskTable``, what the sidecar decodes a native SS_STATE into). An
+# ArrayLedger fed either, and the PyLedger, must be indistinguishable.
+
+
+class _Clock:
+    """Stand-in for ``engine.time``: the engines of one comparison run in
+    turn, and each has to read the same instants, or their plan marks
+    (and so the ``t_planned`` columns) differ by the time a solve took."""
+
+    def __init__(self):
+        self.t = 1000.0
+
+    def monotonic(self):
+        self.t += 1e-4
+        return self.t
+
+
+def _table(tasks):
+    """The list's tuples as a TaskTable: five columns wide when some unit
+    carries a job, as the sidecar's merge makes it."""
+    from adlb_tpu.balancer.ledger import TaskTable
+
+    wide = any(len(t) > 4 for t in tasks)
+    rows = [t if len(t) > 4 else t + (0,) for t in tasks] if wide else tasks
+    return TaskTable(
+        np.array(rows, np.int64).reshape(-1, 5 if wide else 4))
+
+
+class _World:
+    """One engine's snapshots, mutated through the same calls whatever
+    shape its task tables have."""
+
+    def __init__(self, snaps, arrays: bool):
+        from adlb_tpu.balancer.ledger import SnapshotStore
+
+        self.arrays = arrays
+        snaps = copy.deepcopy(snaps)
+        if arrays:
+            # the sidecar's shape: array tables in a versioned store
+            for snap in snaps.values():
+                snap["tasks"] = _table(snap["tasks"])
+            snaps = SnapshotStore(snaps)
+        self.snaps = snaps
+
+    def tasks(self, rank):
+        return list(self.snaps[rank]["tasks"])
+
+    def set_tasks(self, rank, tasks):
+        self.snaps[rank]["tasks"] = _table(tasks) if self.arrays \
+            else list(tasks)
+        _bump(self.snaps, rank)
+
+    def append(self, rank, unit):
+        snap = self.snaps[rank]
+        if self.arrays:
+            snap["tasks"].extend(np.array([unit], np.int64))
+        else:
+            snap["tasks"].append(unit)
+        snap["delta_seq"] = snap.get("delta_seq", 0) + 1
+        _bump(self.snaps, rank)
+
+    def put(self, rank, snap):
+        snap = copy.deepcopy(snap)
+        if self.arrays:
+            snap["tasks"] = _table(snap["tasks"])
+        self.snaps[rank] = snap
+
+
+UNKNOWN_TYPE = 99  # not in TYPES
+
+
+def _spice(rng, tasks, reqs, seq):
+    """Duplicates and unknown types, the rows the vector path has to
+    treat as the row walk did: a unit listed twice, a unit of a type the
+    world does not know, a requester asking for one."""
+    if tasks and rng.random() < 0.3:
+        tasks.append(tasks[int(rng.integers(0, len(tasks)))])
+    if rng.random() < 0.3:
+        seq[0] += 1
+        tasks.append((seq[0], UNKNOWN_TYPE, int(rng.integers(-9, 10)), 8))
+    if reqs and rng.random() < 0.15:
+        r = reqs[int(rng.integers(0, len(reqs)))]
+        if r[2] is not None:
+            reqs[reqs.index(r)] = (r[0], r[1], r[2] + [UNKNOWN_TYPE]) + r[3:]
+    tasks.sort(key=lambda t: -t[2])
+
+
+def _mutate_worlds(rng, worlds, engines, clock, seq, rnd, matches, poked,
+                   J, pokes):
+    """One randomized step applied identically to every world: consume
+    the plan, a delta append, a dead-rank patch, death and rejoin, fresh
+    restamps, and plan marks set and deleted directly."""
+    t = clock.monotonic()
+    for w in worlds:
+        for holder, s_, rh, fr, rq in matches:
+            if holder in w.snaps:
+                w.set_tasks(holder,
+                            [x for x in w.tasks(holder) if x[0] != s_])
+                w.snaps[holder]["task_stamp"] = t
+            rs = w.snaps.get(rh)
+            if rs is not None:
+                rs["reqs"] = [r for r in rs["reqs"]
+                              if not (r[0] == fr and r[1] == rq)]
+                rs["stamp"] = t
+                _bump(w.snaps, rh)
+    ranks = sorted(worlds[0].snaps)
+    if rng.random() < 0.8 and ranks:
+        tgt = int(rng.choice(ranks))
+        seq[0] += 1
+        unit = _job_task(rng, seq[0], J)
+        for w in worlds:
+            w.append(tgt, unit)
+    if rng.random() < 0.4 and ranks:
+        tgt = int(rng.choice(ranks))
+        dead = int(rng.integers(0, 400))
+        for w in worlds:
+            snap = w.snaps[tgt]
+            kept = [r for r in snap["reqs"] if r[0] != dead]
+            if len(kept) != len(snap["reqs"]):
+                snap["reqs"] = kept
+                snap["req_seq"] = snap.get("req_seq", 0) + 1
+                _bump(w.snaps, tgt)
+    if rng.random() < 0.15 and len(ranks) > 2:
+        tgt = int(rng.choice(ranks))
+        for w in worlds:
+            w.snaps.pop(tgt, None)
+    t2 = clock.monotonic()
+    for _ in range(int(rng.integers(1, 3))):
+        tgt = 100 + int(rng.integers(0, 8))
+        tasks = []
+        for _ in range(int(rng.integers(0, 10))):
+            seq[0] += 1
+            tasks.append(_job_task(rng, seq[0], J))
+        rq = ((tgt - 100) * 50 + 20 + rnd, int(rng.integers(1, 1000)),
+              [int(rng.choice(TYPES))])
+        jb = _rand_job(rng, J)
+        reqs = [rq + (0, jb) if jb else rq]
+        _spice(rng, tasks, reqs, seq)
+        snap = {"tasks": tasks, "reqs": reqs,
+                "consumers": int(rng.integers(0, 3)),
+                "stamp": t2, "task_stamp": t2}
+        for w in worlds:
+            w.put(tgt, snap)
+    if not pokes:
+        return
+    # plan marks poked in and out directly (what
+    # test_direct_plan_dict_pokes_stay_coherent does by hand)
+    for key in [k for k in poked if rng.random() < 0.5]:
+        poked.remove(key)
+        for e in engines:
+            d = e._planned_tasks if len(key) == 2 else e._planned_reqs
+            d.pop(key, None)
+    for rank in sorted(worlds[0].snaps):
+        snap = worlds[-1].snaps[rank]
+        if snap["tasks"] and rng.random() < 0.3:
+            tk = snap["tasks"][int(rng.integers(0, len(snap["tasks"])))]
+            key, when = (rank, tk[0]), t2 + (100.0 if rng.random() < 0.5
+                                             else -100.0)
+            poked.append(key)
+            for e in engines:
+                e._planned_tasks[key] = when
+        if snap["reqs"] and rng.random() < 0.2:
+            r = snap["reqs"][0]
+            key = (rank, r[0], r[1])
+            poked.append(key)
+            for e in engines:
+                e._planned_reqs[key] = t2 + 100.0
+
+
+T_COLS = ("t_seq", "t_tix", "t_prio", "t_planned", "t_elig")
+R_COLS = ("r_rank", "r_seq", "r_any", "r_mask", "r_planned", "r_elig",
+          "round_sup")
+AGGREGATES = ("g_dem", "g_any", "g_eligreq", "g_sup", "g_taskcnt",
+              "g_eligtask", "g_planned_away", "g_hasreqs", "g_consumers")
+
+
+def _assert_same_ledger(la, lb, where):
+    """Every resident column, aggregate and packed row of two array
+    ledgers, server by server."""
+    assert la.servers == lb.servers, where
+    va, vb = la.view(), lb.view()
+    assert va.slot_order.size == len(la.servers)
+    for rank in la.servers:
+        sa, sb = la._srv[rank], lb._srv[rank]
+        at = (where, rank)
+        for col in T_COLS + R_COLS:
+            ca, cb = getattr(sa, col), getattr(sb, col)
+            assert ca.dtype == cb.dtype, (at, col)
+            np.testing.assert_array_equal(ca, cb, err_msg=f"{at} {col}")
+        assert (sa.t_n, sa.r_n, sa.consumers, sa.r_dups, sa.r_unknown) == (
+            sb.t_n, sb.r_n, sb.consumers, sb.r_dups, sb.r_unknown), at
+        assert la._task_index(sa) == lb._task_index(sb), at
+        assert sa.t_dups == sb.t_dups, at
+        for g in AGGREGATES:
+            np.testing.assert_array_equal(
+                getattr(la, g)[sa.slot], getattr(lb, g)[sb.slot],
+                err_msg=f"{at} {g}")
+        n = int(va.pk_tn[sa.slot])
+        assert n == int(vb.pk_tn[sb.slot]) == min(
+            int(sa.t_elig.sum()), la.K), at
+        for pk in ("pk_tp", "pk_tt", "pk_rv", "pk_rm"):
+            np.testing.assert_array_equal(
+                getattr(va, pk)[sa.slot], getattr(vb, pk)[sb.slot],
+                err_msg=f"{at} {pk}")
+        np.testing.assert_array_equal(
+            va.pk_ts[sa.slot, :n], vb.pk_ts[sb.slot, :n], err_msg=str(at))
+        assert va.pk_rrefs[sa.slot] == vb.pk_rrefs[sb.slot], at
+        refs = [va.task_ref(sa.slot, i) for i in range(la.K)]
+        assert refs == [vb.task_ref(sb.slot, i) for i in range(la.K)], at
+        assert refs[n:] == [None] * (la.K - n), at
+        assert all(r == (rank, int(q)) for r, q in
+                   zip(refs[:n], sa.t_seq[np.flatnonzero(sa.t_elig)])), at
+
+
+def _drive_shapes(monkeypatch, mk, seed, J=1, rounds=12, nservers=8,
+                  pokes=True):
+    """``mk(host_ledger)`` builds an engine. Three of them — an array
+    ledger fed array-shaped snapshots, one fed the same snapshots as
+    tuple lists, and the Python twin — plan the same fuzzed world."""
+    from adlb_tpu.balancer import engine as engine_mod
+
+    clock = _Clock()
+    monkeypatch.setattr(engine_mod, "time", clock)
+    rng = np.random.default_rng(seed)
+    seq = [0]
+    base = _rand_snaps(rng, nservers, seq, clock.monotonic(), J=J)
+    for snap in base.values():
+        _spice(rng, snap["tasks"], snap["reqs"], seq)
+    stampless = sorted(base)[1]  # re-derived every round, by contract
+    base[stampless].pop("stamp")
+    base[stampless].pop("task_stamp")
+    engines = [mk("array"), mk("array"), mk("py")]
+    worlds = [_World(base, True), _World(base, False), _World(base, False)]
+    a, t, p = engines
+    poked: list = []
+    planned = 0
+    for rnd in range(rounds):
+        at = clock.t
+        plans = []
+        for e, w in zip(engines, worlds):
+            clock.t = at  # every engine reads the same instants
+            plans.append(e.round(w.snaps, None))
+        assert plans[0] == plans[1] == plans[2], (seed, rnd, plans)
+        planned += bool(plans[0][0] or plans[0][1])
+        now = clock.monotonic()
+        for e, w in zip(engines, worlds):
+            e._ledger.sync(w.snaps, now)
+            e._ledger.filter_reqs(w.snaps, {}, now)
+        for rank in worlds[0].snaps:
+            kept = [e._ledger.kept_reqs(rank) for e in engines]
+            elig = [e._ledger.elig_tasks(rank) for e in engines]
+            assert kept[0] == kept[1] == kept[2], (seed, rnd, rank)
+            assert elig[0] == elig[1] == elig[2], (seed, rnd, rank)
+            assert all(type(x) is int for tk in elig[0] for x in tk)
+        _assert_same_ledger(a._ledger, t._ledger, (seed, rnd))
+        _mutate_worlds(rng, worlds, engines, clock, seq, rnd, plans[0][0],
+                       poked, J, pokes)
+    assert planned >= rounds // 3, "the fuzz hardly planned anything"
+    la, lt = a._ledger, t._ledger
+    assert la.syncs_by_input["tuples"] == 0 < la.syncs_by_input["array"]
+    assert lt.syncs_by_input["array"] == 0 < lt.syncs_by_input["tuples"]
+    # the versioned store lets the array arm skip unchanged servers; the
+    # plain dict's key compare must come to the same rebuilds
+    assert la.rows_synced <= lt.rows_synced
+
+
+@pytest.fixture(scope="module")
+def mesh2():
+    return Mesh(np.array(jax.devices()[:2]), axis_names=("s",))
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("jobs", [1, MAX_JOBS])
+@pytest.mark.parametrize("solver", ["single", "sharded"])
+def test_parity_of_the_two_input_shapes(monkeypatch, mesh2, solver, jobs,
+                                        seed):
+    weights = JOB_WEIGHTS if jobs > 1 else None
+
+    def mk(host_ledger):
+        dist = None
+        if solver == "sharded":
+            dist = DistributedAssignmentSolver(
+                types=TYPES, max_tasks_per_server=12, max_requesters=6,
+                mesh=mesh2, rounds=64, servers_per_device=4,
+                max_jobs=jobs, job_weights=weights)
+        return _mk_engine(host_ledger, dist, max_jobs=jobs,
+                          job_weights=weights)
+
+    # marks poked in directly go with the single-device solver only: the
+    # sharded solver's tuple path (the Python twin's) re-reads a server
+    # when the engine's own ledger stamp moved, which a poke does not
+    # move — so there the twin lags a poke, at the parent commit too
+    _drive_shapes(monkeypatch, mk, 3000 + 10 * jobs + seed, J=jobs,
+                  pokes=solver == "single")
+
+
+def test_task_table_reads_like_the_list_it_stands_for():
+    """Whoever indexes, slices, iterates or measures a TaskTable gets
+    what the list of tuples gave: Python ints, four wide, five for a
+    unit outside the default namespace; appends keep earlier views."""
+    from adlb_tpu.balancer.ledger import TaskTable
+
+    units = [(7, 1, 5, 8), (8, 2, -3, 16), (9, 1, 0, 8)]
+    tt = TaskTable(np.array(units, np.int64).reshape(-1))  # a frame's flat
+    assert len(tt) == 3 and list(tt) == units and tt == units
+    assert tt[0] == units[0] and tt[-1] == units[-1] and tt[1:] == units[1:]
+    assert tt[:2] == units[:2] and all(
+        type(x) is int for tk in tt for x in tk)
+    with pytest.raises(IndexError):
+        tt[3]
+    before = tt.rows
+    assert not before.flags.writeable or before.base is not None
+    tt.extend(np.array([(10, 3, 1, 8, 2)], np.int64))  # widens to 5
+    tt.extend(np.array([(11, 3, 1, 8)], np.int64))
+    assert list(tt) == units + [(10, 3, 1, 8, 2), (11, 3, 1, 8)]
+    assert before.shape == (3, 4) and before.tolist() == [
+        list(u) for u in units]
+    assert len(TaskTable(())) == 0 and list(TaskTable([])) == []
+    assert not TaskTable(()) and TaskTable(()) == []

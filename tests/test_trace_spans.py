@@ -14,7 +14,7 @@ from adlb_tpu.runtime.trace import PID_SERVER, Tracer, span
 
 T1 = 1
 
-ROUND_GATED = {"adlb.round", "adlb.round.admit"}
+ROUND_GATED = {"adlb.round", "adlb.round.admit", "adlb.round.admit.sync"}
 ROUND_PLANNED = ROUND_GATED | {
     "adlb.round.plan", "adlb.round.view", "adlb.solve", "adlb.round.mark",
     "adlb.round.migrations", "adlb.round.account", "adlb.solve.pack",
@@ -26,6 +26,7 @@ HOST_SOLVE = {"adlb.solve.host"}
 #: parent -> the spans that run inside it
 CHILDREN = {
     "adlb.round": ["adlb.round.admit", "adlb.round.plan"],
+    "adlb.round.admit": ["adlb.round.admit.sync"],
     "adlb.round.plan": ["adlb.round.view", "adlb.solve", "adlb.round.mark",
                         "adlb.round.migrations", "adlb.round.account"],
     "adlb.solve": ["adlb.solve.pack", "adlb.solve.put", "adlb.solve.call",
@@ -177,6 +178,7 @@ def test_gated_and_planned_rounds_are_counted_apart():
     hists = span_hists(reg)
     assert hists["adlb.round"]["count"] == 3
     assert hists["adlb.round.admit"]["count"] == 3
+    assert hists["adlb.round.admit.sync"]["count"] == 3
     assert hists["adlb.round.plan"]["count"] == 1  # the planning rounds
     assert hists["adlb.solve.wait"]["count"] == 1
 
