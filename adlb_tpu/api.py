@@ -219,7 +219,9 @@ class WorldResult:
     app_results: dict[int, Any]
     # rank -> {int(InfoKey): float}, plus one "solver" entry where the
     # planner lived (the master server, or the balancer sidecar's
-    # pseudo-rank on the native plane): see solver_facts()
+    # pseudo-rank on the native plane): see solver_facts(). Python
+    # servers add their reactor's load: "reactor_loop_s",
+    # "reactor_busy_s" and "reactor_busy_by_second" (USERGUIDE §5)
     server_stats: dict[int, dict]
     aborted: bool
     exception: Optional[BaseException] = None
@@ -250,7 +252,8 @@ class WorldResult:
 
     def solver_facts(self) -> Optional[dict]:
         """Which path planned this world (``PlanEngine.solver_facts``):
-        platform, device_kind, device_count, path (``none`` | ``numpy`` |
+        platform, device_kind, device_count, memory_peak_bytes (read in
+        the process that owns the devices), path (``none`` | ``numpy`` |
         ``xla`` | ``pallas`` | ``pallas-interpret`` | ``mesh-device`` |
         ``mesh-host``), device_solves, host_solves, device_failures.
         None when no planner host reported (native servers under steal)."""

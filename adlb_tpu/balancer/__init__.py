@@ -8,6 +8,17 @@ as one vectorized solve under ``jax.jit`` — on TPU the compatibility matrix
 and conflict resolution map onto the MXU/VPU. The distributed variant
 (``adlb_tpu.balancer.distributed``) shards the task table over a device mesh
 with ``shard_map`` + ``all_gather``.
+
+Importing this package imports no JAX: every server rank takes its
+``SnapshotStore`` from :mod:`.ledger`, and only the planner's host (the
+master rank, the sidecar's process) goes on to :mod:`.engine` and
+:mod:`.solve`.
 """
 
-from adlb_tpu.balancer.solve import AssignmentSolver  # noqa: F401
+
+def __getattr__(name: str):
+    if name == "AssignmentSolver":  # the package's one export, on demand
+        from adlb_tpu.balancer.solve import AssignmentSolver
+
+        return AssignmentSolver
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -52,8 +52,8 @@ _PLAN_AGES: "collections.deque[float]" = collections.deque(maxlen=4096)
 #: same keys as :meth:`PlanEngine.solver_facts`
 NO_PLANNER = {
     "path": "none", "platform": None, "device_kind": None,
-    "device_count": 0, "device_solves": 0, "host_solves": 0,
-    "device_failures": 0,
+    "device_count": 0, "memory_peak_bytes": 0, "device_solves": 0,
+    "host_solves": 0, "device_failures": 0,
 }
 
 
@@ -160,6 +160,7 @@ class PlanEngine:
                 max_jobs=self.max_jobs,
                 job_weights=self._job_weights,
                 metrics=metrics,
+                nservers=nservers or 0,
                 **kw,
             )
         self.max_malloc_per_server = max_malloc_per_server
@@ -253,10 +254,13 @@ class PlanEngine:
     def solver_facts(self) -> dict:
         """Which path answered this engine's solves, for the caller to
         read after the world ends (``finalize_stats()["solver"]``, the
-        sidecar's result and flight artifact). Platform, kind and count
-        are as JAX reports them, and only once a device program exists:
-        a planner whose every solve ran the numpy twin never initialized
-        a backend, and asking here would take the chip for a report."""
+        sidecar's result and flight artifact). Platform, kind, count and
+        ``memory_peak_bytes`` (the largest ``peak_bytes_in_use`` over the
+        devices; 0 where the backend keeps no such count, as the CPU's)
+        are as JAX reports them in the process that owns the devices,
+        and only once a device program exists: a planner whose every
+        solve ran the numpy twin never initialized a backend, and asking
+        here would take the chip for a report."""
         facts = {**NO_PLANNER, **self.solver.facts()}
         if facts["path"] != "numpy":
             import jax
@@ -266,6 +270,10 @@ class PlanEngine:
                 platform=devs[0].platform,
                 device_kind=devs[0].device_kind,
                 device_count=len(devs),
+                memory_peak_bytes=max(
+                    int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                    for d in devs
+                ),
             )
         return facts
 

@@ -176,6 +176,7 @@ class AssignmentSolver:
         host_threshold_reqs: Optional[int] = DEFAULT_HOST_THRESHOLD,
         backend: str = "xla", max_jobs: int = 1,
         job_weights: Optional[dict] = None, metrics=None,
+        nservers: int = 0,
     ) -> None:
         """backend: "xla" = the jitted lax.scan greedy; "pallas" = the
         VMEM-resident Pallas sweep kernel (adlb_tpu.balancer.pallas_solve),
@@ -189,7 +190,14 @@ class AssignmentSolver:
 
         metrics: the engine's obs registry, or None; the ``adlb.solve.*``
         spans (pack, put, call, wait, get | host, extract) observe into
-        it."""
+        it.
+
+        nservers: the world's server count. A device solve is padded to
+        at least that many servers' rows: a program is built per shape,
+        and a world's first rounds see only the servers that have
+        reported so far. Unpadded, each such count is a program of its
+        own (a Pallas compile is 12 s on a v5e, and the workers wait for
+        it); padded, they run what every later round runs."""
         if backend not in ("auto", "xla", "pallas"):
             raise ValueError(f"unknown solver backend {backend!r}")
         self.base_types = tuple(types)
@@ -204,6 +212,7 @@ class AssignmentSolver:
         self.R = max_requesters
         self.rounds = rounds
         self.host_threshold_reqs = host_threshold_reqs
+        self.nservers = nservers
         self.backend = backend
         self.metrics = metrics
         self._device_fn = None  # lazily resolved (pallas import is deferred)
@@ -265,6 +274,20 @@ class AssignmentSolver:
         re-raised: nothing below the caller turns it into a host solve."""
         t0 = time.perf_counter()
         reg = self.metrics
+        n_reqs = req_valid.shape[0]
+        short = self.nservers - n_reqs // self.R
+        if short > 0:
+            # servers yet to report: rows no task or requester fills,
+            # behind the real ones, so every index stays what it was
+            task_prio = np.concatenate(
+                [task_prio, np.full(short * self.K, _NEG, task_prio.dtype)])
+            task_type = np.concatenate(
+                [task_type, np.full(short * self.K, -1, task_type.dtype)])
+            req_mask = np.concatenate(
+                [req_mask, np.zeros((short * self.R,) + req_mask.shape[1:],
+                                    req_mask.dtype)])
+            req_valid = np.concatenate(
+                [req_valid, np.zeros(short * self.R, req_valid.dtype)])
         try:
             fn = self._device_assign()
             with span("adlb.solve.put", reg):
@@ -281,7 +304,7 @@ class AssignmentSolver:
             with span("adlb.solve.wait", reg):
                 out.block_until_ready()
             with span("adlb.solve.get", reg):
-                assign = np.asarray(out)
+                assign = np.asarray(out)[:n_reqs]
         except Exception:
             self.device_failures += 1
             raise

@@ -38,6 +38,15 @@ plane:
   one ``role;[phase:..;]frames... count`` line per stack), or the full
   JSON document (per-rank stacks + sampling windows) with
   ``?format=json``. Render with ``scripts/obs_report.py --profile``.
+* ``POST /device_trace?seconds=<s>&dir=<path>`` — the chip's
+  owner traces itself (adlb_tpu/obs/device_trace.py): one
+  ``jax.profiler`` session of ``<s>`` seconds into ``<path>``, run on
+  the request's thread in this (the master's) process while the world
+  goes on; the answer, sent when the session has ended, says where it
+  began and ended on CLOCK_MONOTONIC. Waits up to a minute for the
+  planner's first device program and never starts JAX itself; 409
+  while another session runs, 503 when the planner holds no device or
+  the world is ending.
 * ``GET /dump`` — trigger a flight-record snapshot: returns the JSON doc
   inline and writes the artifact when a flight directory is configured.
 * ``GET /deadletter`` — this server's dead-letter quarantine (units that
@@ -83,6 +92,8 @@ import json
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
+
+from adlb_tpu.obs.device_trace import DeviceTracer, TraceRefused
 
 
 def _stable_dict(d: dict) -> dict:
@@ -318,7 +329,9 @@ class OpsServer:
                     self._send(500, repr(e).encode(), "text/plain")
 
             def do_POST(self) -> None:  # noqa: N802
-                path = self.path.split("?", 1)[0]
+                from urllib.parse import parse_qs
+
+                path, _, query = self.path.partition("?")
                 try:
                     n = int(self.headers.get("Content-Length") or 0)
                     raw = self.rfile.read(n) if n else b""
@@ -343,14 +356,25 @@ class OpsServer:
                     elif parts == ["control"]:
                         body = json.dumps(ops._control_post(raw)).encode()
                         self._send(200, body, "application/json")
+                    elif parts == ["device_trace"]:
+                        # blocks this request's thread for the session
+                        q = {k: v[-1] for k, v in parse_qs(query).items()}
+                        doc = ops.device_trace.trace(
+                            float(q["seconds"]), q["dir"])
+                        self._send(200, json.dumps(doc).encode(),
+                                   "application/json")
                     else:
                         self._send(404, b"not found\n", "text/plain")
+                except TraceRefused as e:
+                    self._send(e.status, f"{e}\n".encode(), "text/plain")
                 except (KeyError, ValueError, IndexError) as e:
                     self._send(400, repr(e).encode(), "text/plain")
                 except Exception as e:  # noqa: BLE001
                     self._send(500, repr(e).encode(), "text/plain")
 
         ops = self
+        # the chip's owner traces itself on request (obs/device_trace.py)
+        self.device_trace = DeviceTracer(srv.planner_on_device)
         self._httpd = ThreadingHTTPServer((host, port), Handler)
         self._httpd.daemon_threads = True
         self.port = self._httpd.server_address[1]
@@ -372,6 +396,8 @@ class OpsServer:
         return self
 
     def stop(self) -> None:
+        # first, so a session's answer still finds its socket open
+        self.device_trace.close()
         try:
             if self._thread.is_alive():
                 # shutdown() handshakes with serve_forever — calling it

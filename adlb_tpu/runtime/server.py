@@ -144,10 +144,15 @@ class _BalancerWorker(threading.Thread):
         # attributes waiting to "balancer_idle" so the parity profile's
         # balancer_tick share measures ROUNDS, not thread lifetime.
         idle = s.cfg.balancer_idle_interval
+        # the loop's spans (runtime/trace.py; the names are fixed,
+        # USERGUIDE §5): wait, engine.round's own adlb.round, ship, pace
+        # — the sidecar's cadence (sidecar.py::run_sidecar) without its
+        # ingest, which here is the reactor's
         while True:
             if prof is not None:
                 prof.set_phase("balancer_idle")
-            self.wake.wait(timeout=idle if idle > 0 else None)
+            with span("adlb.master.wait", s.metrics):
+                self.wake.wait(timeout=idle if idle > 0 else None)
             self.wake.clear()
             if self.stopped or s.done:
                 return
@@ -157,7 +162,8 @@ class _BalancerWorker(threading.Thread):
             if prof is not None:
                 prof.set_phase("balancer_idle")
             if gap > 0:
-                time.sleep(gap)
+                with span("adlb.master.pace", s.metrics):
+                    time.sleep(gap)
             if produced:
                 # a plan-bearing round usually uncovers follow-on
                 # work (the drained holder's next snapshot may lag
@@ -181,6 +187,23 @@ class _BalancerWorker(threading.Thread):
         # only touches ranks that changed since the previous round
         with span("balancer:round", tracer=s.tracer):
             matches, migrations = engine.round(snaps, s.world)
+        if matches or migrations:
+            with span("adlb.master.ship", s.metrics):
+                self._ship(snaps, matches, migrations)
+        gap = 0.0
+        if s.cfg.balancer_min_gap > 0:
+            # module already cached by run()'s deferred import; this stays
+            # a plain lookup, not a fresh module load
+            from adlb_tpu.balancer.engine import round_gap
+
+            gap = round_gap(s.cfg.balancer_min_gap, matches, migrations)
+        # the caller sleeps the gap (under the idle phase marker) and
+        # re-arms the doorbell after plan-bearing rounds
+        return gap, bool(matches or migrations)
+
+    def _ship(self, snaps, matches, migrations) -> None:
+        """Send one round's SS_PLAN_MATCH / SS_PLAN_MIGRATE frames."""
+        s = self.server
         if matches:
             # whether each planned requester's park is a fused reserve
             # (get_work/stream): snapshot req tuples carry it as a 4th
@@ -227,16 +250,6 @@ class _BalancerWorker(threading.Thread):
                 )
             except OSError:
                 continue
-        gap = 0.0
-        if s.cfg.balancer_min_gap > 0:
-            # module already cached by run()'s deferred import; this stays
-            # a plain lookup, not a fresh module load
-            from adlb_tpu.balancer.engine import round_gap
-
-            gap = round_gap(s.cfg.balancer_min_gap, matches, migrations)
-        # the caller sleeps the gap (under the idle phase marker) and
-        # re-arms the doorbell after plan-bearing rounds
-        return gap, bool(matches or migrations)
 
 
 class _PeerState:
@@ -567,7 +580,17 @@ class Server:
         from adlb_tpu.balancer.ledger import SnapshotStore
 
         self._snapshots: SnapshotStore = SnapshotStore()
+        if self.is_master and cfg.balancer == "tpu":
+            # the planner's host imports the solver (and with it JAX) here,
+            # before its reactor has traffic to serve: the balancer thread
+            # would otherwise import it under the same interpreter lock as
+            # the first flood of puts. No other rank imports JAX at all
+            import adlb_tpu.balancer.solve  # noqa: F401
         self._engine = None  # the balancer thread's PlanEngine, once built
+        # reactor busy seconds as [CLOCK_MONOTONIC second, seconds] pairs,
+        # the newest two hours (_run_loop_inner)
+        self._reactor_busy_by_s: deque = deque(maxlen=7200)
+        self._reactor_t0 = self._reactor_t1 = self._reactor_busy_s = 0.0
         self._balancer: Optional[_BalancerWorker] = None
         if cfg.balancer == "tpu" and self.is_master:
             self._balancer = _BalancerWorker(self)
@@ -939,6 +962,7 @@ class Server:
             f"server starting (master={self.is_master}, "
             f"apps={sorted(self.local_apps)}, balancer={self.cfg.balancer})",
         )
+        clean = False
         try:
             if self.cfg.ops_port is not None and self.is_master:
                 from adlb_tpu.obs.ops_server import maybe_start
@@ -979,6 +1003,7 @@ class Server:
                     msg(Tag.SS_MEMBER, self.rank, mop="ready"),
                 )
             self._run_loop()
+            clean = not (self._aborted or self.died)
         finally:
             profile.stop(self._prof)
             self._prof = None
@@ -1005,6 +1030,12 @@ class Server:
                 # in back-to-back in-process runs; never wait on a wedged
                 # device solve, though — the thread is a daemon
                 self._balancer.join(timeout=1.0)
+            if clean and self.is_master:
+                # the planner's registry (span_s, balancer_round_s,
+                # balancer_plan_age_s, balancer_pairs) outlives a world
+                # that ended well, as the sidecar's does (sidecar.py): a
+                # post-mortem is not the only reader of a flight_dir
+                self.flight.dump_json("exit")
             self._notify_debug_server_end()
             aprintf(
                 self.cfg.aprintf_flag, self.rank,
@@ -1044,6 +1075,9 @@ class Server:
         profile.register_thread("reactor")
         prof = self._prof_shared  # None when profiling is off: the
         # phase markers below cost one None check per transition then
+        self._reactor_t0 = self._reactor_t1 = time.monotonic()
+        busy_by_s = self._reactor_busy_by_s
+        busy_by_s.append([int(self._reactor_t0), 0.0])
         while not self.done:
             if self._balancer is not None and self._balancer.error is not None:
                 raise RuntimeError(
@@ -1079,8 +1113,10 @@ class Server:
                 # landing in the idle wait shows poll/recv frames, which
                 # the stack itself disambiguates from decode work
                 prof.set_phase("decode")
+            asleep = self.ep.recv_blocked_s
             m = self.ep.recv(timeout=max(deadline - time.monotonic(), 0.0))
             t0 = time.monotonic()
+            asleep = self.ep.recv_blocked_s - asleep
             if m is not None:
                 # one submission batch per reactor tick: every doorbell
                 # write / channel send this burst of handlers produces
@@ -1108,7 +1144,30 @@ class Server:
                     self.ep.submit_flush()
             self._flush_repl()
             self._flush_wal()
-            self.stats[InfoKey.LOOP_TOP_TIME] += time.monotonic() - t0
+            t1 = time.monotonic()
+            self.stats[InfoKey.LOOP_TOP_TIME] += t1 - t0
+            # reactor busy time: this turn less what its one blocking
+            # recv slept (the endpoint's count: on the shm fabric the
+            # ring scan and the frame decode inside recv are work) — the
+            # periodic duties, the receive path and everything after it
+            # (LOOP_TOP_TIME is the last alone). The sleep comes first in
+            # a turn, so the busy time is booked as the stretch that
+            # ends with the turn, by CLOCK_MONOTONIC second: a reader can
+            # take the share of any window, and no second reads over one
+            busy = max((t1 - now) - asleep, 0.0)
+            self._reactor_busy_s += busy
+            start = t1 - busy
+            while True:
+                sec = int(start)
+                upto = min(t1, sec + 1.0)
+                if busy_by_s[-1][0] == sec:
+                    busy_by_s[-1][1] += upto - start
+                else:
+                    busy_by_s.append([sec, upto - start])
+                if upto >= t1:
+                    break
+                start = upto
+            self._reactor_t1 = t1
 
     def _handle(self, m: Msg) -> None:
         """Dispatch one message; when tracing, the handler runs inside a
@@ -8156,6 +8215,12 @@ class Server:
 
     # ------------------------------------------------------- stats surface
 
+    def planner_on_device(self) -> bool:
+        """Whether this server's planner has run a device program, so
+        that this process holds a backend (obs/device_trace.py asks)."""
+        eng = self._engine
+        return eng is not None and eng.solver.facts()["device_solves"] > 0
+
     def finalize_stats(self) -> dict:
         from adlb_tpu.utils.stats import rss_kb
 
@@ -8171,6 +8236,13 @@ class Server:
             self._rq_wait_sum / self._rq_wait_n if self._rq_wait_n else 0.0
         )
         out = {int(k): float(v) for k, v in s.items()}
+        # the reactor thread's load (not InfoKeys: the reference has no
+        # such number): seconds the loop ran, seconds of them it was not
+        # asleep in its one blocking recv a turn, and the latter by second
+        out["reactor_loop_s"] = self._reactor_t1 - self._reactor_t0
+        out["reactor_busy_s"] = self._reactor_busy_s
+        out["reactor_busy_by_second"] = {
+            sec: busy for sec, busy in self._reactor_busy_by_s}
         if self.is_master:
             # which path planned (balancer/engine.py solver_facts): the
             # one non-InfoKey entry, so a caller can tell a device solve

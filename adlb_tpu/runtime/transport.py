@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Optional, Protocol
 
 from adlb_tpu.runtime.messages import Msg
@@ -44,6 +45,10 @@ class InProcEndpoint:
         # endpoint where they measure something real
         self.metrics = None
         self._tx_stats: dict = {}
+        # seconds recv has spent asleep waiting for a frame, every
+        # endpoint's count of the same thing: a reactor's turn less its
+        # share of this is its busy time (Server._run_loop_inner)
+        self.recv_blocked_s = 0.0
 
     def submit_begin(self) -> None:
         """Submission batching is a wire-transport concern (deferred
@@ -90,19 +95,23 @@ class InProcEndpoint:
         peer.inbox.put(m)
 
     def recv(self, timeout: Optional[float] = None) -> Optional[Msg]:
-        try:
-            if timeout is None:
-                return self.inbox.get()
-            if timeout <= 0.0:
-                # never SimpleQueue.get(timeout=0.0): on this host class a
-                # freshly forked child's zero-timeout timed get can park
-                # forever in the lock (kernel-level; ~1/10 TCP worlds
-                # wedged in the client's first recv). get_nowait() checks
-                # the list without touching the lock and cannot hang.
+        if timeout is not None and timeout <= 0.0:
+            # never SimpleQueue.get(timeout=0.0): on this host class a
+            # freshly forked child's zero-timeout timed get can park
+            # forever in the lock (kernel-level; ~1/10 TCP worlds
+            # wedged in the client's first recv). get_nowait() checks
+            # the list without touching the lock and cannot hang.
+            try:
                 return self.inbox.get_nowait()
+            except queue.Empty:
+                return None
+        t_block = time.monotonic()
+        try:
             return self.inbox.get(timeout=timeout)
         except queue.Empty:
             return None
+        finally:
+            self.recv_blocked_s += time.monotonic() - t_block
 
     def backlog(self) -> int:
         """Received-but-unhandled frames — the TCP-era analogue of the

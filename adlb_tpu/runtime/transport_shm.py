@@ -363,6 +363,10 @@ class ShmEndpoint:
         self._g_sup = None
         self._h_send = None  # send_s / recv_wait_s histograms — same
         self._h_recv = None  # exposition contract as the TCP endpoint
+        # asleep on the doorbell only (InProcEndpoint.recv_blocked_s):
+        # the ring scan, the frame decode and the spin before the sleep
+        # are this thread's work, not its rest
+        self.recv_blocked_s = 0.0
         self.doorbell_wakeups = 0
         # doorbell coalescing: per-dest ring tail at the last bell we
         # rang (guarded by that dest's send lock). A peer that has not
@@ -764,7 +768,10 @@ class ShmEndpoint:
                     return None
             if self._rx and (remaining is None or remaining > _INSURANCE_S):
                 remaining = _INSURANCE_S  # bounded re-scan (see above)
-            if self._bell.wait(remaining):
+            t_block = time.monotonic()
+            rung = self._bell.wait(remaining)
+            self.recv_blocked_s += time.monotonic() - t_block
+            if rung:
                 self.doorbell_wakeups += 1
                 self._bell.drain()
 

@@ -89,6 +89,7 @@ class TcpEndpoint:
         self._rx_stats: dict = {}
         self._h_send = None  # send_s / recv_wait_s histograms, cached on
         self._h_recv = None  # first use (hot path: no per-message lookup)
+        self.recv_blocked_s = 0.0  # InProcEndpoint.recv_blocked_s
         # shm-fabric hooks (transport_shm.py): ``notify`` fires after
         # every inbox delivery so a recv blocked on the shm doorbell
         # wakes for TCP traffic too; ``shm_ctl`` receives the swallowed
@@ -471,9 +472,7 @@ class TcpEndpoint:
         reg = self.metrics
         t0 = time.monotonic() if reg is not None else 0.0
         try:
-            if timeout is None:
-                m = self.inbox.get()
-            elif timeout <= 0.0:
+            if timeout is not None and timeout <= 0.0:
                 # never SimpleQueue.get(timeout=0.0): on this host class a
                 # freshly forked child's zero-timeout timed get can park
                 # forever in the lock (kernel-level; ~1/10 TCP worlds
@@ -483,7 +482,13 @@ class TcpEndpoint:
                 # checks the list without touching the lock.
                 m = self.inbox.get_nowait()
             else:
-                m = self.inbox.get(timeout=timeout)
+                # frames are decoded by the reader threads, so all of a
+                # blocking get is sleep (InProcEndpoint.recv_blocked_s)
+                t_block = time.monotonic()
+                try:
+                    m = self.inbox.get(timeout=timeout)
+                finally:
+                    self.recv_blocked_s += time.monotonic() - t_block
         except queue.Empty:
             return None
         if reg is not None:

@@ -78,10 +78,18 @@ def _require_tpu(stage: str, platform, kind, count) -> dict:
 
 def _check_facts(stage: str, facts: dict, path: str) -> dict:
     """The planner's own account must say: TPU, the asked-for path, at
-    least one device solve, no host solve, no failure."""
+    least one device solve, no host solve, no failure — and what its
+    devices hold, read in the process that owns them."""
     _say(stage, f"solver facts: {json.dumps(facts, sort_keys=True)}")
     device = _require_tpu(stage, facts["platform"], facts["device_kind"],
                           facts["device_count"])
+    _say(stage, f"the chip's owner reports memory_peak_bytes="
+                f"{facts.get('memory_peak_bytes')}")
+    if not facts.get("memory_peak_bytes"):
+        raise SystemExit(
+            f"[{stage}] FAIL: memory_peak_bytes="
+            f"{facts.get('memory_peak_bytes')!r}: the planner's process "
+            f"did not read its devices' memory")
     if facts["path"] != path:
         raise SystemExit(
             f"[{stage}] FAIL: solver path {facts['path']!r}, wanted {path!r}")
@@ -291,6 +299,8 @@ def stage_py_plane(seed: int) -> dict:
             f"distinct units, {n_tasks} put — not exactly once")
     facts = res.solver_facts()
     device = _check_facts(stage, facts, "pallas")
+    # the forked master rank held the chip, not this process: its memory
+    # came out with the facts, as the benchmark's python plane takes it
     return {"device": device, "solver": facts, "tasks": r.tasks,
             "world_s": round(world_s, 1)}
 

@@ -49,6 +49,50 @@ def test_solver_priority_wins():
     assert pairs == [(10, 2, 11, 0, 1)]  # highest priority task chosen
 
 
+def test_a_device_solve_has_the_worlds_shape_whoever_has_reported():
+    """A world's first rounds see only the servers that have reported.
+    Their device solve is padded to the world's server count, so it runs
+    the program every later round runs and not one of its own shape, and
+    plans what the unpadded solve plans (dict and ledger-view paths)."""
+    import random
+
+    from adlb_tpu.balancer.engine import PlanEngine
+
+    rng = random.Random(7)
+    snapshots = {
+        10: {"tasks": [(i, rng.choice((T1, T2)), rng.randrange(9), 1)
+                       for i in range(8)], "reqs": []},
+        12: {"tasks": [(50 + i, T1, i, 1) for i in range(3)],
+             "reqs": [(r, r, rng.choice(([T1], [T2], None)))
+                      for r in range(4)]},
+    }
+    kw = dict(types=(T1, T2), max_tasks=8, max_requesters=4,
+              host_threshold_reqs=0)
+    plain, padded = AssignmentSolver(**kw), AssignmentSolver(nservers=5, **kw)
+    shapes = []
+    fn = padded._device_assign()
+    padded._device_fn = lambda *a: shapes.append(
+        [x.shape for x in a]) or fn(*a)
+    want = plain.solve(snapshots, None)
+    assert want and padded.solve(snapshots, None) == want
+    assert shapes == [[(40,), (40,), (20, 2), (20,)]]
+    # more servers than the world began with (scale-out): no padding
+    more = {r: {"tasks": [], "reqs": []} for r in range(20, 26)}
+    more.update(snapshots)
+    assert padded.solve(more, None) == plain.solve(more, None)
+    assert shapes[-1] == [(64,), (64,), (32, 2), (32,)]
+    # the engine hands its solver the count, and the view path pads too
+    engine = PlanEngine(nservers=5, backend="xla", **kw)
+    assert engine.solver.nservers == 5
+    engine.solver._device_fn = padded._device_fn
+    for snap in snapshots.values():
+        snap.update(stamp=1.0, nbytes=8, consumers=1)
+    engine.round(dict(snapshots), WorldSpec(nranks=9, nservers=5,
+                                            types=(T1, T2)))
+    assert engine.solver.device_solve_count == 1
+    assert shapes[-1] == [(40,), (40,), (20, 2), (20,)]
+
+
 def test_solver_many_to_many_no_double_assignment():
     s = AssignmentSolver(types=(T1,), max_tasks=16, max_requesters=16)
     snapshots = {

@@ -414,7 +414,8 @@ def test_round_admit_ms_reads_the_admission_span():
         "name": "round_admit_ms", "unit": "ms", "better": "lower",
         "source": "program_span", "layer": "planner host",
         "moves": "worker_fed_pct",
-        "workloads": ["hotspot-native-n128.bulk", "hotspot-native-n64.bulk"],
+        "workloads": ["hotspot-native-n128.bulk", "hotspot-native-n64.bulk",
+                      "hotspot-py-n64.bulk"],
     }]
 
 
