@@ -26,6 +26,10 @@
  *   p0.start      {double t_first, double t_end}     at the first put
  *   p0.bin        {int64 n_acked, double t_first, double t_last,
  *                  double t_end}                      producer, one record
+ *   p0.puts       {double put_s}   one per acknowledged ADLB_Put, in put
+ *                  order: the seconds the call took. Synchronous mixes
+ *                  only (ADLB_WIN_FLUSH_EVERY 0); a pipelined producer
+ *                  writes no such file
  *   w<rank>.fetch {double t_call, double t_ret, int32 n_got, int32 rc}
  *   w<rank>.units {payload as received (32 bytes), double t_call,
  *                  double t_ret, double t_done}                (56 bytes)
@@ -129,6 +133,12 @@ static int produce(const char *logdir) {
     return 5;
   }
   fclose(pf);
+  /* a synchronous producer keeps what every put took, by its own clock */
+  double *put_s = NULL;
+  if (flush_every <= 0 && !(put_s = malloc((size_t)(n + 1) * sizeof *put_s))) {
+    fprintf(stderr, "window_client: no memory for %ld put times\n", n);
+    return 5;
+  }
 
   double t_first = mono(), t_last = t_first;
   double t_end = t_first + warm_s + seconds;
@@ -158,13 +168,18 @@ static int produce(const char *logdir) {
       }
     } else {
       rc = ADLB_Put(&p, (int)sizeof p, -1, -1, TOKEN, 0);
-      if (rc == ADLB_SUCCESS) acked++;
+      if (rc == ADLB_SUCCESS) put_s[acked++] = mono() - p.t_put;
     }
     if (rc != ADLB_SUCCESS) {
       fprintf(stderr, "window_client: put %ld failed rc=%d\n", i, rc);
       return 3;
     }
     t_last = mono();
+  }
+  if (put_s) {
+    FILE *qf = open_log(logdir, "p%d.puts", 0);
+    fwrite(put_s, sizeof *put_s, (size_t)acked, qf);
+    fclose(qf);
   }
   FILE *lf = open_log(logdir, "p%d.bin", 0);
   fwrite(&acked, sizeof acked, 1, lf);

@@ -29,6 +29,10 @@ UNIT = np.dtype([("id", "<i8"), ("t_put", "<f8"), ("t_end", "<f8"),
 PRODUCER = np.dtype([("n_acked", "<i8"), ("t_first", "<f8"),
                      ("t_last", "<f8"), ("t_end", "<f8")])
 
+#: what each acknowledged put of a synchronous producer took, in put order
+#: (``p0.puts``; a pipelined producer writes none)
+PUT_S = np.dtype("<f8")
+
 assert (PLAN.itemsize, PAYLOAD.itemsize, FETCH.itemsize, UNIT.itemsize,
         PRODUCER.itemsize) == (24, 32, 24, 56, 32)
 
@@ -44,11 +48,13 @@ class Logs:
     unit_rank: np.ndarray     # the worker that logged each unit
     fetches: np.ndarray       # FETCH records
     fetch_rank: np.ndarray    # the worker that logged each fetch
+    put_s: np.ndarray         # PUT_S records; none from a pipelined producer
 
 
 def read_logs(logdir: str) -> Logs:
     """Read a run's log directory. A missing producer record (it died
-    before its last put was acknowledged) reads as ``None``."""
+    before its last put was acknowledged) reads as ``None``, a missing
+    ``p0.puts`` (a pipelined producer) as no put times."""
     ppath = os.path.join(logdir, "p0.bin")
     producer = None
     if os.path.exists(ppath) and os.path.getsize(ppath) == PRODUCER.itemsize:
@@ -59,9 +65,7 @@ def read_logs(logdir: str) -> Logs:
         u = _whole_records(upath, UNIT)
         units.append(u)
         unit_rank.append(np.full(len(u), rank, dtype=np.int32))
-        fpath = upath[: -len("units")] + "fetch"
-        f = _whole_records(fpath, FETCH) if os.path.exists(fpath) else \
-            np.zeros(0, dtype=FETCH)
+        f = _whole_records(upath[: -len("units")] + "fetch", FETCH)
         fetches.append(f)
         fetch_rank.append(np.full(len(f), rank, dtype=np.int32))
 
@@ -69,11 +73,15 @@ def read_logs(logdir: str) -> Logs:
         return np.concatenate(parts) if parts else np.zeros(0, dtype=dtype)
 
     return Logs(producer, cat(units, UNIT), cat(unit_rank, np.int32),
-                cat(fetches, FETCH), cat(fetch_rank, np.int32))
+                cat(fetches, FETCH), cat(fetch_rank, np.int32),
+                _whole_records(os.path.join(logdir, "p0.puts"), PUT_S))
 
 
 def _whole_records(path: str, dtype: np.dtype) -> np.ndarray:
-    """A file's whole records (a killed client can leave a torn tail)."""
+    """A file's whole records (a killed client can leave a torn tail); a
+    file that is not there has none."""
+    if not os.path.exists(path):
+        return np.zeros(0, dtype=dtype)
     n = os.path.getsize(path) // dtype.itemsize
     return np.fromfile(path, dtype=dtype, count=n)
 
@@ -89,7 +97,11 @@ def write_worker_log(logdir: str, rank: int, units: np.ndarray,
 
 
 def write_producer_log(logdir: str, n_acked: int, t_first: float,
-                       t_last: float, t_end: float) -> None:
+                       t_last: float, t_end: float, put_s=None) -> None:
+    """The producer's record and, where ``put_s`` is given, what each put
+    of a synchronous producer took (``p0.puts``)."""
+    if put_s is not None:
+        np.asarray(put_s, dtype=PUT_S).tofile(os.path.join(logdir, "p0.puts"))
     rec = np.zeros(1, dtype=PRODUCER)
     rec[0] = (n_acked, t_first, t_last, t_end)
     rec.tofile(os.path.join(logdir, "p0.bin"))

@@ -60,6 +60,7 @@ class Window:
         p = logs.producer
         put_s = float(p["t_last"] - p["t_first"])
         self.put_rate = float(p["n_acked"]) / put_s if put_s > 0 else None
+        self.put_times = logs.put_s  # a synchronous producer's, else empty
         self.flags = []
         if self.first_remote is None or self.first_remote > t0:
             self.flags.append("planner-not-warm")
@@ -90,6 +91,12 @@ class Window:
         fr = "none" if self.first_remote is None else \
             f"{self.first_remote - self.t0:+.3f}s from window start"
         rate = "n/a" if self.put_rate is None else f"{self.put_rate:.0f}/s"
+        if len(self.put_times):
+            q = np.percentile(self.put_times, [50, 99, 100]) * 1e3
+            mean = float(self.put_times.mean())
+            rate += (f" (a put took p50 {q[0]:.4f} p99 {q[1]:.3f} max "
+                     f"{q[2]:.1f} ms, mean {mean * 1e3:.4f} ms = "
+                     f"{1.0 / mean:.0f}/s)")
         return (f"window: {self.seconds:g}s, {self.units_done} units done, "
                 f"first remote delivery {fr}, least backlog "
                 f"{self.least_backlog}, producer put rate {rate}, "

@@ -13,10 +13,11 @@ until the pool is exhausted; a unit costs ``sleep(work_us)`` only while
 
 Each rank logs to files of its own under ``logdir``, in the records
 ``reduce/records.py`` reads (``p0.start``, ``p0.bin``, ``w<rank>.fetch``,
-``w<rank>.units``), buffered. Times are ``time.monotonic()``, which is
-CLOCK_MONOTONIC on Linux, system-wide. A rank returns what the C client
-exits with: 0 only when every put was acknowledged (producer) or the
-last fetch said the pool is exhausted (worker).
+``w<rank>.units``, and in a synchronous mix ``p0.puts``), buffered. Times
+are ``time.monotonic()``, which is CLOCK_MONOTONIC on Linux, system-wide.
+A rank returns what the C client exits with: 0 only when every put was
+acknowledged (producer) or the last fetch said the pool is exhausted
+(worker).
 """
 
 from __future__ import annotations
@@ -63,6 +64,7 @@ def produce(ctx, plan_path: str, logdir: str, warm_s: float, seconds: float,
     with open(os.path.join(logdir, "p0.start"), "wb") as f:
         f.write(struct.pack("<dd", t_first, t_end))
     acked = in_flight = 0
+    put_s = []  # what each synchronous put took; stays empty when pipelined
     last = len(ids) - 1
     for i, unit_id in enumerate(ids):
         if dues[i] > 0:
@@ -78,12 +80,17 @@ def produce(ctx, plan_path: str, logdir: str, warm_s: float, seconds: float,
                     acked += in_flight
                 in_flight = 0
         else:
+            t_put = mono()
             rc = ctx.put(payload, TOKEN)
             if rc == ADLB_SUCCESS:
+                put_s.append(mono() - t_put)
                 acked += 1
         if rc != ADLB_SUCCESS:
             return 3
         t_last = mono()
+    if flush_every <= 0:
+        np.asarray(put_s, dtype=records.PUT_S).tofile(
+            os.path.join(logdir, "p0.puts"))
     with open(os.path.join(logdir, "p0.bin"), "wb") as f:
         f.write(PRODUCER.pack(acked, t_first, t_last, t_end))
     return 0

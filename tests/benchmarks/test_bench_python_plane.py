@@ -326,23 +326,23 @@ def test_the_committed_python_cell_is_the_deployment_it_names():
 
 
 def test_the_span_metrics_list_the_cells_whose_plane_emits_their_spans():
-    """What ``test_bench_hostspans.py`` pinned for "both cells" (an
-    expected failure since this plane has a cell, ``conftest.py``), held
-    entry by entry: every span metric of PR 26 and ``plan_age_p95_ms`` is
-    a ``program_span`` that moves ``worker_fed_pct``; a reader of
-    ``adlb.sidecar.*`` spans lists the native cells alone, a reader blind
-    to the plane lists every cell, each list exact and in order."""
+    """Every span metric of PR 26 and ``plan_age_p95_ms`` is a
+    ``program_span`` that moves ``worker_fed_pct``; a reader of
+    ``adlb.sidecar.*`` spans lists the native plane's cells alone, a
+    reader blind to the plane lists those and this plane's, each list
+    exact and in the order of ``workloads``."""
     spec = Spec(ROOT)
-    native = ["hotspot-native-n128.bulk", "hotspot-native-n64.bulk"]
-    assert spec.cells() == native + [CELL]
+    plane_of = {cell: spec.config(cell)["plane"] for cell in spec.cells()}
+    native = [c for c, p in plane_of.items() if p == "native"]
+    both = [c for c, p in plane_of.items() if p in ("native", "python")]
+    assert len(native) >= 3 and set(both) - set(native) == {CELL}
     by_name = {m["name"]: m for m in spec.doc["per_layer"]}
     want = {
         "planner_busy_pct": native, "ingest_ms_per_s": native,
         "plan_ship_ms": native,
-        "planning_rounds_per_s": native + [CELL],
-        "round_pump_ms": native + [CELL], "round_solve_ms": native + [CELL],
-        "idle_named_pct": native + [CELL],
-        "plan_age_p95_ms": native + [CELL],
+        "planning_rounds_per_s": both, "round_pump_ms": both,
+        "round_solve_ms": both, "idle_named_pct": both,
+        "plan_age_p95_ms": both,
     }
     for name, cells in want.items():
         entry = by_name[name]

@@ -1,0 +1,350 @@
+"""The producer's side of the pool: ``producer_puts_per_s`` and the two
+put-latency readers on hand-made logs, the ``p0.puts`` record from both
+traffic clients, the committed ``hotspot-native-n64.syncput`` cell, its
+control at the cell's own size, and a whole run of it over the stand-in
+plane with the timed path broken underneath. What must not move is held
+too: the plan of a ``bulk`` cell byte for byte, and a pipelined
+producer's files. No chip."""
+
+import hashlib
+import json
+import math
+import os
+import shutil
+import types
+
+import numpy as np
+import pytest
+
+from benchmarks import control, run as bench_run
+from benchmarks.reduce import records
+from benchmarks.reduce.window import Window
+from benchmarks.spec import ROOT, Spec
+from benchmarks.traffic import window_app
+from benchmarks.traffic.generate import make_plan, n_units
+from test_bench_python_plane import CannedContext
+from test_bench_spec import add_standin, copy_of_benchmark
+
+CELL = "hotspot-native-n64.syncput"
+BULK = "hotspot-native-n64.bulk"
+PUT_METRICS = ("put_rtt_p50_ms", "put_rtt_p99_ms")
+
+
+@pytest.fixture(scope="module")
+def spec():
+    return Spec(ROOT)
+
+
+# ------------------------------------------------------------ the readers
+
+
+def producer_only(tmp_path, n_acked, t_first, t_last, put_s=None):
+    records.write_producer_log(str(tmp_path), n_acked, t_first, t_last,
+                               110.0, put_s)
+    return records.read_logs(str(tmp_path))
+
+
+def test_put_rate_is_acked_puts_over_first_to_last_put(tmp_path, spec):
+    read = spec.reader("producer_puts_per_s")
+    logs = producer_only(tmp_path, 7400, 50.0, 52.0)
+    window = Window(logs, 10.0, workers=2, nservers=2, needs_backlog=False)
+    assert read({"window": window}) == pytest.approx(3700.0)
+    assert "producer put rate 3700/s" in window.describe()
+
+
+@pytest.mark.parametrize("case", ["zero_span", "no_producer_record"])
+def test_put_rate_reads_nothing_without_a_span_or_a_record(tmp_path, spec,
+                                                           case):
+    read = spec.reader("producer_puts_per_s")
+    if case == "zero_span":
+        logs = producer_only(tmp_path, 1, 50.0, 50.0)
+        assert read({"window": Window(logs, 10.0, 2, 2, False)}) is None
+    else:  # no record, so no window was reduced at all
+        assert records.read_logs(str(tmp_path)).producer is None
+        assert read({"window": None}) is None
+
+
+def test_put_rtt_quantiles_from_the_producers_put_times(tmp_path, spec):
+    # 1,000 puts: 980 of 0.25 ms, 20 of 5 ms (a stalled host's tail)
+    put_s = np.r_[np.full(980, 0.25e-3), np.full(20, 5e-3)]
+    np.random.default_rng(3).shuffle(put_s)
+    logs = producer_only(tmp_path, 1000, 50.0, 50.0 + put_s.sum(), put_s)
+    assert logs.put_s.dtype == records.PUT_S and len(logs.put_s) == 1000
+    run = {"logs": logs}
+    assert spec.reader("put_rtt_p50_ms")(run) == pytest.approx(0.25)
+    assert spec.reader("put_rtt_p99_ms")(run) == pytest.approx(5.0)
+    # the mean turned over is the rate: the two records tell one story
+    window = Window(logs, 10.0, 2, 2, False)
+    assert 1.0 / logs.put_s.mean() == pytest.approx(window.put_rate)
+
+
+@pytest.mark.parametrize("name", PUT_METRICS)
+def test_put_rtt_reads_nothing_when_the_file_is_absent(tmp_path, spec, name):
+    logs = producer_only(tmp_path, 1000, 50.0, 51.0)  # pipelined: no p0.puts
+    assert not os.path.exists(tmp_path / "p0.puts")
+    assert len(logs.put_s) == 0
+    assert spec.reader(name)({"logs": logs}) is None
+
+
+def test_a_torn_put_record_reads_its_whole_doubles(tmp_path):
+    producer_only(tmp_path, 3, 50.0, 51.0, [1e-3, 2e-3, 3e-3])
+    with open(tmp_path / "p0.puts", "ab") as f:
+        f.write(b"\x00\x01\x02")
+    assert records.read_logs(str(tmp_path)).put_s.tolist() == [1e-3, 2e-3,
+                                                                3e-3]
+
+
+# -------------------------------------------- the record, from both clients
+
+
+class SyncContext(CannedContext):
+    def put(self, payload, _work_type):
+        from adlb_tpu.types import ADLB_SUCCESS
+
+        self.put_payloads.append(payload)
+        return ADLB_SUCCESS
+
+
+def python_producer(tmp_path, flush_every: int) -> records.Logs:
+    plan = np.zeros(5, dtype=records.PLAN)
+    plan["id"], plan["work_us"] = [5, 4, 3, 2, 1], 1000
+    plan.tofile(tmp_path / "plan.bin")
+    ctx = SyncContext(0)
+    assert window_app.produce(ctx, str(tmp_path / "plan.bin"), str(tmp_path),
+                              warm_s=1.0, seconds=2.0,
+                              flush_every=flush_every) == 0
+    assert len(ctx.put_payloads) == 5
+    return records.read_logs(str(tmp_path))
+
+
+def test_the_python_client_logs_a_put_time_for_every_synchronous_put(
+        tmp_path):
+    logs = python_producer(tmp_path, flush_every=0)
+    assert os.path.getsize(tmp_path / "p0.puts") == 5 * 8
+    assert int(logs.producer["n_acked"]) == 5 == len(logs.put_s)
+    assert (logs.put_s >= 0).all()
+    span = float(logs.producer["t_last"] - logs.producer["t_first"])
+    assert logs.put_s.sum() <= span
+
+
+def test_a_pipelined_python_producer_writes_the_files_it_wrote(tmp_path):
+    logs = python_producer(tmp_path, flush_every=2)
+    assert sorted(os.listdir(tmp_path)) == ["p0.bin", "p0.start", "plan.bin"]
+    assert len(logs.put_s) == 0 and int(logs.producer["n_acked"]) == 5
+
+
+@pytest.mark.skipif(shutil.which("g++") is None or shutil.which("gcc") is None,
+                    reason="no C toolchain")
+@pytest.mark.parametrize("flush_every", [0, 64])
+def test_the_native_client_logs_put_times_in_a_synchronous_mix_only(
+        tmp_path, flush_every):
+    """``clients/window_client.c`` through a small world (Python servers,
+    no planner on a device): the producer's files and what they say."""
+    from adlb_tpu.native.capi import build_example, run_native_world
+    from adlb_tpu.runtime.world import Config
+
+    small = {"app_ranks": 4, "servers": 2, "work_us": 2000, "warm_s": 0.3,
+             "fed_warm_s": 0.3}
+    mix = {"put_routing": "home", "flush_every": flush_every}
+    plan = make_plan(small, mix, 2**31 + 3, 0.5)
+    plan.tofile(tmp_path / "plan.bin")
+    logdir = tmp_path / "logs"
+    logdir.mkdir()
+    exe = build_example(os.path.join(ROOT, "benchmarks", "clients",
+                                     "window_client.c"))
+    results, _stats = run_native_world(
+        n_clients=4, nservers=2, types=[1], exe=exe,
+        cfg=Config(exhaust_check_interval=0.2),
+        env_extra={"ADLB_PUT_ROUTING": "home",
+                   "ADLB_WIN_UNITS": str(tmp_path / "plan.bin"),
+                   "ADLB_WIN_LOGDIR": str(logdir),
+                   "ADLB_WIN_WARM_S": "0.3", "ADLB_WIN_SECONDS": "0.5",
+                   "ADLB_WIN_FETCH": "4",
+                   "ADLB_WIN_FLUSH_EVERY": str(flush_every)},
+        timeout=90.0)
+    assert [rc for rc, _out, _err in results] == [0] * 4, results
+    logs = records.read_logs(str(logdir))
+    assert int(logs.producer["n_acked"]) == len(plan) == len(logs.units)
+    if flush_every:
+        assert not os.path.exists(logdir / "p0.puts")
+        assert len(logs.put_s) == 0
+        return
+    assert os.path.getsize(logdir / "p0.puts") == 8 * len(plan)
+    assert (logs.put_s > 0).all() and logs.put_s.max() < 5.0
+    # each put's time lies between its own t_put and the next put's
+    t_put = np.sort(logs.units["t_put"])
+    assert (logs.put_s[:-1] <= np.diff(t_put) + 1e-9).all()
+    span = float(logs.producer["t_last"] - logs.producer["t_first"])
+    assert logs.put_s.sum() == pytest.approx(span, rel=0.05)
+
+
+# --------------------------------------------------- what must not move
+
+
+def test_the_plan_of_a_bulk_cell_is_the_parents_byte_for_byte(spec):
+    """``make_plan`` for ``hotspot-native-n64.bulk``, seed 2**31 + 30, 20 s:
+    the digest of the plan the parent commit (PR 28) makes."""
+    plan = make_plan(spec.config(BULK), spec.traffic(BULK), 2**31 + 30, 20.0)
+    assert len(plan) == 70875
+    assert hashlib.sha256(plan.tobytes()).hexdigest() == PARENT_PLAN_SHA256
+    assert spec.traffic(BULK) == PARENT_BULK_MIX
+
+
+PARENT_PLAN_SHA256 = "bdffef1d7dc8d055776eec63b35e66172eb4a22b21025d6e5669e506c53694c4"
+PARENT_BULK_MIX = {
+    "put_routing": "home", "pace": 0, "flush_every": 512,
+    "work_mult": [[1.0, 1.0]], "needs_backlog": True,
+    "backlog": "ceil(capacity * (fed_warm_s + seconds)) units, capacity = "
+               "workers / unit time: no system outruns it, so the backlog "
+               "outlasts the window",
+    "why_pipelined": "a synchronous producer manages 3,600-3,800 puts/s "
+                     "into the hot server (my chip run, PR 25), under the "
+                     "fleet's 5,292 units/s: the backlog would sit near 200 "
+                     "and the cell would time the producer",
+}
+
+
+# ------------------------------------------------------ the committed cell
+
+
+def test_the_committed_cell_is_the_deployment_it_names(spec):
+    cell, config, mix = spec.cell(CELL), spec.config(CELL), spec.traffic(CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "hotspot-native-n64", "syncput", 1)
+    assert config == spec.config(BULK)  # the configuration, unedited
+    assert (mix["put_routing"], mix["pace"], mix["flush_every"],
+            mix["work_mult"], mix["needs_backlog"]) == (
+        "home", 0, 0, [[1.0, 1.0]], True)
+    assert mix["why_synchronous"] and mix["why_this_configuration"]
+    # as many units as the bulk cell: capacity x (fed_warm_s + seconds)
+    assert n_units(config, mix, spec.run_seconds) == math.ceil(
+        63 / 0.024 * (7 + 20)) == 70875
+    spec.check_files()
+
+
+def test_the_put_metrics_are_listed_for_synchronous_floods_alone(spec):
+    """``producer_puts_per_s`` is a put rate only where every put is one
+    blocking round trip and nothing paces it; the two latencies move it."""
+    by_name = {m["name"]: m for kind in ("end_to_end", "per_layer")
+               for m in spec.doc[kind]}
+    rate = by_name["producer_puts_per_s"]
+    assert (rate["unit"], rate["better"], rate["source"]) == (
+        "units/s", "higher", "host_clock")
+    assert rate["workloads"] == [CELL] and 0.01 <= rate["bound"] <= 0.25
+    for cell in rate["workloads"]:
+        mix = spec.traffic(cell)
+        assert mix.get("flush_every", 0) == 0 and mix.get("pace", 0) == 0
+    for name in PUT_METRICS:
+        entry = by_name[name]
+        assert (entry["moves"], entry["workloads"], entry["source"],
+                entry["unit"], entry["better"]) == (
+            "producer_puts_per_s", [CELL], "host_clock", "ms", "lower")
+        assert entry["layer"] == by_name["fetch_rtt_p50_ms"]["layer"]
+    names = [m["name"] for m in spec.metrics("end_to_end", CELL)]
+    assert sorted(names) == ["producer_puts_per_s", "setup_s", "units_per_s",
+                             "worker_fed_pct"]
+    for other in spec.cells():
+        if other != CELL:
+            assert "producer_puts_per_s" not in [
+                m["name"] for m in spec.metrics("end_to_end", other)]
+    # a traced run reports what its bulk twin reports, and the two. But
+    # one: tests/test_sidecar_replay.py (PR 27, outside the benchmark's
+    # directories) pins the list of round_admit_ms to three cells
+    here = {m["name"] for m in spec.metrics("per_layer", CELL)}
+    there = {m["name"] for m in spec.metrics("per_layer", BULK)}
+    assert here == (there - {"round_admit_ms"}) | set(PUT_METRICS)
+
+
+def test_no_entry_that_was_there_changed_but_for_its_list_of_cells(spec):
+    """Bounds, sources, units and ``moves`` of PR 28's entries, as the
+    ledger's lines were measured under them."""
+    want = {"units_per_s": ("units/s", "higher", 0.02),
+            "worker_fed_pct": ("%", "higher", 0.01),
+            "setup_s": ("s", "lower", 0.25)}
+    for m in spec.doc["end_to_end"]:
+        if m["name"] in want:
+            assert (m["unit"], m["better"], m["bound"]) == want[m["name"]]
+            assert m["source"] == "host_clock" and "workloads" not in m
+    assert spec.run_seconds == 20
+    assert spec.cells()[:3] == ["hotspot-native-n128.bulk", BULK,
+                                "hotspot-py-n64.bulk"]
+
+
+# ----------------------------------- the control, at the cell's own size
+
+
+@pytest.mark.parametrize("guarantee,number", [
+    ("at_least_once", "duplicated_units"),
+    ("at_most_once", "missing_units"),
+    ("altered", "altered_units"),
+])
+def test_each_control_is_not_correct_at_the_cells_own_size(guarantee, number):
+    out = control.judge(CELL, seed=2**31 + 30, seconds=20.0,
+                        guarantee=guarantee)
+    assert out["units"] == 70875 and out["correct"] is False
+    assert out["compared"][number] == {"value": 70, "limit": 0}
+
+
+def test_the_sound_pool_is_correct_at_the_cells_own_size():
+    out = control.judge(CELL, seed=2**31 + 31, seconds=20.0,
+                        guarantee="exactly_once")
+    assert out["units"] == 70875 and out["correct"] is True
+    assert all(v == {"value": 0, "limit": 0}
+               for v in out["compared"].values())
+
+
+# ----------------- a whole run, with the timed path broken underneath
+
+
+def run_standin_syncput(tmp_path, fault: str) -> dict:
+    """``run.child`` over the stand-in plane under the ``syncput`` mix: the
+    stand-in's configuration with the committed traffic file, the cell
+    added to every list a synchronous flood belongs on."""
+    root = copy_of_benchmark(tmp_path)
+    add_standin(root, fault=fault)
+    cell = "standin-n8.syncput"
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    doc["workloads"].append({"name": cell, "config": "standin-n8",
+                             "traffic": "syncput", "chips": 1, "why": "test"})
+    for m in doc["end_to_end"] + doc["per_layer"]:
+        if CELL in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(doc, f)
+    args = types.SimpleNamespace(workload=cell, seed=2**31 + 7, seconds=2.0,
+                                 trace=0, t0=0.0)
+    os.makedirs(bench_run.scratch_dir(root, cell))
+    assert bench_run.child(args, root=root) == 0
+    with open(os.path.join(bench_run.scratch_dir(root, cell),
+                           "result.json")) as f:
+        return json.load(f)
+
+
+def test_a_sound_syncput_run_reports_the_put_rate_beside_the_rest(tmp_path):
+    result = run_standin_syncput(tmp_path, "none")
+    assert result["correct"] is True and result["failed"] == 0
+    assert set(result["metrics"]) == {"units_per_s", "worker_fed_pct",
+                                      "setup_s", "producer_puts_per_s"}
+    # the stand-in's producer record spans one second: 8 / 20 ms x 3 s
+    assert result["attempted"] == 1200
+    assert result["metrics"]["producer_puts_per_s"] == {"value": 1200.0,
+                                                        "unit": "units/s"}
+    assert list(result)[-1] == "compared"
+
+
+@pytest.mark.parametrize("fault,number", [
+    ("at_least_once", "duplicated_units"),
+    ("at_most_once", "missing_units"),
+    ("altered", "altered_units"),
+    ("t_end_altered", "altered_units"),
+    ("client_failed", "clients_failed"),
+    ("unacked_put", "unacked_puts"),
+    ("solve_altered", "solve_mismatch"),
+])
+def test_a_syncput_run_with_the_timed_path_broken_is_not_correct(
+        tmp_path, fault, number):
+    result = run_standin_syncput(tmp_path, fault)
+    assert result["correct"] is False
+    assert result["compared"][number]["value"] > result["compared"][
+        number]["limit"] == 0
