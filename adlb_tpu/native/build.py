@@ -240,7 +240,7 @@ def ensure_serverd() -> str:
     """
     return build_artifact(
         "adlb_serverd",
-        ["g++", "-O2", "-std=c++17", "-pthread", "-o", "{out}",
+        ["g++", "-O2", "-std=c++17", "-o", "{out}",
          _SERVERD_SRC],
         [_SERVERD_SRC, _WQ_HDR],
     )

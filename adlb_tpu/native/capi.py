@@ -31,7 +31,7 @@ def build_libadlb() -> str:
     its path."""
     return build_artifact(
         "libadlb.so",
-        ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
+        ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
          f"-I{_INCLUDE}", "-o", "{out}", _SRC, _FSRC],
         [_SRC, _FSRC, _HDR],
     )

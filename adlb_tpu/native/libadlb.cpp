@@ -7,31 +7,36 @@
 // batch-common prefix fetch, batch puts, Info queries, finalize/abort —
 // re-targeted from tagged MPI sends to the framework's TCP fabric.
 //
-// Threads: one acceptor + one reader per inbound connection feed a single
-// inbox (deque + condvar); the API itself is strictly request/response like
-// the reference's client (blocking MPI_Wait), so no other locking is needed.
+// Threads: none of its own. The thread that blocks in a call does the reads:
+// it sleeps in poll() over the listener and the inbound connections, accepts,
+// reads and decodes what arrives and returns the frame it waited for
+// (poll_inbound). Inbound traffic therefore makes progress only inside
+// library calls, as the reference's client makes none outside MPI calls:
+// between calls an abort, a pipelined put's response or an app message waits
+// in the kernel's socket buffers. The API is strictly request/response like
+// the reference's client (blocking MPI_Wait) and is not thread-safe.
 // Little-endian hosts assumed (as is the Python struct '<' side).
 
 #include <arpa/inet.h>
 #include <netdb.h>
 #include <netinet/in.h>
 #include <netinet/tcp.h>
+#include <poll.h>
 #include <sys/socket.h>
 #include <unistd.h>
 
-#include <atomic>
-#include <chrono>
-#include <condition_variable>
+#include <algorithm>
+#include <cerrno>
 #include <cstdarg>
 #include <cstdint>
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
+#include <ctime>
 #include <deque>
 #include <map>
-#include <mutex>
 #include <string>
-#include <thread>
+#include <string_view>
 #include <vector>
 
 #include "../../include/adlb/adlb.h"
@@ -141,6 +146,7 @@ struct Encoder {
   uint16_t nfields = 0;
 
   explicit Encoder(uint16_t tag, int32_t src) {
+    put_u32(body, 0);  // the frame's length prefix, backpatched in finish()
     body.push_back((char)BINARY_MAGIC);
     put_u16(body, tag);
     put_i32(body, src);
@@ -169,13 +175,17 @@ struct Encoder {
     nfields++;
     return *this;
   }
+  // The whole frame, length prefix and all: one send, one packet, one
+  // wake-up of the peer's reading thread.
   std::string finish() {
-    memcpy(&body[7], &nfields, 2);  // offset of nfields in the header
+    uint32_t len = (uint32_t)(body.size() - 4);
+    memcpy(&body[0], &len, 4);
+    memcpy(&body[11], &nfields, 2);  // offset of nfields in the header
     return std::move(body);
   }
 };
 
-bool decode(const std::string &body, Msg *out) {
+bool decode(std::string_view body, Msg *out) {
   if (body.size() < 9 || (uint8_t)body[0] != BINARY_MAGIC) return false;
   size_t off = 1;
   auto need = [&](size_t n) { return off + n <= body.size(); };
@@ -254,6 +264,13 @@ bool decode(const std::string &body, Msg *out) {
 
 // ---- context --------------------------------------------------------------
 
+struct InConn {
+  int fd = -1;
+  std::string buf;  // bytes received and not yet decoded: at most one
+                    // partial frame once parse_frames has run
+  bool established = false;  // has delivered a decodable frame
+};
+
 struct Ctx {
   int rank = -1, nranks = 0, nservers = 0, num_app_ranks = 0, home = -1;
   int aprintf_flag = 0;
@@ -261,14 +278,10 @@ struct Ctx {
   std::vector<std::pair<std::string, int>> addr;  // per rank
 
   int listen_fd = -1;
-  std::thread acceptor;
-  std::vector<std::thread> readers;
-  std::mutex mu;
-  std::condition_variable cv;
-  std::deque<Msg> inbox;
+  std::vector<InConn> in;   // inbound connections, read by whoever waits
+  std::deque<Msg> inbox;    // decoded frames no call has looked at yet
   std::deque<Msg> app_inbox;  // stashed AM_APP frames (the app_comm channel)
   std::map<int, int> out_fds;
-  std::atomic<bool> closed{false};
 
   int rr = 0;       // round-robin cursor over servers
   bool route_home = false;  // ADLB_PUT_ROUTING=home: untargeted puts -> home
@@ -293,75 +306,43 @@ void die(const char *fmt, ...) {
 
 // ---- sockets --------------------------------------------------------------
 
-bool read_exact(int fd, void *p, size_t n) {
-  char *c = (char *)p;
-  while (n > 0) {
-    ssize_t r = read(fd, c, n);
-    if (r <= 0) return false;
-    c += r;
-    n -= (size_t)r;
-  }
-  return true;
-}
-
-// Body reads grow with the bytes actually received instead of
-// pre-allocating the advertised length: a connection that sends only a
-// large length prefix (then stalls) must not pin that memory in recv.
-bool read_body(int fd, uint32_t n, std::string *body) {
-  body->clear();
-  char chunk[65536];
-  while (body->size() < n) {
-    size_t want = n - body->size();
-    if (want > sizeof chunk) want = sizeof chunk;
-    ssize_t r = recv(fd, chunk, want, 0);
-    if (r <= 0) return false;
-    body->append(chunk, (size_t)r);
-  }
-  return true;
-}
-
-bool write_all(int fd, const void *p, size_t n) {
-  const char *c = (const char *)p;
-  while (n > 0) {
-    ssize_t r = write(fd, c, n);
-    if (r <= 0) return false;
-    c += r;
-    n -= (size_t)r;
-  }
-  return true;
-}
-
-void reader_loop(int fd) {
-  // Robustness policy (mirrors serverd.cpp): a connection that has never
-  // delivered a decodable frame is untrusted — garbage on it closes the
-  // connection without touching the world (a stray scanner must not kill
-  // a rank, and rank death kills the whole world). Once a frame has
-  // decoded, the peer is a real rank: corruption on an ESTABLISHED
-  // stream is a protocol error and fails fast — dropping it instead
-  // could discard the response a blocking caller is parked on, turning
-  // a diagnosable failure into a silent distributed hang.
+// Decode every complete frame of c.buf into g->inbox, keeping a partial
+// tail. Returns false when the connection must close.
+//
+// Robustness policy (mirrors serverd.cpp): a connection that has never
+// delivered a decodable frame is untrusted — garbage on it closes the
+// connection without touching the world (a stray scanner must not kill
+// a rank, and rank death kills the whole world). Once a frame has
+// decoded, the peer is a real rank: corruption on an ESTABLISHED
+// stream is a protocol error and fails fast — dropping it instead
+// could discard the response a blocking caller is parked on, turning
+// a diagnosable failure into a silent distributed hang.
+bool parse_frames(InConn &c) {
   static const uint32_t kMaxFrame = 1u << 28;  // 256 MB
-  bool established = false;
-  for (;;) {
+  size_t off = 0;
+  bool keep = true;
+  while (c.buf.size() - off >= 4) {
     uint32_t len;
-    if (!read_exact(fd, &len, 4)) break;
+    memcpy(&len, c.buf.data() + off, 4);
     if (len > kMaxFrame) {
-      // cap before resize(): a hostile 4 GB prefix must not become the
-      // allocation that kills this rank
-      if (established)
+      // cap before a byte of the body is buffered: a hostile 4 GB prefix
+      // must not become the allocation that kills this rank
+      if (c.established)
         die("frame length %u exceeds %u cap on an established connection",
             len, kMaxFrame);
       std::fprintf(stderr,
                    "[libadlb] frame length %u exceeds %u cap; closing "
                    "connection\n", len, kMaxFrame);
+      keep = false;
       break;
     }
-    std::string body;
-    if (!read_body(fd, len, &body)) break;
+    if (c.buf.size() - off - 4 < len) break;  // the rest has not arrived
+    std::string_view body(c.buf.data() + off + 4, len);
+    off += 4 + (size_t)len;
     Msg m;
     if (len == 0 || (uint8_t)body[0] != BINARY_MAGIC) {
       if (len > 0 && (uint8_t)body[0] == 0x80 &&
-          body.find("adlb_tpu") != std::string::npos) {
+          body.find("adlb_tpu") != std::string_view::npos) {
         // pickle protocol-2+ magic AND the pickled Msg's embedded module
         // path: a Python server that has not yet learned this rank is a
         // binary peer pickles its frames, and the only unsolicited
@@ -370,49 +351,109 @@ void reader_loop(int fd) {
         // synthesizing a fatal abort; test_codec.py pins the invariant.)
         m.tag = T_TA_ABORT;
         m.ints[F_CODE] = ADLB_ERROR;
-      } else if (!established) {
+      } else if (!c.established) {
         std::fprintf(stderr,
                      "[libadlb] closing connection after non-binary "
                      "frame (%u B)\n", len);
+        keep = false;
         break;
       } else {
         die("non-binary frame (%u bytes) on an established connection",
             len);
       }
     } else if (!decode(body, &m)) {
-      if (!established) {
+      if (!c.established) {
         std::fprintf(stderr,
                      "[libadlb] closing connection after undecodable "
                      "first frame (%u B) — stray connection, or a "
                      "version-skewed peer (if a caller now hangs, "
                      "rebuild both sides from one tree)\n", len);
+        keep = false;
         break;
       }
       die("undecodable binary frame (%u bytes) from a live peer", len);
     } else {
-      established = true;
+      c.established = true;
     }
-    {
-      std::lock_guard<std::mutex> lk(g->mu);
-      g->inbox.push_back(std::move(m));
-    }
-    g->cv.notify_all();
+    g->inbox.push_back(std::move(m));
   }
-  close(fd);
+  c.buf.erase(0, off);
+  return keep;
 }
 
-void accept_loop() {
+// One read of what has arrived on c, never blocking, then parse. The buffer
+// grows with the bytes actually received, never with the advertised length:
+// a connection that sends a large length prefix and then stalls pins
+// neither that memory nor the calling thread. False at EOF, error or
+// garbage: the caller closes the connection.
+bool read_conn(InConn &c) {
+  char chunk[65536];
+  ssize_t r = recv(c.fd, chunk, sizeof chunk, MSG_DONTWAIT);
+  if (r < 0 && (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR))
+    return true;
+  if (r <= 0) return false;
+  c.buf.append(chunk, (size_t)r);
+  return parse_frames(c);
+}
+
+// The library's one wait. Sleeps in poll() over the listener, every inbound
+// connection and (optionally) one outbound socket a send is stuck on;
+// accepts, reads and decodes whatever is ready into g->inbox. With `block`
+// it returns once `wfd` is writable or, given none, once the inbox holds a
+// frame; without, it takes what is there now and returns.
+void poll_inbound(bool block, int wfd = -1) {
+  static std::vector<struct pollfd> pfds;
   for (;;) {
-    int fd = accept(g->listen_fd, nullptr, nullptr);
-    if (fd < 0) {
-      if (g->closed.load()) return;
+    pfds.clear();
+    pfds.push_back({g->listen_fd, POLLIN, 0});
+    for (const InConn &c : g->in) pfds.push_back({c.fd, POLLIN, 0});
+    if (wfd >= 0) pfds.push_back({wfd, POLLOUT, 0});
+    int n = poll(pfds.data(), pfds.size(), block ? -1 : 0);
+    if (n < 0 && errno != EINTR) die("poll: %s", strerror(errno));
+    if (n > 0) {
+      size_t nconn = g->in.size();
+      for (size_t i = 0; i < nconn; i++) {
+        InConn &c = g->in[i];
+        if (pfds[1 + i].revents != 0 && !read_conn(c)) {
+          close(c.fd);
+          c.fd = -1;
+        }
+      }
+      g->in.erase(std::remove_if(g->in.begin(), g->in.end(),
+                                 [](const InConn &c) { return c.fd < 0; }),
+                  g->in.end());
+      if (pfds[0].revents != 0) {
+        for (;;) {  // the listener is non-blocking: take all that wait
+          int fd = accept(g->listen_fd, nullptr, nullptr);
+          if (fd < 0) break;
+          g->in.emplace_back();
+          g->in.back().fd = fd;
+        }
+      }
+      if (wfd >= 0 && pfds.back().revents != 0) return;
+    }
+    if (!block || (wfd < 0 && !g->inbox.empty())) return;
+    // woke for a connection, a partial frame or a signal: sleep again
+  }
+}
+
+// A send that would block never stops this rank's reads: while the socket
+// is full the thread waits in poll_inbound, so two ranks sending each other
+// more than the socket buffers hold both get through.
+bool write_all(int fd, const void *p, size_t n) {
+  const char *c = (const char *)p;
+  while (n > 0) {
+    ssize_t r = send(fd, c, n, MSG_DONTWAIT | MSG_NOSIGNAL);
+    if (r < 0 && (errno == EAGAIN || errno == EWOULDBLOCK)) {
+      poll_inbound(true, fd);
       continue;
     }
-    int one = 1;
-    setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof one);
-    std::lock_guard<std::mutex> lk(g->mu);
-    g->readers.emplace_back(reader_loop, fd);
+    if (r < 0 && errno == EINTR) continue;
+    if (r <= 0) return false;
+    c += r;
+    n -= (size_t)r;
   }
+  return true;
 }
 
 int connect_to(int dest) {
@@ -443,26 +484,22 @@ int connect_to(int dest) {
 }
 
 void send_msg(int dest, Encoder &enc) {
-  std::string body = enc.finish();
-  uint32_t len = (uint32_t)body.size();
+  std::string frame = enc.finish();
   auto it = g->out_fds.find(dest);
   int fd = it == g->out_fds.end() ? -1 : it->second;
   if (fd < 0) {
     fd = connect_to(dest);
     g->out_fds[dest] = fd;
   }
-  if (!write_all(fd, &len, 4) || !write_all(fd, body.data(), body.size())) {
+  if (!write_all(fd, frame.data(), frame.size())) {
     close(fd);
     fd = connect_to(dest);  // one reconnect attempt
     g->out_fds[dest] = fd;
-    if (!write_all(fd, &len, 4) || !write_all(fd, body.data(), body.size()))
+    if (!write_all(fd, frame.data(), frame.size()))
       die("send to rank %d failed", dest);
   }
 }
 
-// Blocks until a frame with `want` arrives.  TA_ABORT terminates the process
-// (the reference client dies inside MPI_Abort in the same situation,
-// reference src/adlb.c:3165-3176).
 // ---- pipelined puts (iput; no reference analogue — upstream's Put is one
 // synchronous round trip per unit, src/adlb.c:2811-2843). Requests carry a
 // put_id echoed in the response; settle out of band, replaying rejects at
@@ -495,7 +532,7 @@ static void send_iput(int64_t id, const PendingPut &pp) {
   send_msg(pp.server, e);
 }
 
-static void settle_put(const Msg &m) {  // called with g->mu held
+static void settle_put(const Msg &m) {
   int64_t id = m.geti(F_PUT_ID);
   auto it = pending_puts.find(id);
   if (it == pending_puts.end()) return;
@@ -503,9 +540,9 @@ static void settle_put(const Msg &m) {  // called with g->mu held
   if (rc == ADLB_BACKOFF) {
     // backpressured pipelined put: replay toward the same server without
     // burning the reject budget, pacing by the server's carried hint
-    // (pump_resends sleeps it with the lock released — the fixed 2 ms
-    // resend pace would hammer the saturated server ~12x faster than it
-    // asked for, defeating the load shedding)
+    // (pump_resends sleeps it — the fixed 2 ms resend pace would hammer
+    // the saturated server ~12x faster than it asked for, defeating the
+    // load shedding)
     it->second.backoff_ms = (int)m.geti(F_RETRY_AFTER_MS, 25);
     resend_queue.push_back(id);
     return;
@@ -513,8 +550,9 @@ static void settle_put(const Msg &m) {  // called with g->mu held
   if (rc == ADLB_PUT_REJECTED && ++it->second.attempts <= 10) {
     int hint = (int)m.geti(F_HINT, -1);
     it->second.server = hint >= 0 ? hint : next_server();
-    // replay happens in pump_resends() with the lock RELEASED: sleeping or
-    // sending here would stall the reader threads (and abort delivery)
+    // replay happens in pump_resends(), once the frames already read have
+    // all been looked at: sleeping or sending here would hold up the
+    // responses (and an abort) queued behind this one
     resend_queue.push_back(id);
     return;
   }
@@ -532,38 +570,30 @@ static void settle_put(const Msg &m) {  // called with g->mu held
   pending_puts.erase(it);
 }
 
-// Replay rejected pipelined puts queued by settle_put. Call WITHOUT g->mu:
-// the pacing sleep and the (possibly connect-blocking) send must not stall
-// inbound frames.
+// Replay rejected pipelined puts queued by settle_put. Called between
+// drains of the inbox, never from inside one: the pacing sleep and the
+// (possibly connect-blocking) send come after every frame already read
+// has been handled.
 static void pump_resends() {
-  for (;;) {
-    int64_t id = -1;
-    PendingPut copy;
-    {
-      std::lock_guard<std::mutex> lk(g->mu);
-      while (!resend_queue.empty()) {
-        int64_t cand = resend_queue.front();
-        resend_queue.erase(resend_queue.begin());
-        auto it = pending_puts.find(cand);
-        if (it != pending_puts.end()) {
-          id = cand;
-          copy = it->second;
-          it->second.backoff_ms = 0;  // hint consumed by this replay
-          break;
-        }
-      }
-    }
-    if (id < 0) return;
+  while (!resend_queue.empty()) {
+    int64_t id = resend_queue.front();
+    resend_queue.erase(resend_queue.begin());
+    auto it = pending_puts.find(id);
+    if (it == pending_puts.end()) continue;
     // a backpressured put sleeps the server's retry-after hint; a
     // rejected-and-rerouted one paces like the synchronous retry loop
-    usleep(copy.backoff_ms > 0 ? (useconds_t)copy.backoff_ms * 1000
-                               : 2000);
-    send_iput(id, copy);
+    usleep(it->second.backoff_ms > 0
+               ? (useconds_t)it->second.backoff_ms * 1000
+               : 2000);
+    it->second.backoff_ms = 0;  // hint consumed by this replay
+    send_iput(id, it->second);
   }
 }
 
 // Handle a frame that is not an awaited protocol response: abort frames
-// terminate, app_comm traffic is stashed, anything else is fatal.
+// terminate (the reference client dies inside MPI_Abort in the same
+// situation, reference src/adlb.c:3165-3176), app_comm traffic is stashed,
+// anything else is fatal.
 void dispatch_passive(Msg m) {
   if (m.tag == T_TA_ABORT) {
     int code = (int)m.geti(F_CODE, ADLB_ERROR);
@@ -582,11 +612,13 @@ void dispatch_passive(Msg m) {
   die("unexpected tag %u outside a pending request", m.tag);
 }
 
+// Blocks until a frame with `want` arrives, reading the sockets itself: the
+// thread that waits is the thread that reads, so a response costs one
+// wake-up on this side. Frames read on the way that are not the awaited
+// one are handled here, on the caller's thread.
 Msg wait_for(uint16_t want) {
   for (;;) {
-    {
-      std::unique_lock<std::mutex> lk(g->mu);
-      g->cv.wait(lk, [] { return !g->inbox.empty(); });
+    while (!g->inbox.empty()) {
       Msg m = std::move(g->inbox.front());
       g->inbox.pop_front();
       if (m.tag == want &&
@@ -594,7 +626,8 @@ Msg wait_for(uint16_t want) {
         return m;
       dispatch_passive(std::move(m));
     }
-    pump_resends();  // lock released: replays queued by settle_put
+    pump_resends();  // replays queued by settle_put
+    if (g->inbox.empty()) poll_inbound(true);
   }
 }
 
@@ -738,7 +771,7 @@ int ADLBP_Init(int num_servers, int use_debug_server, int aprintf_flag,
   g->route_home = (routing != nullptr && strcmp(routing, "home") == 0);
 
   // bind our listener at the advertised address
-  g->listen_fd = socket(AF_INET, SOCK_STREAM, 0);
+  g->listen_fd = socket(AF_INET, SOCK_STREAM | SOCK_NONBLOCK, 0);
   int one = 1;
   setsockopt(g->listen_fd, SOL_SOCKET, SO_REUSEADDR, &one, sizeof one);
   struct sockaddr_in sa = {};
@@ -747,8 +780,9 @@ int ADLBP_Init(int num_servers, int use_debug_server, int aprintf_flag,
   sa.sin_port = htons((uint16_t)g->addr[g->rank].second);
   if (bind(g->listen_fd, (struct sockaddr *)&sa, sizeof sa) != 0)
     die("cannot bind port %d", g->addr[g->rank].second);
-  if (listen(g->listen_fd, 64) != 0) die("listen failed");
-  g->acceptor = std::thread(accept_loop);
+  // connections wait here until this rank's next call into the library
+  // accepts them (poll_inbound): room for every rank of a large world
+  if (listen(g->listen_fd, 1024) != 0) die("listen failed");
 
   if (am_server) *am_server = 0;
   if (am_debug_server) *am_debug_server = 0;
@@ -1068,13 +1102,15 @@ int ADLBP_Finalize(void) {
   }
   Encoder e(T_FA_LOCAL_APP_DONE, g->rank);
   send_msg(g->home, e);
-  g->closed.store(true);
   for (auto &kv : g->out_fds) {
     shutdown(kv.second, SHUT_WR);  // FIN after data; no unread inbound
     close(kv.second);
   }
-  shutdown(g->listen_fd, SHUT_RDWR);
+  g->out_fds.clear();
+  for (InConn &c : g->in) close(c.fd);
+  g->in.clear();
   close(g->listen_fd);
+  g->listen_fd = -1;
   return ADLB_SUCCESS;
 }
 int ADLB_Finalize(void) {
@@ -1117,8 +1153,10 @@ int ADLB_App_send(int d, void *b, int l, int t) {
   return rc;
 }
 
-// drain frames already delivered while idle; call with g->mu held
-static void drain_inbox_locked() {
+// Take what the sockets hold now, without waiting, and handle it: outside
+// a pending request every frame is a passive one.
+static void drain_inbox() {
+  poll_inbound(false);
   while (!g->inbox.empty()) {
     Msg m = std::move(g->inbox.front());
     g->inbox.pop_front();
@@ -1128,8 +1166,7 @@ static void drain_inbox_locked() {
 
 int ADLBP_App_iprobe(int *src, int *apptag, int *len) {
   if (!g) return ADLB_ERROR;
-  std::unique_lock<std::mutex> lk(g->mu);
-  drain_inbox_locked();
+  drain_inbox();
   if (g->app_inbox.empty()) return 0;
   const Msg &m = g->app_inbox.front();
   if (src) *src = m.src;
@@ -1151,11 +1188,10 @@ int ADLB_App_iprobe(int *s_, int *t, int *l) {
 
 int ADLBP_App_recv(void *buf, int maxlen, int *src, int *apptag) {
   if (!g) return ADLB_ERROR;
-  std::unique_lock<std::mutex> lk(g->mu);
   for (;;) {
-    drain_inbox_locked();
+    drain_inbox();
     if (!g->app_inbox.empty()) break;
-    g->cv.wait(lk, [] { return !g->inbox.empty(); });
+    poll_inbound(true);
   }
   Msg m = std::move(g->app_inbox.front());
   g->app_inbox.pop_front();
@@ -1188,24 +1224,20 @@ int ADLBP_Iput(void *work_buf, int work_len, int target_rank, int answer_rank,
         "refcount must be exact)");
   if (target_rank >= 0 && target_rank >= g->num_app_ranks)
     die("Iput target rank %d is not an app rank", target_rank);
-  PendingPut copy;
-  int64_t id;
-  {
-    std::unique_lock<std::mutex> lk(g->mu);
-    drain_inbox_locked();  // settle delivered responses: stay bounded
-    PendingPut pp;
-    pp.payload.assign((const char *)work_buf, (size_t)work_len);
-    pp.work_type = work_type;
-    pp.prio = work_prio;
-    pp.target_rank = target_rank;
-    pp.answer_rank = answer_rank;
-    pp.attempts = 0;
-    pp.server = target_rank >= 0 ? home_server(target_rank) : next_server();
-    id = next_put_id++;
-    pending_puts[id] = pp;
-    copy = std::move(pp);
-  }
-  send_iput(id, copy);  // lock released: sends may block on connect
+  // settle delivered responses, to stay bounded: a look at the sockets is
+  // a system call, so every eighth put takes it (responses are some 40
+  // bytes; eight of them wait in the kernel meanwhile)
+  int64_t id = next_put_id++;
+  if (id % 8 == 0) drain_inbox();
+  PendingPut &pp = pending_puts[id];
+  pp.payload.assign((const char *)work_buf, (size_t)work_len);
+  pp.work_type = work_type;
+  pp.prio = work_prio;
+  pp.target_rank = target_rank;
+  pp.answer_rank = answer_rank;
+  pp.attempts = 0;
+  pp.server = target_rank >= 0 ? home_server(target_rank) : next_server();
+  send_iput(id, pp);
   pump_resends();
   return ADLB_SUCCESS;
 }
@@ -1221,17 +1253,13 @@ int ADLB_Iput(void *b, int l, int t, int a, int w, int p) {
 int ADLBP_Flush_puts(void) {
   if (!g) return ADLB_ERROR;
   for (;;) {
-    {
-      std::unique_lock<std::mutex> lk(g->mu);
-      drain_inbox_locked();
-      if (pending_puts.empty() && resend_queue.empty()) break;
-      if (resend_queue.empty())
-        g->cv.wait_for(lk, std::chrono::milliseconds(100),
-                       [] { return !g->inbox.empty(); });
-    }
-    pump_resends();  // lock released: pacing + sends must not stall readers
+    drain_inbox();
+    pump_resends();
+    if (pending_puts.empty()) break;
+    // every put still pending has a response on its way: sleep in the
+    // kernel until a frame arrives (a replay's send may have read some)
+    if (g->inbox.empty()) poll_inbound(true);
   }
-  std::lock_guard<std::mutex> lk(g->mu);
   int failed = failed_puts;
   bool nmw = failed_nmw;
   failed_puts = 0;

@@ -31,12 +31,14 @@
 #include <dirent.h>
 #include <netinet/in.h>
 #include <netinet/tcp.h>
+#include <sys/epoll.h>
 #include <sys/socket.h>
+#include <sys/uio.h>
 #include <unistd.h>
 
 #include <algorithm>
+#include <cerrno>
 #include <cmath>
-#include <condition_variable>
 #include <cstdarg>
 #include <cstdint>
 #include <cstdio>
@@ -46,12 +48,11 @@
 #include <iostream>
 #include <array>
 #include <map>
-#include <mutex>
 #include <set>
 #include <sstream>
 #include <stdexcept>
 #include <string>
-#include <thread>
+#include <string_view>
 #include <unordered_map>
 #include <unordered_set>
 #include <vector>
@@ -359,8 +360,11 @@ void put_i32(std::string& out, int32_t v) { out.append((const char*)&v, 4); }
 void put_i64(std::string& out, int64_t v) { out.append((const char*)&v, 8); }
 void put_f64(std::string& out, double v) { out.append((const char*)&v, 8); }
 
+// The whole frame, 4-byte LE length prefix and all: one send, one packet,
+// one wake-up of the peer's reading thread.
 std::string encode(const NMsg& m) {
   std::string out;
+  put_u32(out, 0);  // length prefix, backpatched below
   out.push_back(char(0x01));  // BINARY_MAGIC
   put_u16(out, m.tag);
   put_i32(out, m.src);
@@ -401,17 +405,20 @@ std::string encode(const NMsg& m) {
         break;
     }
   }
+  uint32_t len = uint32_t(out.size() - 4);
+  std::memcpy(&out[0], &len, 4);
   return out;
 }
 
-// Malformed frames throw (the reader drops them and keeps serving, like
-// the Python TcpEndpoint) rather than die(): one garbage connection must
-// not take down a server that other ranks depend on.
+// Malformed frames throw (the endpoint closes a connection whose first
+// frame is one and keeps serving, like the Python TcpEndpoint) rather than
+// die(): one garbage connection must not take down a server that other
+// ranks depend on.
 struct FrameError : std::runtime_error {
   using std::runtime_error::runtime_error;
 };
 
-NMsg decode(const std::string& body) {
+NMsg decode(std::string_view body) {
   if (body.size() < 9 || body[0] != 0x01) throw FrameError("bad frame magic");
   NMsg m;
   size_t off = 1;
@@ -503,17 +510,29 @@ NMsg decode(const std::string& body) {
   return m;
 }
 
-// ---- endpoint: acceptor + readers -> inbox, lazy outbound -----------------
-// Same shape as the native client's transport (libadlb.cpp) and the Python
-// TcpEndpoint: one listener, one reader thread per inbound connection,
-// persistent outbound sockets, 4-byte LE length prefix per frame.
+// ---- endpoint: one thread, one epoll set, lazy outbound --------------------
+// The reactor owns every socket. Its one wait (wait_io, from recv) sleeps in
+// epoll over the listener, the inbound connections and whichever outbound
+// sockets have bytes queued; it accepts, reads, decodes and flushes on the
+// calling thread, so a request is dispatched and answered by the thread
+// that read it and the daemon has no other. Wire form as the native
+// client's transport (libadlb.cpp) and the Python TcpEndpoint: persistent
+// outbound sockets, 4-byte LE length prefix per frame.
+//
+// A send never blocks. Frames are queued per destination and handed to the
+// sockets when the reactor has dispatched what it had read (flush_pending);
+// what a socket does not take at once stays queued and goes when epoll
+// reports it writable, so two daemons shipping each other more than the
+// socket buffers hold both keep reading.
 
 class Endpoint {
  public:
   Endpoint() = default;
 
   int listen_any() {
-    lsock_ = socket(AF_INET, SOCK_STREAM, 0);
+    epfd_ = epoll_create1(0);
+    if (epfd_ < 0) die("epoll_create1: %s", strerror(errno));
+    lsock_ = socket(AF_INET, SOCK_STREAM | SOCK_NONBLOCK, 0);
     if (lsock_ < 0) die("socket: %s", strerror(errno));
     int one = 1;
     setsockopt(lsock_, SOL_SOCKET, SO_REUSEADDR, &one, sizeof(one));
@@ -523,11 +542,12 @@ class Endpoint {
     addr.sin_port = 0;
     if (bind(lsock_, (sockaddr*)&addr, sizeof(addr)) < 0)
       die("bind: %s", strerror(errno));
-    if (listen(lsock_, 64) < 0) die("listen: %s", strerror(errno));
+    // connections wait in the kernel until the reactor's next turn
+    if (listen(lsock_, 1024) < 0) die("listen: %s", strerror(errno));
     socklen_t len = sizeof(addr);
     getsockname(lsock_, (sockaddr*)&addr, &len);
     port_ = ntohs(addr.sin_port);
-    acceptor_ = std::thread([this] { accept_loop(); });
+    watch(EPOLL_CTL_ADD, lsock_, EPOLLIN, kListener, 0);
     return port_;
   }
 
@@ -536,187 +556,303 @@ class Endpoint {
   }
 
   void send(int dest, const NMsg& m) {
-    std::string body = encode(m);
-    std::string frame;
-    put_u32(frame, uint32_t(body.size()));
-    frame += body;
-    std::unique_lock<std::mutex> lk(out_mu_);
-    int& sock = out_socks_[dest];
-    if (sock == 0) sock = connect_to(dest);
-    if (sock < 0) {
+    OutConn& oc = out_[dest];
+    if (oc.fd < 0) oc.fd = connect_to(dest);
+    if (oc.fd < 0) {
       // peer unreachable after the retry window (shutdown races): drop this
       // frame loudly, but leave the slot retryable so a recovered peer is
       // reconnected on the next send instead of being black-holed forever
-      sock = 0;
       std::fprintf(stderr,
                    "[adlb_serverd] dropping frame tag %u to unreachable "
                    "rank %d\n", m.tag, dest);
       return;
     }
-    const char* p = frame.data();
-    size_t left = frame.size();
-    while (left > 0) {
-      ssize_t n = ::send(sock, p, left, MSG_NOSIGNAL);
-      if (n <= 0) {
-        close(sock);
-        sock = connect_to(dest);  // one reconnect attempt
-        if (sock < 0) return;
-        p = frame.data();
-        left = frame.size();
-        continue;
-      }
-      p += n;
-      left -= size_t(n);
+    oc.q.push_back(encode(m));
+    // It leaves in flush_pending(), which the reactor calls once it has
+    // dispatched what it had read and before every wait: one request's
+    // answer goes at once, the answers to one read of a pipelined peer go
+    // in one system call. A socket that is waiting for room keeps its
+    // order and goes when epoll says it is writable.
+    if (!oc.armed && !oc.pending) {
+      oc.pending = true;
+      pending_.push_back(dest);
     }
   }
 
-  // blocking receive with timeout (seconds); false on timeout
+  void flush_pending() {
+    for (int dest : pending_) {
+      OutConn& oc = out_[dest];
+      oc.pending = false;
+      flush(dest, oc);
+    }
+    pending_.clear();
+  }
+
+  // blocking receive with timeout (seconds); false on timeout, and also
+  // when the wait ended for something that is no whole frame yet (a new
+  // connection, part of a frame, a socket flushed): the caller's loop
+  // recomputes its deadline and comes back
   bool recv(NMsg* out, double timeout) {
-    std::unique_lock<std::mutex> lk(in_mu_);
     if (inbox_.empty()) {
-      in_cv_.wait_for(lk, std::chrono::duration<double>(timeout),
-                      [this] { return !inbox_.empty(); });
+      flush_pending();
+      wait_io(timeout);
     }
-    if (inbox_.empty()) return false;
-    *out = std::move(inbox_.front());
-    inbox_.pop_front();
-    return true;
+    return recv_now(out);
   }
 
+  // a frame already read off its socket, if there is one; no system call
   bool recv_now(NMsg* out) {
-    std::unique_lock<std::mutex> lk(in_mu_);
     if (inbox_.empty()) return false;
     *out = std::move(inbox_.front());
     inbox_.pop_front();
     return true;
   }
 
-  // received-but-unhandled frames: the TCP analogue of the reference's
-  // MPI unexpected-message-queue probe (src/adlb.c:3645-3719)
-  size_t backlog() {
-    std::unique_lock<std::mutex> lk(in_mu_);
-    return inbox_.size();
-  }
+  // read-but-unhandled frames: the TCP analogue of the reference's MPI
+  // unexpected-message-queue probe (src/adlb.c:3645-3719). What the
+  // reactor has not read yet waits in the kernel's buffers, uncounted.
+  size_t backlog() { return inbox_.size(); }
 
   void close_all() {
+    // frames still queued behind a full socket (the ring's last tokens,
+    // DS_END, an abort's fan-out) leave before the process does
+    flush_pending();
+    double deadline = monotonic() + 5.0;
+    while (unsent() && monotonic() < deadline) wait_io(deadline - monotonic());
     closed_ = true;
-    if (lsock_ >= 0) { shutdown(lsock_, SHUT_RDWR); close(lsock_); }
-    std::unique_lock<std::mutex> lk(out_mu_);
-    for (auto& kv : out_socks_)
-      if (kv.second > 0) { shutdown(kv.second, SHUT_WR); close(kv.second); }
+    if (lsock_ >= 0) close(lsock_);
+    for (auto& kv : out_)
+      if (kv.second.fd >= 0) {
+        shutdown(kv.second.fd, SHUT_WR);
+        close(kv.second.fd);
+      }
   }
 
  private:
-  void accept_loop() {
-    while (!closed_) {
-      int conn = accept(lsock_, nullptr, nullptr);
-      if (conn < 0) return;
-      std::thread([this, conn] { reader(conn); }).detach();
+  static constexpr uint32_t kMaxFrame = 1u << 28;  // 256 MB
+  enum Kind : uint64_t { kListener = 0, kInbound = 1, kOutbound = 2 };
+
+  struct InConn {
+    std::string buf;  // received, not yet decoded: at most a partial frame
+                      // once parse_frames has run
+    int32_t last_src = -1;
+    bool established = false;  // has delivered a decodable frame
+  };
+
+  struct OutConn {
+    int fd = -1;
+    std::deque<std::string> q;  // whole frames, head partly sent
+    size_t off = 0;             // bytes of q.front() the socket has taken
+    bool armed = false;         // in the epoll set, waiting for EPOLLOUT
+    bool pending = false;       // in pending_, waiting for flush_pending()
+  };
+
+  void watch(int op, int fd, uint32_t events, Kind kind, int id) {
+    epoll_event ev{};
+    ev.events = events;
+    ev.data.u64 = (uint64_t(kind) << 32) | uint32_t(id);
+    if (epoll_ctl(epfd_, op, fd, &ev) < 0)
+      die("epoll_ctl: %s", strerror(errno));
+  }
+
+  bool unsent() const {
+    for (const auto& kv : out_)
+      if (!kv.second.q.empty()) return true;
+    return false;
+  }
+
+  // The daemon's one wait: sleep until a socket is ready or `timeout`
+  // seconds pass, then do what each ready socket asks for. Level-triggered,
+  // one read a ready connection a turn: a flooding peer gets its 64 KB and
+  // the loop goes round, so periodic() keeps its deadlines.
+  void wait_io(double timeout) {
+    epoll_event evs[64];
+    if (timeout < 0) timeout = 0;
+    timespec ts;
+    ts.tv_sec = time_t(timeout);
+    ts.tv_nsec = long((timeout - double(ts.tv_sec)) * 1e9);
+    int n = -1;
+    if (have_pwait2_) {
+      n = epoll_pwait2(epfd_, evs, 64, &ts, nullptr);
+      if (n < 0 && errno == ENOSYS) have_pwait2_ = false;
+    }
+    if (!have_pwait2_)  // kernels before 5.11: whole milliseconds
+      n = epoll_wait(epfd_, evs, 64, int(std::ceil(timeout * 1e3)));
+    if (n < 0) {
+      if (errno == EINTR) return;
+      die("epoll wait: %s", strerror(errno));
+    }
+    for (int i = 0; i < n; ++i) {
+      int id = int(uint32_t(evs[i].data.u64));
+      switch (Kind(evs[i].data.u64 >> 32)) {
+        case kListener: accept_all(); break;
+        case kInbound: read_conn(id); break;
+        case kOutbound: {
+          auto it = out_.find(id);
+          if (it != out_.end() && it->second.armed) flush(id, it->second);
+          break;
+        }
+      }
     }
   }
 
-  void reader(int conn) {
-    // Robustness policy (mirrors libadlb.cpp's reader): garbage on a
-    // connection that has never delivered a decodable frame closes that
-    // connection and nothing else — a stray scanner must not kill a
-    // server other ranks depend on. Corruption on an ESTABLISHED stream
-    // is a protocol error between real ranks and fails fast: silently
-    // dropping a request would leave its sender parked forever.
-    // The length cap guards resize(): a hostile 4 GB prefix must not
-    // become the allocation that kills the daemon.
-    static constexpr uint32_t kMaxFrame = 1u << 28;  // 256 MB
-    int32_t last_src = -1;
-    bool established = false;
+  void accept_all() {
     for (;;) {
+      int conn = accept(lsock_, nullptr, nullptr);
+      if (conn < 0) return;
+      in_[conn];
+      watch(EPOLL_CTL_ADD, conn, EPOLLIN, kInbound, conn);
+    }
+  }
+
+  // One read of what has arrived, never blocking; every whole frame goes to
+  // the inbox in order. The buffer grows with the bytes actually received,
+  // never with the advertised length: a connection that sends a large
+  // length prefix and then stalls pins neither that memory nor the reactor.
+  void read_conn(int conn) {
+    auto it = in_.find(conn);
+    if (it == in_.end()) return;
+    InConn& c = it->second;
+    char chunk[65536];
+    ssize_t r = ::recv(conn, chunk, sizeof chunk, MSG_DONTWAIT);
+    if (r < 0 && (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR))
+      return;
+    bool open = r > 0;
+    if (open) {
+      c.buf.append(chunk, size_t(r));
+      open = parse_frames(c);
+    }
+    if (open) return;
+    // EOF after the peer's frames: synthetic in-order signal so the
+    // reactor can tell a finalized peer from a dead one (the reference's
+    // failure model is rank-death-kills-job, src/adlb.c:2508-2526)
+    if (c.last_src >= 0 && !closed_) {
+      NMsg eof;
+      eof.tag = T_PEER_EOF;
+      eof.src = c.last_src;
+      inbox_.push_back(std::move(eof));
+    }
+    in_.erase(it);
+    close(conn);  // leaves the epoll set with its last descriptor
+  }
+
+  // Decode every whole frame of c.buf into the inbox, keeping a partial
+  // tail; false when the connection must close.
+  //
+  // Robustness policy (mirrors libadlb.cpp's): garbage on a connection
+  // that has never delivered a decodable frame closes that connection and
+  // nothing else — a stray scanner must not kill a server other ranks
+  // depend on. Corruption on an ESTABLISHED stream is a protocol error
+  // between real ranks and fails fast: silently dropping a request would
+  // leave its sender parked forever. The length cap comes before a byte
+  // of the body is buffered: a hostile 4 GB prefix must not become the
+  // allocation that kills the daemon.
+  bool parse_frames(InConn& c) {
+    size_t off = 0;
+    bool keep = true;
+    while (c.buf.size() - off >= 4) {
       uint32_t n;
-      if (!read_exact(conn, (char*)&n, 4)) break;
+      std::memcpy(&n, c.buf.data() + off, 4);
       if (n > kMaxFrame) {
-        if (established)
-          die("frame length %u from rank %d exceeds %u cap", n, last_src,
+        if (c.established)
+          die("frame length %u from rank %d exceeds %u cap", n, c.last_src,
               kMaxFrame);
         std::fprintf(stderr,
                      "[adlb_serverd] frame length %u exceeds %u cap; "
                      "closing connection\n", n, kMaxFrame);
+        keep = false;
         break;
       }
-      std::string body;
-      if (!read_body(conn, n, &body)) break;
+      if (c.buf.size() - off - 4 < n) break;  // the rest has not arrived
+      std::string_view body(c.buf.data() + off + 4, n);
+      off += 4 + size_t(n);
       if (n == 0 || body[0] != 0x01) {
-        if (established)
+        if (c.established)
           // never legitimate: Python peers raise rather than pickle to a
           // declared-binary destination, so mid-stream non-TLV is
           // corruption (or a misconfigured peer), and dropping it could
           // park its sender forever
-          die("non-binary frame (%u bytes) from rank %d", n, last_src);
+          die("non-binary frame (%u bytes) from rank %d", n, c.last_src);
         std::fprintf(stderr,
                      "[adlb_serverd] closing connection after non-binary "
                      "frame (%u B)\n", n);
+        keep = false;
         break;
       }
       NMsg m;
       try {
         m = decode(body);
       } catch (const FrameError& e) {
-        if (!established) {
+        if (!c.established) {
           std::fprintf(stderr,
                        "[adlb_serverd] closing connection after "
                        "undecodable first frame (%u B): %s — stray "
                        "connection, or a version-skewed peer (if a rank "
                        "now hangs, rebuild both sides from one tree)\n",
                        n, e.what());
+          keep = false;
           break;
         }
-        die("undecodable frame (%u bytes) from rank %d: %s", n, last_src,
+        die("undecodable frame (%u bytes) from rank %d: %s", n, c.last_src,
             e.what());
       }
-      established = true;
-      last_src = m.src;
-      {
-        std::lock_guard<std::mutex> lk(in_mu_);
-        inbox_.push_back(std::move(m));
-      }
-      in_cv_.notify_one();
+      c.established = true;
+      c.last_src = m.src;
+      inbox_.push_back(std::move(m));
     }
-    // EOF after the peer's frames: synthetic in-order signal so the
-    // reactor can tell a finalized peer from a dead one (the reference's
-    // failure model is rank-death-kills-job, src/adlb.c:2508-2526)
-    if (last_src >= 0 && !closed_) {
-      NMsg eof;
-      eof.tag = T_PEER_EOF;
-      eof.src = last_src;
-      {
-        std::lock_guard<std::mutex> lk(in_mu_);
-        inbox_.push_back(std::move(eof));
-      }
-      in_cv_.notify_one();
-    }
-    close(conn);
+    c.buf.erase(0, off);
+    return keep;
   }
 
-  static bool read_exact(int fd, char* buf, size_t n) {
-    size_t got = 0;
-    while (got < n) {
-      ssize_t r = ::recv(fd, buf + got, n - got, 0);
-      if (r <= 0) return false;
-      got += size_t(r);
+  // Hand the queue to the socket, up to 16 frames a system call, until it
+  // is empty or the socket is full; then the destination waits in the
+  // epoll set for EPOLLOUT. A socket error restarts the head frame from
+  // its first byte on a fresh connection, once; a second one drops what is
+  // queued, loudly.
+  void flush(int dest, OutConn& oc) {
+    bool retried = false;
+    while (!oc.q.empty()) {
+      iovec iov[16];
+      msghdr mh{};
+      mh.msg_iov = iov;
+      size_t skip = oc.off;
+      for (auto f = oc.q.begin(); f != oc.q.end() && mh.msg_iovlen < 16; ++f) {
+        iov[mh.msg_iovlen].iov_base = const_cast<char*>(f->data()) + skip;
+        iov[mh.msg_iovlen].iov_len = f->size() - skip;
+        mh.msg_iovlen += 1;
+        skip = 0;
+      }
+      ssize_t n = sendmsg(oc.fd, &mh, MSG_NOSIGNAL | MSG_DONTWAIT);
+      if (n < 0 && (errno == EAGAIN || errno == EWOULDBLOCK)) {
+        if (!oc.armed) watch(EPOLL_CTL_ADD, oc.fd, EPOLLOUT, kOutbound, dest);
+        oc.armed = true;
+        return;
+      }
+      if (n < 0 && errno == EINTR) continue;
+      if (n <= 0) {
+        close(oc.fd);  // leaves the epoll set with it
+        oc.armed = false;
+        oc.off = 0;
+        oc.fd = retried ? -1 : connect_to(dest);
+        retried = true;
+        if (oc.fd < 0) {
+          std::fprintf(stderr,
+                       "[adlb_serverd] dropping %zu queued frame(s) to "
+                       "rank %d: send failed\n", oc.q.size(), dest);
+          oc.q.clear();
+        }
+        continue;
+      }
+      oc.off += size_t(n);
+      while (!oc.q.empty() && oc.off >= oc.q.front().size()) {
+        oc.off -= oc.q.front().size();
+        oc.q.pop_front();
+      }
     }
-    return true;
-  }
-
-  // Body reads grow with the bytes actually received instead of
-  // pre-allocating the advertised length: a connection that sends only a
-  // large length prefix (and then stalls) must not pin the whole frame's
-  // memory while blocked in recv.
-  static bool read_body(int fd, uint32_t n, std::string* body) {
-    body->clear();
-    char chunk[65536];
-    while (body->size() < n) {
-      size_t want = std::min(sizeof chunk, size_t(n) - body->size());
-      ssize_t r = ::recv(fd, chunk, want, 0);
-      if (r <= 0) return false;
-      body->append(chunk, size_t(r));
+    if (oc.armed) {
+      epoll_ctl(epfd_, EPOLL_CTL_DEL, oc.fd, nullptr);
+      oc.armed = false;
     }
-    return true;
   }
 
   int connect_to(int dest) {
@@ -740,16 +876,16 @@ class Endpoint {
     }
   }
 
+  int epfd_ = -1;
   int lsock_ = -1;
   int port_ = 0;
   bool closed_ = false;
-  std::thread acceptor_;
+  bool have_pwait2_ = true;  // until the kernel says ENOSYS
   std::map<int, std::pair<std::string, int>> addr_map_;
-  std::map<int, int> out_socks_;
-  std::mutex out_mu_;
-  std::deque<NMsg> inbox_;
-  std::mutex in_mu_;
-  std::condition_variable in_cv_;
+  std::unordered_map<int, InConn> in_;   // by descriptor
+  std::unordered_map<int, OutConn> out_;  // by destination rank
+  std::vector<int> pending_;  // destinations with frames flush_pending() owes
+  std::deque<NMsg> inbox_;  // read and decoded, not yet dispatched
 };
 
 // ---- world / config -------------------------------------------------------
@@ -867,13 +1003,15 @@ class Server {
       double t0 = monotonic();
       if (got) {
         dispatch(m);
-        // bounded drain before paying the poll timeout again
+        // bounded drain of what the last reads brought: periodic() keeps
+        // its deadlines under a flood, and the answers leave together
         for (int i = 0; i < 128 && !done_; ++i) {
           if (monotonic() >= deadline) break;
           NMsg m2;
           if (!ep_->recv_now(&m2)) break;
           dispatch(m2);
         }
+        ep_->flush_pending();
       }
       stats_[K_LOOP_TOP_TIME] += monotonic() - t0;
     }
@@ -3052,7 +3190,7 @@ int main() {
   server.run();
   server.notify_balancer_end();
   server.print_stats();
-  ep.close_all();
-  // readers may still be blocked in recv; exit hard after stats are out
+  ep.close_all();  // flushes what is still queued, then closes
+  // stats are out and the sockets shut: skip the destructors
   std::_Exit(server.aborted() ? 2 : 0);
 }

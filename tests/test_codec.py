@@ -62,7 +62,7 @@ def test_wire_ids_total_and_unique():
 
 
 def test_pickled_abort_carries_module_path():
-    """The C client (libadlb.cpp reader_loop) honors a pickled frame as
+    """The C client (libadlb.cpp parse_frames) honors a pickled frame as
     the TA_ABORT fan-out only when the body contains the pickled Msg's
     module path — this pins the invariant that heuristic depends on, so
     a module rename fails here instead of silently breaking abort
