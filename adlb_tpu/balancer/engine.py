@@ -29,22 +29,11 @@ compensated at enactment (holders validate against live state).
 
 from __future__ import annotations
 
-import collections
 import time
 from typing import Optional
 
 from adlb_tpu.balancer.jobdim import req_job, task_job
 from adlb_tpu.runtime.trace import span
-
-# Plan-age samples: for every round that produced output, the age of the
-# OLDEST snapshot the plan was computed from (seconds between that
-# state's capture and the plan being handed to the transport). This is
-# the end-to-end staleness the snapshot->solve->enact pipeline delivers —
-# the quantity the reference's design fixes at qmstat_interval x ring
-# hops (reference src/adlb.c:165,1705-1757) and this architecture keeps
-# event-driven. Module-level so benches can read it across whichever
-# engines (in-server threads, sidecar) a world spawned in-process.
-_PLAN_AGES: "collections.deque[float]" = collections.deque(maxlen=4096)
 
 
 #: solver facts of a server that hosts no planner (steal mode, or a tpu
@@ -55,12 +44,6 @@ NO_PLANNER = {
     "device_count": 0, "memory_peak_bytes": 0, "device_solves": 0,
     "host_solves": 0, "device_failures": 0,
 }
-
-
-def drain_plan_ages() -> list:
-    out = list(_PLAN_AGES)
-    _PLAN_AGES.clear()
-    return out
 
 
 def round_gap(min_gap: float, matches, migrations) -> float:
@@ -164,19 +147,26 @@ class PlanEngine:
                 **kw,
             )
         self.max_malloc_per_server = max_malloc_per_server
-        # per-instance overrides of the pump constants (Config knobs)
-        if lookahead is not None:
-            self.LOOKAHEAD = lookahead
-        if look_max is not None:
-            self.LOOK_MAX = look_max
-        if grow_window is not None:
-            self.LOOK_GROW_WINDOW = grow_window
-        if inflow_ttl is not None:
-            self.INFLOW_TTL = inflow_ttl
-        if inflow_min_age is not None:
-            self.INFLOW_MIN_AGE = inflow_min_age
+        # per-instance overrides of the pump constants below (the class
+        # attributes are the one statement of their values; the parity,
+        # fuzz and span tests build engines with others)
+        for attr, v in (("LOOKAHEAD", lookahead), ("LOOK_MAX", look_max),
+                        ("LOOK_GROW_WINDOW", grow_window),
+                        ("INFLOW_TTL", inflow_ttl),
+                        ("INFLOW_MIN_AGE", inflow_min_age)):
+            if v is None:
+                continue
+            if v < 0:
+                raise ValueError(f"{attr.lower()} must be >= 0")
+            setattr(self, attr, v)
+        # a transit floor above the credit TTL cannot be honored (TTL
+        # expiry would silently override the min-age guarantee)
         if self.INFLOW_MIN_AGE > self.INFLOW_TTL:
             raise ValueError("inflow_min_age must be <= inflow_ttl")
+        # look_max below the lookahead floor would let _touch_window
+        # decay a destination's window under its own floor — with
+        # look_max=0 the window (and thus need) pins to 0 and migrations
+        # to that destination are silently disabled forever
         if self.LOOK_MAX < max(1, self.LOOKAHEAD):
             raise ValueError("look_max must be >= max(1, lookahead)")
         # Plan ledgers: when each requester/task was last planned. The
@@ -233,6 +223,28 @@ class PlanEngine:
         # "workers waited here recently" signal the anticipatory pump is
         # gated on (see _plan_migrations)
         self._last_parked: dict[int, float] = {}
+
+    @classmethod
+    def from_config(cls, world, cfg, metrics=None) -> "PlanEngine":
+        """The one mapping from a world and its ``Config`` to an engine:
+        both planner hosts (the in-server balancer thread and the
+        sidecar) build theirs here. ``world`` and ``cfg`` are only read
+        (``runtime.world.WorldSpec`` / ``Config`` as a rule): this
+        module imports neither."""
+        return cls(
+            types=world.types,
+            nservers=world.nservers,
+            max_tasks=cfg.balancer_max_tasks,
+            max_requesters=cfg.balancer_max_requesters,
+            backend=cfg.solver_backend,
+            max_malloc_per_server=cfg.max_malloc_per_server,
+            use_mesh=cfg.balancer_mesh == "auto",
+            host_threshold_reqs=cfg.solver_host_threshold,
+            auction=cfg.balancer_auction,
+            max_jobs=cfg.balancer_max_jobs,
+            job_weights=cfg.job_weights,
+            metrics=metrics,
+        )
 
     def set_job_weights(self, job_weights: Optional[dict]) -> bool:
         """Live fair-share update (controller / POST /jobs/<id>): fold
@@ -495,17 +507,17 @@ class PlanEngine:
                 | {mv[0] for mv in migrations}
                 | {mv[1] for mv in migrations}  # deficit side
             )
+            # the plan's age: that of the OLDEST snapshot it stood on,
+            # from that state's capture to the plan's hand-off
             ages = [
                 t_planned - snapshots[r].get("stamp", t_planned)
                 for r in involved
                 if r in snapshots
             ]
-            if ages:
-                _PLAN_AGES.append(max(ages))
-                if self.metrics is not None:
-                    self.metrics.histogram("balancer_plan_age_s").observe(
-                        max(ages)
-                    )
+            if ages and self.metrics is not None:
+                self.metrics.histogram("balancer_plan_age_s").observe(
+                    max(ages)
+                )
         for src_rank, dest, _seqnos, _mid in migrations:
             self._rank_planned[src_rank] = t_planned
             self._rank_planned[dest] = t_planned

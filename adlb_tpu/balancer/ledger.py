@@ -37,9 +37,9 @@ walks rows in Python.
 
 Two interchangeable implementations behind one interface:
 
-* :class:`PyLedger` — the pre-existing pure-Python filter, extracted
-  verbatim.  Retained as the semantic twin: ``Config(host_ledger="py")``
-  selects it, and ``tests/test_ledger_parity.py`` fuzz-proves the
+* :class:`PyLedger` — the pure-Python filter, retained as the
+  semantic twin: ``PlanEngine(host_ledger="py")`` builds it, and
+  ``tests/test_ledger_parity.py`` fuzz-proves the
   vectorized ledger produces identical kept-requester / eligible-task
   sets (and therefore identical plans) across randomized delta /
   suppression / expiry / dead-rank sequences.
@@ -121,8 +121,7 @@ class SnapshotStore(dict):
     dedup-compacted change log, and membership changes (new rank, death)
     additionally bump ``member_ver``.  :meth:`ArrayLedger.sync` uses
     these to touch only the ranks that changed since its last sync —
-    killing the per-round O(servers) compare scan that was the 1k-parked
-    admission floor (MULTICHIP_r07) — while staying a plain dict for
+    no per-round O(servers) compare scan — while staying a plain dict for
     every other consumer (the ``host_ledger="py"`` twin, the sharded
     solver's stamp path, tests).
 
@@ -301,8 +300,8 @@ class PyLedger:
         self._freqs: dict = {}
         self._snapshots: dict = {}
         self._now = 0.0
-        # twin-side counters mirror the array ledger's surface so bench
-        # and smoke code can read them unconditionally
+        # twin-side counters mirror the array ledger's surface so the
+        # engine's gauges and plan_bench read either ledger alike
         self.patch_count = 0
         self.resync_count = 0
         self.last_sync_us = 0.0
@@ -507,7 +506,7 @@ class ArrayLedger:
         # is taken or dropped, so a consumer can skip its own O(S)
         # membership walk on the (vastly common) no-churn round
         self.member_gen = 1
-        # stats surfaced by bench / CI smoke / obs gauges
+        # stats surfaced by plan_bench, the CI smoke and the obs gauges
         self.patch_count = 0     # incremental per-server (re)builds
         self.resync_count = 0    # full rebuilds (cold + cadence)
         # task-side rebuilds by the shape the table arrived in, and the

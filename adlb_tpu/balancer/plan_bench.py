@@ -18,8 +18,7 @@ in the environment:
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python -m adlb_tpu.balancer.plan_bench --quick
 
-bench.py and scripts/sim_scale.py --plan-sweep shell out to this module,
-so a parent that must stay off JAX can.
+A parent that must stay off JAX runs this module as a child process.
 """
 
 from __future__ import annotations
@@ -33,8 +32,8 @@ import numpy as np
 #: (servers, max_tasks K, max_requesters R) ladder; the last row is the
 #: acceptance scale: 10,000 servers x 100 parked requesters each = 1M
 #: (--quick keeps the first, 1k and 10k rows: the smoke still covers
-#: the acceptance scale AND the 1k row the plan_round_1k_ms continuity
-#: key — guarded since BENCH_r06 — is derived from)
+#: the acceptance scale AND the 1k row the plan_round_1k_ms key is
+#: derived from)
 SCALES = [(64, 16, 16), (256, 16, 32), (1000, 16, 100), (10000, 16, 100)]
 TYPES = tuple(range(1, 9))
 DELTA_SERVERS = 8  # servers receiving a task burst per steady round
@@ -181,7 +180,7 @@ def run_sweep(scales=None, reps: int = 40, ndev: int = 8,
             "incrementally (exact, see balancer/distributed.py)."
         ),
     }
-    # compact scalar keys for scripts/bench_guard.py's raw-text scan
+    # compact scalar keys beside the rows
     for r in rows:
         if r["servers"] == 1000:
             out["plan_round_1k_ms"] = r["plan_round_p50_ms"]
@@ -344,7 +343,7 @@ def run_engine_sweep(scales=None, reps: int = 40) -> dict:
             "the O(S) scan kill."
         ),
     }
-    # compact scalar keys for scripts/bench_guard.py's raw-text scan
+    # compact scalar keys beside the rows
     for r in rows:
         if r["parked_reqs"] == 1000:
             out["admission_1k_ms"] = round(r["engine_round_us"] / 1e3, 3)
@@ -408,9 +407,8 @@ def main(argv=None) -> int:
 
 
 def _stamp_provenance(out) -> None:
-    """Core count + load on every MULTICHIP record (the r07 caveat made
-    policy): scheduler-bound numbers from a 1-core box must be readable
-    as such, and bench_guard skips-with-note across core-count changes."""
+    """Core count + load on every record: scheduler-bound numbers from
+    a 1-core box must be readable as such."""
     if isinstance(out, dict):
         import os as _os
 

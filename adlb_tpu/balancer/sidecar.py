@@ -159,30 +159,7 @@ def run_sidecar(world, cfg, ep, abort_event=None) -> dict:
     # traffic like any server
     metrics = Registry(rank=world.nranks)
     attach(ep, metrics)
-    engine = PlanEngine(
-        types=world.types,
-        metrics=metrics,
-        max_tasks=cfg.balancer_max_tasks,
-        max_requesters=cfg.balancer_max_requesters,
-        backend=cfg.solver_backend,
-        max_malloc_per_server=cfg.max_malloc_per_server,
-        use_mesh=cfg.balancer_mesh == "auto",
-        nservers=world.nservers,
-        host_threshold_reqs=cfg.solver_host_threshold,
-        lookahead=cfg.balancer_lookahead,
-        look_max=cfg.balancer_look_max,
-        grow_window=cfg.balancer_grow_window,
-        inflow_ttl=cfg.balancer_inflow_ttl,
-        inflow_min_age=cfg.balancer_inflow_min_age,
-        host_ledger=cfg.host_ledger,
-        auction=cfg.balancer_auction,
-        # job axis: the native plane advertises only the default
-        # namespace today (4-wide flat tasks), but the engine kwargs
-        # stay in lockstep with the in-server master so a multi-job
-        # config plans identically on either plane
-        max_jobs=cfg.balancer_max_jobs,
-        job_weights=cfg.job_weights,
-    )
+    engine = PlanEngine.from_config(world, cfg, metrics=metrics)
     # versioned snapshot table (balancer/ledger.py): the ledger's sync
     # touches only ranks whose snapshots changed since the last round.
     # The sidecar loop is single-threaded, so the engine reads the live

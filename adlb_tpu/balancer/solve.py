@@ -103,13 +103,6 @@ def _greedy_assign(
     return assign
 
 
-def _auction_assign(task_prio, task_type, req_mask, req_valid, rounds=6):
-    """Back-compat alias (the greedy scan superseded the bid auction, which
-    converged one-task-per-type-per-round under crowding)."""
-    del rounds
-    return _greedy_assign(task_prio, task_type, req_mask, req_valid)
-
-
 def _host_greedy(task_prio, task_type, req_mask, req_valid):
     """Numpy twin of :func:`_greedy_assign` — bit-identical semantics, used
     below a size threshold where an accelerator dispatch round-trip costs

@@ -60,7 +60,7 @@ def run_native_probe(
     cfg=None,
     timeout: float = 300.0,
 ):
-    """Shared bootstrap for the native benchmark probes
+    """Shared bootstrap for the native workload drivers
     (workloads/hotspot_native.py, workloads/trickle_native.py): force
     native servers, build ``examples/<example>``, run one C client per app
     rank, and raise on any nonzero client exit. Returns the per-rank
@@ -129,7 +129,7 @@ def probe_makespan(rows):
 def check_fetch_mode(rows, fetch: str, what: str, skip_first: bool = False):
     """Every consuming rank must report the REQUESTED fetch mode — a
     broken env plumbing falling back to single-unit would silently
-    mislabel the bench's batch rows.  ``skip_first`` skips a rank-0
+    report single fetches as a batch run's.  ``skip_first`` skips a rank-0
     producer/collector row that predates the field."""
     want = "batch" if fetch.startswith("batch") else "single"
     check = rows[1:] if skip_first else rows

@@ -46,8 +46,7 @@ Unretained tail journeys still feed one ``unit_total_s`` observation
 (that histogram IS the p99 estimator; the per-stage ``unit_stage_s``
 cells stay head-sampled-only — the unbiased baseline) and skip the
 journey-dict build — the hot-path cost of tail mode is spans + one
-fold, bounded by the ``trace_tail_overhead_ratio`` bench arm. Promoted
-journeys carry
+fold (not measured on the chip). Promoted journeys carry
 ``why=[...]`` and route to ``/trace/tails`` on the master (head
 journeys keep ``/trace/units``), plus a ``prof_win`` window-id range
 binding them to the continuous profiler's clock-aligned windows

@@ -40,10 +40,9 @@ server to start one owns it (and gossips it); later servers share the
 instance for phase markers only, so the fleet view counts each process
 exactly once.
 
-Overhead: one ``sys._current_frames()`` + a frame walk per tick. At
-19 Hz with ~10 threads x ~30 frames that is well under 0.1% of a core
-(the ``profile_overhead`` bench row bounds the end-to-end cost at
-<= 1.05x pop latency, same bar as the trace arms).
+Overhead: one ``sys._current_frames()`` + a frame walk per tick, at
+19 Hz over ~10 threads x ~30 frames (its end-to-end cost is not
+measured on the chip).
 """
 
 from __future__ import annotations

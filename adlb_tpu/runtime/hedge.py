@@ -23,9 +23,9 @@ Two structural properties the server hooks rely on:
 * **Backpressure-subordinate** — any overload signal at launch time
   (memory watermark, per-job quota, allocation failure) vetoes the
   hedge STICKILY for that straggler: a vetoed origin can never launch
-  later ("zero vetoed-then-launched", proven under the put-storm
-  bench). Budget and no-parked-taker vetoes are transient — the next
-  scan may retry them.
+  later ("zero vetoed-then-launched", ``tests/test_hedge.py``).
+  Budget and no-parked-taker vetoes are transient — the next scan may
+  retry them.
 
 The manager is pure bookkeeping (groups, buckets, veto set); all queue
 / lease / WAL side effects live in ``runtime/server.py`` so the hedge

@@ -112,26 +112,7 @@ class _BalancerWorker(threading.Thread):
         s = self.server
         from adlb_tpu.balancer.engine import PlanEngine
 
-        engine = PlanEngine(
-            types=s.world.types,
-            max_tasks=s.cfg.balancer_max_tasks,
-            max_requesters=s.cfg.balancer_max_requesters,
-            backend=s.cfg.solver_backend,
-            max_malloc_per_server=s.cfg.max_malloc_per_server,
-            use_mesh=s.cfg.balancer_mesh == "auto",
-            nservers=s.world.nservers,
-            host_threshold_reqs=s.cfg.solver_host_threshold,
-            lookahead=s.cfg.balancer_lookahead,
-            look_max=s.cfg.balancer_look_max,
-            grow_window=s.cfg.balancer_grow_window,
-            inflow_ttl=s.cfg.balancer_inflow_ttl,
-            inflow_min_age=s.cfg.balancer_inflow_min_age,
-            host_ledger=s.cfg.host_ledger,
-            auction=s.cfg.balancer_auction,
-            metrics=s.metrics,
-            max_jobs=s.cfg.balancer_max_jobs,
-            job_weights=s.cfg.job_weights,
-        )
+        engine = PlanEngine.from_config(s.world, s.cfg, metrics=s.metrics)
         s._engine = engine  # finalize_stats reads its solver facts
         from adlb_tpu.obs import profile as _profile
 
@@ -141,7 +122,7 @@ class _BalancerWorker(threading.Thread):
         # deltas, qmstat/hungry changes and failover patches) and fall
         # back to a slow insurance tick — an idle world runs ~4 rounds/s
         # instead of spinning through wake/solve cycles, and the sampler
-        # attributes waiting to "balancer_idle" so the parity profile's
+        # attributes waiting to "balancer_idle" so the profiler's
         # balancer_tick share measures ROUNDS, not thread lifetime.
         idle = s.cfg.balancer_idle_interval
         # the loop's spans (runtime/trace.py; the names are fixed,
@@ -1359,12 +1340,11 @@ class Server:
             # queue-depth gauges + bounded timelines, sampled on their
             # OWN cadence (Config(gauge_interval), 0.25 s default),
             # decoupled from the balancer tick: in tpu mode the state
-            # sync runs at balancer_interval (20 ms), and paying the
-            # gauge walk + its ctypes GIL crossings 50x/s on the reactor
-            # thread was a measured slice of the r01->r05 tpu pop-latency
-            # drift (see docs/pop_latency_r06.md). Observability loses
-            # nothing: the timelines still cover the same history,
-            # just at post-mortem resolution.
+            # sync runs at balancer_interval (20 ms), and the gauge
+            # walk with its ctypes GIL crossings does not belong on the
+            # reactor thread 50x/s. Observability loses nothing: the
+            # timelines still cover the same history, just at
+            # post-mortem resolution.
             self._next_gauge_sample = now + max(
                 interval, self.cfg.gauge_interval)
             wq_d, wq_avail, wq_bytes = self.wq.depth_sample()

@@ -1,9 +1,8 @@
 """Shared-memory ring fabric: the third transport, for co-located ranks.
 
 The TCP fabric pays the loopback stack (syscalls, softirq, per-frame
-wakeups) even when both ranks sit on one host — bench r05/r06 put the
-intra-host per-op floor at ~0.66-0.9 ms p50, transport-bound (ROADMAP
-item 4).  This module moves the same-host data plane into user space:
+wakeups) even when both ranks sit on one host.  This module moves the
+same-host data plane into user space:
 
 * one **SPSC byte ring** per direction per connected pair, living in a
   named shared-memory segment (a ``/dev/shm``-backed ``mmap`` — see

@@ -108,9 +108,8 @@ class Config:
     # the balancer worker is event-gated: it sleeps on its doorbell
     # (armed by puts, requester parks and qmstat deltas) and only falls
     # back to this slow insurance tick when no work signal arrives —
-    # an idle world pays ~4 ticks/s instead of 50 (the 20 ms tick was
-    # 8.3% of single-core samples on the tsp parity bench). 0 disables
-    # the insurance tick entirely (pure event-driven; not recommended)
+    # an idle world pays ~4 ticks/s instead of 50. 0 disables the
+    # insurance tick entirely (pure event-driven; not recommended)
     balancer_idle_interval: float = 0.25
     # untargeted put routing: "round_robin" spreads over servers (reference
     # src/adlb.c:2771-2773); "home" keeps work at the putter's home server
@@ -261,18 +260,6 @@ class Config:
     # shares, priorities stay the intra-job ordering. Live updates
     # ride POST /jobs/<id> {"weight": w}. None = all jobs neutral.
     job_weights: Optional[dict] = None
-    # Adaptive migration-pump knobs (balancer/engine.py): a server holding
-    # >= lookahead ready units per local consumer is never
-    # migration-deficient; a destination that re-triggers its deficit
-    # within grow_window seconds of the last shipped batch has its
-    # per-consumer window doubled (capped at look_max); in-flight batch
-    # credits survive at least inflow_min_age seconds and at most
-    # inflow_ttl. None = engine defaults.
-    balancer_lookahead: "Optional[int]" = None
-    balancer_look_max: "Optional[int]" = None
-    balancer_grow_window: "Optional[float]" = None
-    balancer_inflow_ttl: "Optional[float]" = None
-    balancer_inflow_min_age: "Optional[float]" = None
     # device solve implementation: "auto" = Pallas sweep kernel on TPU, XLA
     # scan elsewhere; explicit "xla"/"pallas" force one
     solver_backend: str = "auto"
@@ -292,13 +279,6 @@ class Config:
     # fuzz-proven exactly equal to. Only consulted when the mesh
     # solver is active (balancer_mesh="auto" on a multi-device host)
     balancer_auction: str = "device"
-    # host tier of the plan engine (balancer/ledger.py): "array" keeps
-    # parked requesters / snapshot tasks resident in numpy columns so
-    # round admission is array operations over the servers that
-    # changed; "py" is the pure-Python
-    # twin (exact reference semantics, fuzz-proven identical — an
-    # escape hatch, not a feature switch)
-    host_ledger: str = "array"
     trace: bool = False  # event tracing hooks (reference MPE shims);
     # since the obs unification this traces BOTH sides: client API spans
     # (pid 0) and server handler / balancer-round spans (pid 1) into one
@@ -440,8 +420,8 @@ class Config:
     aprintf_flag: bool = False  # stamped debug prints (src/adlb.c:3395-3417)
     # queue-depth gauge / timeline sampling cadence on the reactor tick
     # (floored at the state-sync interval): decoupled from the 20 ms
-    # tpu-mode balancer tick, whose per-tick gauge walk was a measured
-    # slice of the r01->r05 tpu pop-latency drift
+    # tpu-mode balancer tick, so the reactor does not walk the gauges
+    # on every tick
     gauge_interval: float = 0.25
     selfdiag_interval: float = 30.0  # server health dumps; 0 = off
     # (src/adlb.c:558-710; the reference hard-codes 30 s)
@@ -527,8 +507,6 @@ class Config:
             raise ValueError(f"unknown native_queues {self.native_queues!r}")
         if self.solver_backend not in ("auto", "xla", "pallas"):
             raise ValueError(f"unknown solver_backend {self.solver_backend!r}")
-        if self.host_ledger not in ("array", "py"):
-            raise ValueError(f"unknown host_ledger {self.host_ledger!r}")
         if self.server_impl not in ("python", "native"):
             raise ValueError(f"unknown server_impl {self.server_impl!r}")
         if self.elastic_scaleout not in ("off", "auto"):
@@ -680,39 +658,6 @@ class Config:
             raise ValueError("wal_max_bytes must be >= 0")
         if self.ops_dump_bytes < 0:
             raise ValueError("ops_dump_bytes must be >= 0")
-        # snapshot lists are flattened into binary-codec list fields whose
-        # element count is a u16 (4 entries per task, 3+ntypes per
-        # requester); keep a wide safety margin under 65535
-        for knob in ("balancer_lookahead", "balancer_look_max",
-                     "balancer_grow_window", "balancer_inflow_ttl",
-                     "balancer_inflow_min_age"):
-            v = getattr(self, knob)
-            if v is not None and v < 0:
-                raise ValueError(f"{knob} must be >= 0")
-        # the engine cannot honor a transit floor above the credit TTL
-        # (TTL expiry would silently override the min-age guarantee);
-        # literals = the engine defaults (balancer/engine.py INFLOW_TTL /
-        # INFLOW_MIN_AGE), not imported here to keep Config import-light
-        # look_max below the lookahead floor would let _touch_window decay
-        # a destination's window under its own floor — with look_max=0 the
-        # window (and thus need) pins to 0 and migrations to that
-        # destination are silently disabled forever
-        look = 8 if self.balancer_lookahead is None \
-            else self.balancer_lookahead
-        lmax = 512 if self.balancer_look_max is None \
-            else self.balancer_look_max
-        if lmax < max(1, look):
-            raise ValueError(
-                "balancer_look_max must be >= max(1, balancer_lookahead)"
-            )
-        ttl = 2.0 if self.balancer_inflow_ttl is None \
-            else self.balancer_inflow_ttl
-        age = 0.05 if self.balancer_inflow_min_age is None \
-            else self.balancer_inflow_min_age
-        if age > ttl:
-            raise ValueError(
-                "balancer_inflow_min_age must be <= balancer_inflow_ttl"
-            )
         if not (0 < self.balancer_max_jobs <= 16):
             # the composite type axis is max_jobs * len(types) solver
             # columns; 16 namespaces keeps the widened axis far from

@@ -68,8 +68,7 @@ class InfoKey(enum.IntEnum):
     # server-failover surface (Config(on_server_failure="failover")): how
     # many takeovers this server performed, units counted lost to
     # replication lag at takeover, and the last promotion's
-    # detection->promoted time in ms (the recovery-cost row bench.py
-    # records as failover_mttr_ms)
+    # detection->promoted time in ms
     NUM_FAILOVERS = 15
     FAILOVER_LOST = 16
     FAILOVER_MTTR_MS = 17

@@ -6,8 +6,9 @@
 Drives the system once through the entry points a user calls —
 ``spawn_world`` with Python servers, ``hotspot_native.run`` on the
 all-native plane — at the largest deployment the repo itself runs (the
-``n128b`` row of bench.py: 128 app ranks, 32 servers, 5,291 units, a
-65,536 x 8,192 solve), with every planning round forced onto the device,
+benchmark's ``hotspot-native-n128``, PERF.md §4: 128 app ranks, 32
+servers, 5,291 units, a 65,536 x 8,192 solve), with every planning
+round forced onto the device,
 and checks the answers: every unit delivered exactly once, device
 programs bit-identical to the numpy twin, and the planner's own account
 of which path answered (platform ``tpu``, compiled Pallas, no host
@@ -50,7 +51,7 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
 
-# the n128b deployment (bench.py): the largest world the repo runs
+# the hotspot-native-n128 deployment: the largest world the repo runs
 N128B = dict(n_tasks=5291, work_us=24000, num_app_ranks=128, nservers=32,
              fetch="batch:8")
 N128B_K, N128B_R = 2048, 256

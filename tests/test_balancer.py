@@ -461,8 +461,8 @@ def test_migration_spares_locally_demanded_unit():
 
 
 def test_pump_knobs_config_wiring():
-    """The adaptive-pump constants are per-instance Config knobs, not just
-    class constants."""
+    """The adaptive-pump constants are per-instance engine keywords, not
+    just class constants, and the engine checks them."""
     import pytest
 
     from adlb_tpu.balancer.engine import PlanEngine
@@ -474,17 +474,17 @@ def test_pump_knobs_config_wiring():
             eng.INFLOW_TTL, eng.INFLOW_MIN_AGE) == (3, 64, 0.5, 9.0, 0.2)
     # class defaults untouched
     assert PlanEngine.LOOKAHEAD == 8
+    def mk(**kw):
+        return PlanEngine(types=(T1,), max_tasks=16, max_requesters=4, **kw)
+
     with pytest.raises(ValueError):
-        Config(balancer_lookahead=-1)
+        mk(lookahead=-1)
     # look_max below the lookahead floor would let window decay pin a
     # destination's need to 0, silently disabling migrations to it
     with pytest.raises(ValueError):
-        Config(balancer_look_max=0)
+        mk(look_max=0)
     with pytest.raises(ValueError):
-        Config(balancer_lookahead=16, balancer_look_max=4)
-    with pytest.raises(ValueError):
-        PlanEngine(types=(T1,), max_tasks=16, max_requesters=4,
-                   lookahead=16, look_max=4)
+        mk(lookahead=16, look_max=4)
 
 
 def test_matched_requester_not_double_withheld():
