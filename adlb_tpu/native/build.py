@@ -88,6 +88,9 @@ def build_artifact(name: str, cmd: Sequence[str],
 
 _WQ_SRC = os.path.join(_DIR, "wqcore.cpp")
 _WQ_HDR = os.path.join(_DIR, "wqcore.hpp")
+# the socket family between ranks of one host: read by serverd.cpp and by
+# libadlb.cpp (capi.build_libadlb), so both spell the name one way
+HOSTSOCK_HDR = os.path.join(_DIR, "hostsock.hpp")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -242,5 +245,5 @@ def ensure_serverd() -> str:
         "adlb_serverd",
         ["g++", "-O2", "-std=c++17", "-o", "{out}",
          _SERVERD_SRC],
-        [_SERVERD_SRC, _WQ_HDR],
+        [_SERVERD_SRC, _WQ_HDR, HOSTSOCK_HDR],
     )

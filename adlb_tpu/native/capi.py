@@ -16,7 +16,7 @@ import tempfile
 import threading
 from typing import Optional, Sequence
 
-from adlb_tpu.native.build import build_artifact
+from adlb_tpu.native.build import HOSTSOCK_HDR, build_artifact
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _REPO = os.path.dirname(os.path.dirname(_DIR))
@@ -33,7 +33,7 @@ def build_libadlb() -> str:
         "libadlb.so",
         ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
          f"-I{_INCLUDE}", "-o", "{out}", _SRC, _FSRC],
-        [_SRC, _FSRC, _HDR],
+        [_SRC, _FSRC, _HDR, HOSTSOCK_HDR],
     )
 
 

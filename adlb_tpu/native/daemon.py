@@ -83,8 +83,10 @@ def send_addrs(proc: subprocess.Popen, addr_map: dict) -> None:
 def _parse_trailer(lines):
     """Parse STATS/ABORT lines from an iterable; other output (STAT_APS
     chunks, diagnostics) passes through to stdout so the offline decoder
-    and the operator still see it. Returns (stats dict (int key -> float)
-    or None, abort code or None)."""
+    and the operator still see it. Returns (stats dict or None, abort code
+    or None). The stats' Info keys are ints (InfoKey -> float); what the
+    daemon reports by name beside them (``conns_unix``, ``conns_tcp``: the
+    connections it opened and accepted, by socket family) keeps its name."""
     import sys
 
     stats: Optional[dict] = None
@@ -93,7 +95,8 @@ def _parse_trailer(lines):
         line = line.rstrip("\n")
         stripped = line.strip()
         if stripped.startswith("STATS "):
-            stats = {int(k): v for k, v in json.loads(stripped[6:]).items()}
+            stats = {(int(k) if k.isdigit() else k): v
+                     for k, v in json.loads(stripped[6:]).items()}
         elif stripped.startswith("ABORT "):
             abort_code = int(stripped.split()[1])
         elif stripped:

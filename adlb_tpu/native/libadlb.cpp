@@ -40,6 +40,7 @@
 #include <vector>
 
 #include "../../include/adlb/adlb.h"
+#include "hostsock.hpp"
 
 namespace {
 
@@ -277,7 +278,10 @@ struct Ctx {
   std::vector<int> types;
   std::vector<std::pair<std::string, int>> addr;  // per rank
 
-  int listen_fd = -1;
+  int listen_fd = -1;       // TCP, at the port the rendezvous file gives
+  int listen_unix_fd = -1;  // the same port's name (hostsock.hpp), or -1
+  // connections opened and accepted, by family (ADLB_TRACE reports them)
+  int conns_unix = 0, conns_tcp = 0;
   std::vector<InConn> in;   // inbound connections, read by whoever waits
   std::deque<Msg> inbox;    // decoded frames no call has looked at yet
   std::deque<Msg> app_inbox;  // stashed AM_APP frames (the app_comm channel)
@@ -396,8 +400,8 @@ bool read_conn(InConn &c) {
   return parse_frames(c);
 }
 
-// The library's one wait. Sleeps in poll() over the listener, every inbound
-// connection and (optionally) one outbound socket a send is stuck on;
+// The library's one wait. Sleeps in poll() over the two listeners, every
+// inbound connection and (optionally) one outbound socket a send is stuck on;
 // accepts, reads and decodes whatever is ready into g->inbox. With `block`
 // it returns once `wfd` is writable or, given none, once the inbox holds a
 // frame; without, it takes what is there now and returns.
@@ -405,7 +409,9 @@ void poll_inbound(bool block, int wfd = -1) {
   static std::vector<struct pollfd> pfds;
   for (;;) {
     pfds.clear();
+    // a listener that is not there is -1, which poll() passes over
     pfds.push_back({g->listen_fd, POLLIN, 0});
+    pfds.push_back({g->listen_unix_fd, POLLIN, 0});
     for (const InConn &c : g->in) pfds.push_back({c.fd, POLLIN, 0});
     if (wfd >= 0) pfds.push_back({wfd, POLLOUT, 0});
     int n = poll(pfds.data(), pfds.size(), block ? -1 : 0);
@@ -414,7 +420,7 @@ void poll_inbound(bool block, int wfd = -1) {
       size_t nconn = g->in.size();
       for (size_t i = 0; i < nconn; i++) {
         InConn &c = g->in[i];
-        if (pfds[1 + i].revents != 0 && !read_conn(c)) {
+        if (pfds[2 + i].revents != 0 && !read_conn(c)) {
           close(c.fd);
           c.fd = -1;
         }
@@ -422,12 +428,14 @@ void poll_inbound(bool block, int wfd = -1) {
       g->in.erase(std::remove_if(g->in.begin(), g->in.end(),
                                  [](const InConn &c) { return c.fd < 0; }),
                   g->in.end());
-      if (pfds[0].revents != 0) {
-        for (;;) {  // the listener is non-blocking: take all that wait
-          int fd = accept(g->listen_fd, nullptr, nullptr);
+      for (int l = 0; l < 2; l++) {
+        if (pfds[l].revents == 0) continue;
+        for (;;) {  // the listeners are non-blocking: take all that wait
+          int fd = accept(pfds[l].fd, nullptr, nullptr);
           if (fd < 0) break;
           g->in.emplace_back();
           g->in.back().fd = fd;
+          ++(l == 0 ? g->conns_tcp : g->conns_unix);
         }
       }
       if (wfd >= 0 && pfds.back().revents != 0) return;
@@ -456,8 +464,14 @@ bool write_all(int fd, const void *p, size_t n) {
   return true;
 }
 
+// The family comes from the address map and the peer's answer alone
+// (hostsock.hpp): a destination on this rank's host is tried at its port's
+// Unix name first, on every attempt, so a peer that is not up yet (it
+// refuses both) never pins the pair on TCP; a peer with no such listener (a
+// Python rank) and a destination on another host get TCP.
 int connect_to(int dest) {
   auto &hp = g->addr[dest];
+  bool local = hostsock::same_host(hp.first, g->addr[g->rank].first);
   struct addrinfo hints = {}, *res = nullptr;
   hints.ai_family = AF_INET;
   hints.ai_socktype = SOCK_STREAM;
@@ -465,12 +479,18 @@ int connect_to(int dest) {
   snprintf(port, sizeof port, "%d", hp.second);
   // servers may come up after us: retry with backoff for ~15 s
   for (int attempt = 0; attempt < 60; attempt++) {
+    int ufd = local ? hostsock::connect_unix(hp.second) : -1;
+    if (ufd >= 0) {
+      g->conns_unix++;
+      return ufd;
+    }
     if (getaddrinfo(hp.first.c_str(), port, &hints, &res) == 0) {
       int fd = socket(res->ai_family, res->ai_socktype, res->ai_protocol);
       if (fd >= 0 && connect(fd, res->ai_addr, res->ai_addrlen) == 0) {
         int one = 1;
         setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof one);
         freeaddrinfo(res);
+        g->conns_tcp++;
         return fd;
       }
       if (fd >= 0) close(fd);
@@ -719,6 +739,14 @@ static void trace_flush(int rank) {
               "\"dur\":%.3f,\"pid\":%d,\"tid\":%d}",
               e.name, e.ts * 1e6, e.dur * 1e6, rank, rank);
   }
+  // the transport's counter: connections this rank opened and accepted,
+  // by socket family (hostsock.hpp)
+  fprintf(f,
+          "%s{\"name\":\"adlb:conns\",\"ph\":\"C\",\"ts\":%.3f,"
+          "\"pid\":%d,\"tid\":%d,\"args\":{\"conns_unix\":%d,"
+          "\"conns_tcp\":%d}}",
+          trace_events.empty() ? "" : ",", trace_now() * 1e6, rank, rank,
+          g ? g->conns_unix : 0, g ? g->conns_tcp : 0);
   fprintf(f, "]\n");
   fclose(f);
 }
@@ -770,6 +798,12 @@ int ADLBP_Init(int num_servers, int use_debug_server, int aprintf_flag,
   const char *routing = getenv("ADLB_PUT_ROUTING");
   g->route_home = (routing != nullptr && strcmp(routing, "home") == 0);
 
+  // the port's Unix name first (hostsock.hpp): whoever finds the TCP port
+  // open below has had the name to try
+  g->listen_unix_fd = hostsock::listen_unix(g->addr[g->rank].second, 1024);
+  if (g->listen_unix_fd < 0 && errno == EADDRINUSE)
+    die("cannot bind port %d: its Unix name is taken",
+        g->addr[g->rank].second);
   // bind our listener at the advertised address
   g->listen_fd = socket(AF_INET, SOCK_STREAM | SOCK_NONBLOCK, 0);
   int one = 1;
@@ -1111,11 +1145,17 @@ int ADLBP_Finalize(void) {
   g->in.clear();
   close(g->listen_fd);
   g->listen_fd = -1;
+  if (g->listen_unix_fd >= 0) close(g->listen_unix_fd);
+  g->listen_unix_fd = -1;
   return ADLB_SUCCESS;
 }
 int ADLB_Finalize(void) {
+  trace_api_entry();  // the user state ends here, not after the shutdown
+  // the record is written last: LOCAL_APP_DONE may open the last
+  // connection it counts
+  int rc = ADLBP_Finalize();
   trace_flush(g ? g->rank : -1);
-  return ADLBP_Finalize();
+  return rc;
 }
 
 int ADLBP_Abort(int code) {

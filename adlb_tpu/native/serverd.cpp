@@ -57,6 +57,7 @@
 #include <unordered_set>
 #include <vector>
 
+#include "hostsock.hpp"
 #include "wqcore.hpp"
 
 namespace {
@@ -517,7 +518,12 @@ NMsg decode(std::string_view body) {
 // calling thread, so a request is dispatched and answered by the thread
 // that read it and the daemon has no other. Wire form as the native
 // client's transport (libadlb.cpp) and the Python TcpEndpoint: persistent
-// outbound sockets, 4-byte LE length prefix per frame.
+// outbound stream sockets, 4-byte LE length prefix per frame. Two listeners,
+// the TCP port and that port's Unix name: a native rank of this host
+// connects to the name, everyone else to the port, and this end does the
+// same when it connects (hostsock.hpp says how the family is chosen from
+// the address map and the peer's answer; nothing selects it). Above the
+// socket the two families are one code path.
 //
 // A send never blocks. Frames are queued per destination and handed to the
 // sockets when the reactor has dispatched what it had read (flush_pending);
@@ -547,13 +553,26 @@ class Endpoint {
     socklen_t len = sizeof(addr);
     getsockname(lsock_, (sockaddr*)&addr, &len);
     port_ = ntohs(addr.sin_port);
-    watch(EPOLL_CTL_ADD, lsock_, EPOLLIN, kListener, 0);
+    watch(EPOLL_CTL_ADD, lsock_, EPOLLIN, kListener, lsock_);
+    // the port's Unix name, before the PORT hello tells anyone the port:
+    // whoever finds the port open has had the name to try
+    usock_ = hostsock::listen_unix(port_, 1024);
+    if (usock_ < 0 && errno == EADDRINUSE)
+      die("port %d: its Unix name is taken", port_);
+    if (usock_ >= 0) watch(EPOLL_CTL_ADD, usock_, EPOLLIN, kListener, usock_);
     return port_;
   }
 
   void set_addr(int rank, std::string host, int port) {
     addr_map_[rank] = {std::move(host), port};
   }
+
+  // whose host counts as this daemon's own (its own address-map entry)
+  void set_rank(int rank) { rank_ = rank; }
+
+  // connections opened and accepted, by family (the STATS trailer)
+  int conns_unix() const { return conns_unix_; }
+  int conns_tcp() const { return conns_tcp_; }
 
   void send(int dest, const NMsg& m) {
     OutConn& oc = out_[dest];
@@ -621,6 +640,7 @@ class Endpoint {
     while (unsent() && monotonic() < deadline) wait_io(deadline - monotonic());
     closed_ = true;
     if (lsock_ >= 0) close(lsock_);
+    if (usock_ >= 0) close(usock_);
     for (auto& kv : out_)
       if (kv.second.fd >= 0) {
         shutdown(kv.second.fd, SHUT_WR);
@@ -685,7 +705,7 @@ class Endpoint {
     for (int i = 0; i < n; ++i) {
       int id = int(uint32_t(evs[i].data.u64));
       switch (Kind(evs[i].data.u64 >> 32)) {
-        case kListener: accept_all(); break;
+        case kListener: accept_all(id); break;
         case kInbound: read_conn(id); break;
         case kOutbound: {
           auto it = out_.find(id);
@@ -696,12 +716,13 @@ class Endpoint {
     }
   }
 
-  void accept_all() {
+  void accept_all(int lsock) {
     for (;;) {
-      int conn = accept(lsock_, nullptr, nullptr);
+      int conn = accept(lsock, nullptr, nullptr);
       if (conn < 0) return;
       in_[conn];
       watch(EPOLL_CTL_ADD, conn, EPOLLIN, kInbound, conn);
+      ++(lsock == usock_ ? conns_unix_ : conns_tcp_);
     }
   }
 
@@ -855,11 +876,25 @@ class Endpoint {
     }
   }
 
+  // The family comes from the address map and the peer's answer alone
+  // (hostsock.hpp): a destination on this daemon's host is tried at its
+  // port's Unix name first, on every attempt, so a peer that is not up yet
+  // (it refuses both) never pins the pair on TCP; a peer with no such
+  // listener (the Python sidecar, debug server or app rank) and a
+  // destination on another host get TCP.
   int connect_to(int dest) {
     auto it = addr_map_.find(dest);
     if (it == addr_map_.end()) die("no address for rank %d", dest);
+    auto self = addr_map_.find(rank_);
+    bool local = self != addr_map_.end() &&
+                 hostsock::same_host(it->second.first, self->second.first);
     double deadline = monotonic() + 15.0;
     for (;;) {
+      int usock = local ? hostsock::connect_unix(it->second.second) : -1;
+      if (usock >= 0) {
+        ++conns_unix_;
+        return usock;
+      }
       int sock = socket(AF_INET, SOCK_STREAM, 0);
       sockaddr_in addr{};
       addr.sin_family = AF_INET;
@@ -868,6 +903,7 @@ class Endpoint {
       if (connect(sock, (sockaddr*)&addr, sizeof(addr)) == 0) {
         int one = 1;
         setsockopt(sock, IPPROTO_TCP, TCP_NODELAY, &one, sizeof(one));
+        ++conns_tcp_;
         return sock;
       }
       close(sock);
@@ -877,8 +913,11 @@ class Endpoint {
   }
 
   int epfd_ = -1;
-  int lsock_ = -1;
+  int lsock_ = -1;  // TCP, the port of the PORT hello
+  int usock_ = -1;  // that port's Unix name (hostsock.hpp), or -1
   int port_ = 0;
+  int rank_ = -1;
+  int conns_unix_ = 0, conns_tcp_ = 0;
   bool closed_ = false;
   bool have_pwait2_ = true;  // until the kernel says ENOSYS
   std::map<int, std::pair<std::string, int>> addr_map_;
@@ -1032,6 +1071,9 @@ class Server {
       std::snprintf(num, sizeof(num), "%.17g", stats_[k]);
       os << "\"" << k << "\": " << num;
     }
+    // beside the Info keys, by name: the transport's connections by family
+    os << ", \"conns_unix\": " << ep_->conns_unix()
+       << ", \"conns_tcp\": " << ep_->conns_tcp();
     os << "}";
     std::printf("%s\n", os.str().c_str());
     std::fflush(stdout);
@@ -3170,6 +3212,7 @@ int main() {
   }
   if (rank < 0 || !w.is_server(rank)) die("bad or missing rank");
   Endpoint ep;
+  ep.set_rank(rank);
   int port = ep.listen_any();
   std::printf("PORT %d\n", port);
   std::fflush(stdout);
