@@ -389,6 +389,11 @@ class Server:
         self._killed_units: set[int] = set()
         self._killed_order: deque = deque()
         self.wal_recovered = 0  # units adopted from the WAL at startup
+        self.wal_replayed = 0   # log records the recovery replayed
+        self.wal_recover_s = 0.0
+        # seconds the reactor spent in _flush_wal (write-out and group
+        # commit), by CLOCK_MONOTONIC second as _reactor_busy_by_s
+        self._wal_flush_by_s: deque = deque(maxlen=7200)
 
         # ---- elastic membership (adlb_tpu/runtime/membership.py) ----
         # master's id pool for attached ranks / scale-out servers: above
@@ -466,6 +471,10 @@ class Server:
         # serialized inside the unacked SS_MIGRATE_WORK
         self._mig_token = 0
         self._migrate_pending: dict[int, dict[int, list]] = {}
+        # durable servers: token -> the seqnos a batch took out of the
+        # wq, whose OP_REMOVE the WAL takes only once the units are
+        # durable elsewhere (see _wal_settle_moved); empty without a WAL
+        self._migrate_moved: dict[int, list] = {}
         self.died = False  # this server's own (injected) connectivity death
         # app ranks whose connection died before finalize (reclaim policy);
         # a rank that reconnects (network churn, not death) is resurrected
@@ -667,6 +676,13 @@ class Server:
         self._g_wal_depth = self.metrics.gauge("wal_depth")
         self._g_wal_lag = self.metrics.gauge("wal_fsync_lag_ms")
         self._m_wal_syncs = self.metrics.counter("wal_syncs")
+        if self.wal is not None:
+            # what a group commit costs and how far it is amortised:
+            # seconds per commit, records and bytes written to the log
+            # (a server without a log mints none of these)
+            self._h_wal_fsync = self.metrics.histogram("wal_fsync_s")
+            self._m_wal_records = self.metrics.counter("wal_records")
+            self._m_wal_bytes = self.metrics.counter("wal_bytes")
         self._m_jobs_done = self.metrics.counter("jobs_done")
         self._g_fo_mttr = self.metrics.gauge("failover_mttr_ms")
         # elastic-membership surface: counted ONCE fleet-wide (attach/
@@ -4187,6 +4203,7 @@ class Server:
         if m.data.get("fo_from") is not None:
             return  # plan named the dead server's inventory: stale
         units = []
+        moved = []
         for seqno in m.seqnos:
             unit = self.wq.get(seqno)
             if unit is None or unit.pinned or unit.target_rank >= 0:
@@ -4194,8 +4211,13 @@ class Server:
             self._unspill(unit)  # shipping needs the bytes
             self.wq.remove(seqno)
             self.mem.free(len(unit.payload))
-            if self.wlog is not None:
-                self.wlog.log_remove(seqno)
+            # the buddy's mirror follows the wq at once; the WAL keeps the
+            # unit until the destination has it on disk, or a fleet that
+            # dies with the batch in flight would recover it nowhere
+            if self.repl is not None:
+                self.repl.log_remove(seqno)
+            if self.wal is not None:
+                moved.append(seqno)
             self.stats[InfoKey.NPUSHED_FROM_HERE] += 1
             shipped = {
                 "payload": unit.payload,
@@ -4230,18 +4252,22 @@ class Server:
         # pools parked ~180 ms mid-run (round 4) while a neighbor held
         # hundreds of units.
         self._send_migrate_batch(
-            m.dest, units, bounced=False, mig_id=m.data.get("mig_id", 0)
+            m.dest, units, bounced=False, mig_id=m.data.get("mig_id", 0),
+            moved=moved,
         )
 
     def _send_migrate_batch(self, dest: int, units: list, bounced: bool,
-                            mig_id: int = 0) -> None:
+                            mig_id: int = 0, moved=()) -> None:
         """Ship one migration batch, tracked until acked: the units live
         in no wq while serialized in the frame, and a destination dying
         mid-transit must hand them back (see _on_server_dead) instead of
-        losing them."""
+        losing them. ``moved``: the seqnos the batch took out of a durable
+        server's wq, still in its WAL."""
         self._migrate_unacked += 1
         self._mig_token += 1
         tok = self._mig_token
+        if moved:
+            self._migrate_moved[tok] = moved
         sent_to = self._send_srv(
             dest,
             msg(Tag.SS_MIGRATE_WORK, self.rank, units=units, bounced=bounced,
@@ -4252,8 +4278,19 @@ class Server:
             self._migrate_unacked -= 1
             for u in units:
                 self._admit_migrated_unit(u, bounced=bounced)
+            self._wal_settle_moved(tok)
             return
         self._migrate_pending.setdefault(sent_to, {})[tok] = units
+
+    def _wal_settle_moved(self, tok: int) -> None:
+        """The WAL's OP_REMOVE of a migrated batch, held until its units
+        are durable elsewhere: in the destination's log (its
+        SS_MIGRATE_ACK waits for the group commit that covers them) or
+        back in this server's under new seqnos. A fleet that dies in
+        between recovers such a unit on both servers — a re-execution,
+        the crash-recovery contract — and never on neither."""
+        for seqno in self._migrate_moved.pop(tok, ()):
+            self.wal.log_remove(seqno)
 
     def _on_migrate_work(self, m: Msg) -> None:
         # ack the planner's batch id via the next snapshot: credits for
@@ -4300,11 +4337,15 @@ class Server:
             if self.wlog is not None:
                 self.wlog.log_put(unit, -1, None)
             self.stats[InfoKey.NPUSHED_TO_HERE] += 1
-        self._send_srv(
-            m.src,
-            msg(Tag.SS_MIGRATE_ACK, self.rank,
-                mig_tok=m.data.get("mig_tok", 0)),
-        )
+        ack = msg(Tag.SS_MIGRATE_ACK, self.rank,
+                  mig_tok=m.data.get("mig_tok", 0))
+        if self.wal is not None and m.units:
+            # durable before acknowledged, as a put: the source's WAL
+            # lets go of the units on this ack (_wal_settle_moved)
+            self.wal.defer_ack(m.src, ack)
+            self._flush_wal()
+        else:
+            self._send_srv(m.src, ack)
         if bounced_back:
             self._send_migrate_batch(m.src, bounced_back, bounced=True)
         if m.units:
@@ -4327,6 +4368,8 @@ class Server:
             # the exhaustion vote on a negative unacked count
             return
         self._migrate_unacked -= 1
+        if tok:
+            self._wal_settle_moved(tok)
         held = getattr(self, "_held_checkpoints", None)
         if held and self._migrate_unacked == 0:
             self._held_checkpoints = []
@@ -5351,9 +5394,29 @@ class Server:
         if prof is not None:
             prof.set_phase("wal_fsync")
         synced_before = w.syncs
-        self._release_wal_acks(w.tick(time.monotonic(), force=force))
+        records_before, bytes_before = w.records_written, w.bytes_written
+        t0 = time.monotonic()
+        self._release_wal_acks(w.tick(t0, force=force))
+        if w.records_written != records_before:
+            self._m_wal_records.inc(w.records_written - records_before)
+            self._m_wal_bytes.inc(w.bytes_written - bytes_before)
         if w.syncs != synced_before:
             self._m_wal_syncs.inc(w.syncs - synced_before)
+            self._h_wal_fsync.observe(w.last_fsync_s)
+        # booked as the reactor's busy time is, a stretch split where it
+        # straddles a second, so a reader can take any window's share
+        t1 = time.monotonic()
+        by_s = self._wal_flush_by_s
+        while True:
+            sec = int(t0)
+            upto = min(t1, sec + 1.0)
+            if by_s and by_s[-1][0] == sec:
+                by_s[-1][1] += upto - t0
+            else:
+                by_s.append([sec, upto - t0])
+            if upto >= t1:
+                break
+            t0 = upto
 
     def _release_wal_acks(self, acks) -> None:
         """Send the put acks a group commit (or compaction) released;
@@ -5372,7 +5435,10 @@ class Server:
                     if self.wlog is not None:
                         self.wlog.log_trace(unit.seqno, unit.trace_id,
                                             unit.spans)
-            self._send_app(app, resp)
+            if self.world.is_server(app):  # a held SS_MIGRATE_ACK
+                self._send_srv(app, resp)
+            else:
+                self._send_app(app, resp)
 
     def _wal_seed(self, log) -> None:
         """Durable non-pool state re-seeded into a fresh WAL segment at
@@ -5419,6 +5485,7 @@ class Server:
         previous fleet — so recovered work re-executes, the standard
         crash-recovery contract; an ACKED put is always here (or in the
         quarantine), never silently gone."""
+        t_recover = time.monotonic()
         mirror = self.wal.recover()
         if mirror is None:
             return
@@ -5491,6 +5558,8 @@ class Server:
         for jid, (code, quota, name) in mirror.jobs_meta.items():
             self.jobs.restore(jid, code, quota, name)
         self.wal_recovered = n_units
+        self.wal_replayed = mirror.entries_applied
+        self.wal_recover_s = time.monotonic() - t_recover
         if n_units or mirror.entries_applied:
             self.flight.record(
                 f"wal_recovered units={n_units} "
@@ -5498,7 +5567,9 @@ class Server:
                 f"quarantined={len(mirror.quarantined)} "
                 f"jobs={len(mirror.jobs_meta)} "
                 f"hedge_siblings_dropped={hedge_dropped} "
-                f"torn_tail={self.wal.recovered_torn}"
+                f"torn_tail={self.wal.recovered_torn} "
+                f"replayed={mirror.entries_applied} "
+                f"seconds={self.wal_recover_s:.3f}"
             )
             aprintf(
                 self.cfg.aprintf_flag, self.rank,
@@ -5507,6 +5578,23 @@ class Server:
                 f"{len(mirror.jobs_meta)} jobs "
                 f"(torn tail: {self.wal.recovered_torn})",
             )
+
+    def wal_stats(self) -> dict:
+        """A durable server's own account, for ``finalize_stats()``: what
+        the restart replayed and adopted and how long that took, what the
+        log took since (commits, records, bytes with their framing), and
+        the reactor's seconds in ``_flush_wal`` by CLOCK_MONOTONIC
+        second."""
+        return {
+            "wal_recovered": self.wal_recovered,
+            "wal_replayed": self.wal_replayed,
+            "wal_recover_s": self.wal_recover_s,
+            "wal_syncs": self.wal.syncs,
+            "wal_records": self.wal.records_written,
+            "wal_bytes": self.wal.bytes_written,
+            "wal_flush_by_second": {
+                sec: spent for sec, spent in self._wal_flush_by_s},
+        }
 
     def _void_killed_unit(self, seqno: int) -> None:
         self._killed_units.add(seqno)
@@ -7408,6 +7496,7 @@ class Server:
             self._migrate_unacked -= 1
             for u in units:
                 self._admit_migrated_unit(u, bounced=False)
+            self._wal_settle_moved(tok)
             self.flight.record(
                 f"migrate batch tok={tok} to dead server {dead} "
                 f"requeued ({len(units)} units)"
@@ -8223,6 +8312,8 @@ class Server:
         out["reactor_busy_s"] = self._reactor_busy_s
         out["reactor_busy_by_second"] = {
             sec: busy for sec, busy in self._reactor_busy_by_s}
+        if self.wal is not None:
+            out.update(self.wal_stats())
         if self.is_master:
             # which path planned (balancer/engine.py solver_facts): the
             # one non-InfoKey entry, so a caller can tell a device solve

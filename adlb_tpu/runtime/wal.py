@@ -40,7 +40,12 @@ an append-only on-disk log under ``Config(wal_dir)``:
 Loss model: everything fsynced is recovered; the tail after the last
 group commit is lost *except that no put in it was ever acked* — the
 conservation contract (completed / re-executed / counted lost, zero
-silent loss) extends across process death.
+silent loss) extends across process death. A unit the planner moves
+between two durable servers is held to the same contract: the source's
+log keeps it until the destination's ``SS_MIGRATE_ACK``, which waits for
+the destination's group commit, so a fleet that dies with the batch in
+flight recovers the unit on one of them or, for a moment, on both (a
+re-execution), and never on neither (``Server._wal_settle_moved``).
 """
 
 from __future__ import annotations
@@ -56,6 +61,7 @@ from adlb_tpu.runtime.replica import (
     ReplicaMirror,
     ReplicationLog,
 )
+from adlb_tpu.runtime.trace import span
 
 # on-disk record framing: crc32 of the entry bytes, then entry length.
 # The entry itself is the replica wire form (op byte + body length +
@@ -113,6 +119,9 @@ class WriteAheadLog(ReplicationLog):
         self.pending_acks: list = []
         self.entries_synced = 0
         self.syncs = 0
+        self.last_fsync_s = 0.0    # what the newest group commit took
+        self.records_written = 0   # records handed to the OS file
+        self.bytes_written = 0     # with their framing
         self.compactions = 0
         self.recovered_torn = False
 
@@ -158,6 +167,8 @@ class WriteAheadLog(ReplicationLog):
         blob = b"".join(recs)
         self._f.write(blob)
         self.size += len(blob)
+        self.records_written += len(self._buf)
+        self.bytes_written += len(blob)
         self._unsynced += len(self._buf)
         if self._first_unsynced_t is None:
             self._first_unsynced_t = time.monotonic()
@@ -165,9 +176,16 @@ class WriteAheadLog(ReplicationLog):
 
     def _sync(self) -> list:
         """fsync the segment; returns the acks the commit releases."""
+        self.last_fsync_s = 0.0
         if self._f is not None and self._unsynced:
-            self._f.flush()
-            os.fsync(self._f.fileno())
+            # the group commit, as the thread that runs it sees it: the
+            # span lands in a profiler session of the process (the
+            # master's host plane of a device trace)
+            t0 = time.monotonic()
+            with span("adlb.wal.fsync"):
+                self._f.flush()
+                os.fsync(self._f.fileno())
+            self.last_fsync_s = time.monotonic() - t0
         self.entries_synced += self._unsynced
         self.syncs += 1
         self._unsynced = 0
