@@ -86,7 +86,9 @@ def _parse_trailer(lines):
     and the operator still see it. Returns (stats dict or None, abort code
     or None). The stats' Info keys are ints (InfoKey -> float); what the
     daemon reports by name beside them (``conns_unix``, ``conns_tcp``: the
-    connections it opened and accepted, by socket family) keeps its name."""
+    connections it opened and accepted, by socket family; ``waits_polled``,
+    ``waits_slept``: its reactor's waits that ended inside the polling
+    phase, and those that went on to sleep) keeps its name."""
     import sys
 
     stats: Optional[dict] = None

@@ -24,6 +24,21 @@
 // Python rank (TcpEndpoint: the balancer sidecar, the debug server, Python
 // app ranks and servers of a mixed world) has no such listener, and a peer
 // in another network namespace cannot be seen. Other hosts get TCP.
+//
+// Also here, because both ends share it: how long a rank that awaits a frame
+// looks for it before it sleeps (poll_budget_s). A frame's sender is, as a
+// rule, microseconds away (a server answers a put within some 10 us of
+// reading it, a synchronous client's next request follows its answer by
+// less), and a rank that went to sleep pays the host a wake-up for it:
+// tens of microseconds where system calls are answered by a user-space
+// kernel. So the client's wait for an answer to its own request
+// (libadlb.cpp wait_for) and the daemon's wait after a turn that carried
+// traffic (serverd.cpp Endpoint::recv) first take what is there without
+// blocking, again and again for this long, and sleep only when nothing
+// came. The value is transport_shm.py's (_SPIN_S, the Python plane's ring
+// poll before it parks on its doorbell), and as there it is 0 on a
+// single-core host, where polling only takes the sender's time slice.
+// Nothing selects it; what adapts is when a rank enters the phase.
 
 #ifndef ADLB_TPU_HOSTSOCK_HPP
 #define ADLB_TPU_HOSTSOCK_HPP
@@ -39,6 +54,13 @@
 #include <string>
 
 namespace hostsock {
+
+// Seconds of CLOCK_MONOTONIC a rank polls for an awaited frame before it
+// sleeps (above); 0 means it sleeps at once.
+inline double poll_budget_s() {
+  static const double s = sysconf(_SC_NPROCESSORS_ONLN) > 1 ? 50e-6 : 0.0;
+  return s;
+}
 
 inline socklen_t unix_name(int port, sockaddr_un* sa) {
   std::memset(sa, 0, sizeof *sa);

@@ -8,9 +8,13 @@
 // re-targeted from tagged MPI sends to the framework's TCP fabric.
 //
 // Threads: none of its own. The thread that blocks in a call does the reads:
-// it sleeps in poll() over the listener and the inbound connections, accepts,
+// it looks at the listeners and the inbound connections with poll(), accepts,
 // reads and decodes what arrives and returns the frame it waited for
-// (poll_inbound). Inbound traffic therefore makes progress only inside
+// (poll_inbound). A call that awaits the answer to its own request looks
+// without blocking for a bounded time first (hostsock::poll_budget_s: the
+// answer is as a rule microseconds away, and a wake-up costs more) and
+// sleeps in poll() only when nothing came; every other wait sleeps at once
+// (wait_for). Inbound traffic therefore makes progress only inside
 // library calls, as the reference's client makes none outside MPI calls:
 // between calls an abort, a pipelined put's response or an app message waits
 // in the kernel's socket buffers. The API is strictly request/response like
@@ -282,6 +286,14 @@ struct Ctx {
   int listen_unix_fd = -1;  // the same port's name (hostsock.hpp), or -1
   // connections opened and accepted, by family (ADLB_TRACE reports them)
   int conns_unix = 0, conns_tcp = 0;
+  // waits for an answer that ended inside the polling phase, and waits that
+  // went on to sleep in poll() (wait_for; ADLB_TRACE reports them)
+  int64_t waits_polled = 0, waits_slept = 0;
+  // the connection that delivered the last frame, and whether the one
+  // before came over it too: a rank whose answers keep coming over one
+  // connection (its home server's, as a rule) reads that one first
+  int last_fd = -1;
+  bool last_fd_twice = false;
   std::vector<InConn> in;   // inbound connections, read by whoever waits
   std::deque<Msg> inbox;    // decoded frames no call has looked at yet
   std::deque<Msg> app_inbox;  // stashed AM_APP frames (the app_comm channel)
@@ -297,6 +309,12 @@ struct Ctx {
 };
 
 Ctx *g = nullptr;
+
+double monotonic() {
+  timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return double(ts.tv_sec) + double(ts.tv_nsec) * 1e-9;
+}
 
 void die(const char *fmt, ...) {
   va_list ap;
@@ -397,14 +415,21 @@ bool read_conn(InConn &c) {
     return true;
   if (r <= 0) return false;
   c.buf.append(chunk, (size_t)r);
-  return parse_frames(c);
+  size_t had = g->inbox.size();
+  bool keep = parse_frames(c);
+  if (g->inbox.size() > had) {
+    g->last_fd_twice = g->last_fd == c.fd;
+    g->last_fd = c.fd;
+  }
+  return keep;
 }
 
-// The library's one wait. Sleeps in poll() over the two listeners, every
-// inbound connection and (optionally) one outbound socket a send is stuck on;
-// accepts, reads and decodes whatever is ready into g->inbox. With `block`
-// it returns once `wfd` is writable or, given none, once the inbox holds a
-// frame; without, it takes what is there now and returns.
+// The library's one look at its sockets: poll() over the two listeners,
+// every inbound connection and (optionally) one outbound socket a send is
+// stuck on; accepts, reads and decodes whatever is ready into g->inbox. With
+// `block` it sleeps there and returns once `wfd` is writable or, given none,
+// once the inbox holds a frame; without, it takes what is there now and
+// returns (the polling phase of wait_for is this form, repeated).
 void poll_inbound(bool block, int wfd = -1) {
   static std::vector<struct pollfd> pfds;
   for (;;) {
@@ -632,10 +657,44 @@ void dispatch_passive(Msg m) {
   die("unexpected tag %u outside a pending request", m.tag);
 }
 
+// Until the inbox holds a frame: look for it without blocking for the
+// polling budget, over the same descriptors as the sleep (so connections
+// are accepted and other peers' frames read meanwhile), then sleep. The
+// caller has a request out, so the frame is on its way. Where the last two
+// frames came over one connection, each look starts with a read of that
+// connection alone: a hit there costs one system call, not poll() and then
+// the read; the others are still looked at in the same turn.
+void await_answer() {
+  double budget = hostsock::poll_budget_s();
+  if (budget > 0) {
+    double deadline = monotonic() + budget;
+    do {
+      auto last = g->last_fd_twice
+                      ? std::find_if(g->in.begin(), g->in.end(),
+                                     [](const InConn &c) {
+                                       return c.fd == g->last_fd;
+                                     })
+                      : g->in.end();
+      if (last != g->in.end() && !read_conn(*last)) {
+        close(last->fd);
+        g->in.erase(last);
+      }
+      if (g->inbox.empty()) poll_inbound(false);
+      if (!g->inbox.empty()) {
+        g->waits_polled++;
+        return;
+      }
+    } while (monotonic() < deadline);
+  }
+  g->waits_slept++;
+  poll_inbound(true);
+}
+
 // Blocks until a frame with `want` arrives, reading the sockets itself: the
-// thread that waits is the thread that reads, so a response costs one
-// wake-up on this side. Frames read on the way that are not the awaited
-// one are handled here, on the caller's thread.
+// thread that waits is the thread that reads, so a response costs this side
+// no thread hand-off, and no wake-up either when it comes within the polling
+// budget. Frames read on the way that are not the awaited one are handled
+// here, on the caller's thread.
 Msg wait_for(uint16_t want) {
   for (;;) {
     while (!g->inbox.empty()) {
@@ -647,7 +706,7 @@ Msg wait_for(uint16_t want) {
       dispatch_passive(std::move(m));
     }
     pump_resends();  // replays queued by settle_put
-    if (g->inbox.empty()) poll_inbound(true);
+    if (g->inbox.empty()) await_answer();
   }
 }
 
@@ -683,11 +742,6 @@ extern "C" {
 // between Get_reserved calls), gated here by ADLB_TRACE=<path prefix> at
 // run time. ADLB_Finalize writes <prefix>.<rank>.trace.json in Chrome
 // trace-event format (one file per rank; concatenate the arrays to merge).
-static double trace_now() {
-  timespec ts;
-  clock_gettime(CLOCK_MONOTONIC, &ts);
-  return double(ts.tv_sec) + double(ts.tv_nsec) * 1e-9;
-}
 struct TraceEv {
   const char *name;
   int wt;  // work type for inferred user states, -1 for API calls
@@ -704,17 +758,17 @@ static void trace_api_entry() {
   if (!trace_on) return;
   if (trace_user_t0 >= 0) {  // close the open inferred user-state span
     trace_events.push_back(
-        {"user", trace_user_wt, trace_user_t0, trace_now() - trace_user_t0});
+        {"user", trace_user_wt, trace_user_t0, monotonic() - trace_user_t0});
     trace_user_t0 = -1.0;
   }
 }
 static void trace_call(const char *name, double t0) {
   if (!trace_on) return;
-  trace_events.push_back({name, -1, t0, trace_now() - t0});
+  trace_events.push_back({name, -1, t0, monotonic() - t0});
 }
 static void trace_got_work() {  // successful Get_reserved opens a user span
   if (!trace_on) return;
-  trace_user_t0 = trace_now();
+  trace_user_t0 = monotonic();
   trace_user_wt = trace_last_reserved_wt;
 }
 static void trace_flush(int rank) {
@@ -745,8 +799,17 @@ static void trace_flush(int rank) {
           "%s{\"name\":\"adlb:conns\",\"ph\":\"C\",\"ts\":%.3f,"
           "\"pid\":%d,\"tid\":%d,\"args\":{\"conns_unix\":%d,"
           "\"conns_tcp\":%d}}",
-          trace_events.empty() ? "" : ",", trace_now() * 1e6, rank, rank,
+          trace_events.empty() ? "" : ",", monotonic() * 1e6, rank, rank,
           g ? g->conns_unix : 0, g ? g->conns_tcp : 0);
+  // and how its waits for an answer ended: inside the polling phase, or
+  // asleep in poll() (wait_for)
+  fprintf(f,
+          ",{\"name\":\"adlb:waits\",\"ph\":\"C\",\"ts\":%.3f,"
+          "\"pid\":%d,\"tid\":%d,\"args\":{\"waits_polled\":%lld,"
+          "\"waits_slept\":%lld}}",
+          monotonic() * 1e6, rank, rank,
+          (long long)(g ? g->waits_polled : 0),
+          (long long)(g ? g->waits_slept : 0));
   fprintf(f, "]\n");
   fclose(f);
 }
@@ -898,7 +961,7 @@ int ADLBP_Put(void *work_buf, int work_len, int target_rank, int answer_rank,
 int ADLB_Put(void *b, int l, int t, int a, int w, int p) {
   if (!trace_on) return ADLBP_Put(b, l, t, a, w, p);
   trace_api_entry();
-  double t0 = trace_now();
+  double t0 = monotonic();
   int rc = ADLBP_Put(b, l, t, a, w, p);
   trace_call("adlb:put", t0);
   return rc;
@@ -954,7 +1017,7 @@ int ADLBP_Reserve(int *rt, int *wt, int *wp, int *wh, int *wl, int *ar) {
 int ADLB_Reserve(int *rt, int *wt, int *wp, int *wh, int *wl, int *ar) {
   if (!trace_on) return reserve_impl(rt, wt, wp, wh, wl, ar, 1);
   trace_api_entry();
-  double t0 = trace_now();
+  double t0 = monotonic();
   int rc = reserve_impl(rt, wt, wp, wh, wl, ar, 1);
   trace_call("adlb:reserve", t0);
   return rc;
@@ -965,7 +1028,7 @@ int ADLBP_Ireserve(int *rt, int *wt, int *wp, int *wh, int *wl, int *ar) {
 int ADLB_Ireserve(int *rt, int *wt, int *wp, int *wh, int *wl, int *ar) {
   if (!trace_on) return reserve_impl(rt, wt, wp, wh, wl, ar, 0);
   trace_api_entry();
-  double t0 = trace_now();
+  double t0 = monotonic();
   int rc = reserve_impl(rt, wt, wp, wh, wl, ar, 0);
   trace_call("adlb:ireserve", t0);
   return rc;
@@ -1027,7 +1090,7 @@ int ADLBP_Get_reserved_timed(void *work_buf, int *work_handle,
 int ADLB_Get_reserved_timed(void *b, int *h, double *t) {
   if (!trace_on) return ADLBP_Get_reserved_timed(b, h, t);
   trace_api_entry();
-  double t0 = trace_now();
+  double t0 = monotonic();
   int rc = ADLBP_Get_reserved_timed(b, h, t);
   trace_call("adlb:get_reserved", t0);
   if (rc == ADLB_SUCCESS) trace_got_work();
@@ -1187,7 +1250,7 @@ int ADLBP_App_send(int dest_app_rank, void *buf, int len, int apptag) {
 int ADLB_App_send(int d, void *b, int l, int t) {
   if (!trace_on) return ADLBP_App_send(d, b, l, t);
   trace_api_entry();
-  double t0 = trace_now();
+  double t0 = monotonic();
   int rc = ADLBP_App_send(d, b, l, t);
   trace_call("adlb:app_send", t0);
   return rc;
@@ -1220,7 +1283,7 @@ int ADLBP_App_iprobe(int *src, int *apptag, int *len) {
 int ADLB_App_iprobe(int *s_, int *t, int *l) {
   if (!trace_on) return ADLBP_App_iprobe(s_, t, l);
   trace_api_entry();
-  double t0 = trace_now();
+  double t0 = monotonic();
   int rc = ADLBP_App_iprobe(s_, t, l);
   trace_call("adlb:app_iprobe", t0);
   return rc;
@@ -1247,7 +1310,7 @@ int ADLBP_App_recv(void *buf, int maxlen, int *src, int *apptag) {
 int ADLB_App_recv(void *b, int m, int *s_, int *t) {
   if (!trace_on) return ADLBP_App_recv(b, m, s_, t);
   trace_api_entry();
-  double t0 = trace_now();
+  double t0 = monotonic();
   int rc = ADLBP_App_recv(b, m, s_, t);
   trace_call("adlb:app_recv", t0);
   return rc;
@@ -1284,7 +1347,7 @@ int ADLBP_Iput(void *work_buf, int work_len, int target_rank, int answer_rank,
 int ADLB_Iput(void *b, int l, int t, int a, int w, int p) {
   if (!trace_on) return ADLBP_Iput(b, l, t, a, w, p);
   trace_api_entry();
-  double t0 = trace_now();
+  double t0 = monotonic();
   int rc = ADLBP_Iput(b, l, t, a, w, p);
   trace_call("adlb:iput", t0);
   return rc;
@@ -1310,7 +1373,7 @@ int ADLBP_Flush_puts(void) {
 int ADLB_Flush_puts(void) {
   if (!trace_on) return ADLBP_Flush_puts();
   trace_api_entry();
-  double t0 = trace_now();
+  double t0 = monotonic();
   int rc = ADLBP_Flush_puts();
   trace_call("adlb:flush_puts", t0);
   return rc;
@@ -1410,7 +1473,7 @@ int ADLB_Get_work_batch(int *rt, int max_units, int *ng, int *wt, int *wp,
   if (!trace_on)
     return ADLBP_Get_work_batch(rt, max_units, ng, wt, wp, b, mlpu, wl, ar);
   trace_api_entry();
-  double t0 = trace_now();
+  double t0 = monotonic();
   int rc = ADLBP_Get_work_batch(rt, max_units, ng, wt, wp, b, mlpu, wl, ar);
   trace_call("adlb:get_work_batch", t0);
   return rc;
@@ -1419,7 +1482,7 @@ int ADLB_Get_work(int *rt, int *wt, int *wp, void *b, int ml, int *wl,
                   int *ar) {
   if (!trace_on) return ADLBP_Get_work(rt, wt, wp, b, ml, wl, ar);
   trace_api_entry();
-  double t0 = trace_now();
+  double t0 = monotonic();
   int rc = ADLBP_Get_work(rt, wt, wp, b, ml, wl, ar);
   trace_call("adlb:get_work", t0);
   if (rc == ADLB_SUCCESS) trace_got_work();
@@ -1431,8 +1494,8 @@ int ADLB_Get_work(int *rt, int *wt, int *wp, void *b, int ml, int *wl,
 // the aprintf_flag given to ADLB_Init.
 void adlbp_dbgprintf(int flag, int linenum, const char *fmt, ...) {
   if (!flag || g == nullptr || !g->aprintf_flag) return;
-  static double t0 = trace_now();
-  fprintf(stderr, "[r=%d] <%d> %.6f: ", g->rank, linenum, trace_now() - t0);
+  static double t0 = monotonic();
+  fprintf(stderr, "[r=%d] <%d> %.6f: ", g->rank, linenum, monotonic() - t0);
   va_list ap;
   va_start(ap, fmt);
   vfprintf(stderr, fmt, ap);

@@ -512,11 +512,16 @@ NMsg decode(std::string_view body) {
 }
 
 // ---- endpoint: one thread, one epoll set, lazy outbound --------------------
-// The reactor owns every socket. Its one wait (wait_io, from recv) sleeps in
-// epoll over the listener, the inbound connections and whichever outbound
+// The reactor owns every socket. Its one wait (wait_io, from recv) asks
+// epoll about the listener, the inbound connections and whichever outbound
 // sockets have bytes queued; it accepts, reads, decodes and flushes on the
 // calling thread, so a request is dispatched and answered by the thread
-// that read it and the daemon has no other. Wire form as the native
+// that read it and the daemon has no other. After a turn that carried
+// traffic recv asks without blocking for a bounded time before it sleeps
+// there (hostsock::poll_budget_s: a synchronous peer's next request follows
+// its answer within microseconds, and a wake-up costs more); after a turn
+// that ended on its timeout it sleeps at once, so an idle daemon polls
+// nothing. Wire form as the native
 // client's transport (libadlb.cpp) and the Python TcpEndpoint: persistent
 // outbound stream sockets, 4-byte LE length prefix per frame. Two listeners,
 // the TCP port and that port's Unix name: a native rank of this host
@@ -573,6 +578,10 @@ class Endpoint {
   // connections opened and accepted, by family (the STATS trailer)
   int conns_unix() const { return conns_unix_; }
   int conns_tcp() const { return conns_tcp_; }
+  // waits of recv that ended inside the polling phase, and waits that went
+  // on to sleep in epoll (the STATS trailer)
+  int64_t waits_polled() const { return waits_polled_; }
+  int64_t waits_slept() const { return waits_slept_; }
 
   void send(int dest, const NMsg& m) {
     OutConn& oc = out_[dest];
@@ -611,10 +620,30 @@ class Endpoint {
   // when the wait ended for something that is no whole frame yet (a new
   // connection, part of a frame, a socket flushed): the caller's loop
   // recomputes its deadline and comes back
+  //
+  // The answers leave first (flush_pending). Then, if frames were handed
+  // out since the last wait (the turn that just ended carried traffic),
+  // the sockets are asked without blocking until a frame is in, the polling
+  // budget has passed or `timeout` is due; only then does the reactor
+  // sleep. The budget is shorter than any of periodic()'s intervals.
   bool recv(NMsg* out, double timeout) {
     if (inbox_.empty()) {
       flush_pending();
-      wait_io(timeout);
+      bool traffic = served_;
+      served_ = false;
+      double budget = std::min(hostsock::poll_budget_s(), timeout);
+      if (traffic && budget > 0) {
+        double deadline = monotonic() + budget;
+        do {
+          wait_io(0);
+        } while (inbox_.empty() && monotonic() < deadline);
+      }
+      if (inbox_.empty()) {
+        ++waits_slept_;
+        wait_io(timeout);
+      } else {
+        ++waits_polled_;
+      }
     }
     return recv_now(out);
   }
@@ -624,6 +653,7 @@ class Endpoint {
     if (inbox_.empty()) return false;
     *out = std::move(inbox_.front());
     inbox_.pop_front();
+    served_ = true;
     return true;
   }
 
@@ -682,9 +712,10 @@ class Endpoint {
   }
 
   // The daemon's one wait: sleep until a socket is ready or `timeout`
-  // seconds pass, then do what each ready socket asks for. Level-triggered,
-  // one read a ready connection a turn: a flooding peer gets its 64 KB and
-  // the loop goes round, so periodic() keeps its deadlines.
+  // seconds pass (0: ask and return, the form recv's polling phase repeats),
+  // then do what each ready socket asks for. Level-triggered, one read a
+  // ready connection a turn: a flooding peer gets its 64 KB and the loop
+  // goes round, so periodic() keeps its deadlines.
   void wait_io(double timeout) {
     epoll_event evs[64];
     if (timeout < 0) timeout = 0;
@@ -918,6 +949,8 @@ class Endpoint {
   int port_ = 0;
   int rank_ = -1;
   int conns_unix_ = 0, conns_tcp_ = 0;
+  int64_t waits_polled_ = 0, waits_slept_ = 0;
+  bool served_ = false;  // a frame was handed out since recv last waited
   bool closed_ = false;
   bool have_pwait2_ = true;  // until the kernel says ENOSYS
   std::map<int, std::pair<std::string, int>> addr_map_;
@@ -1074,6 +1107,9 @@ class Server {
     // beside the Info keys, by name: the transport's connections by family
     os << ", \"conns_unix\": " << ep_->conns_unix()
        << ", \"conns_tcp\": " << ep_->conns_tcp();
+    // and how the reactor's waits ended: polling, or asleep in epoll
+    os << ", \"waits_polled\": " << ep_->waits_polled()
+       << ", \"waits_slept\": " << ep_->waits_slept();
     os << "}";
     std::printf("%s\n", os.str().c_str());
     std::fflush(stdout);

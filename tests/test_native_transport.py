@@ -18,11 +18,23 @@ would; with ``tcp`` they have no such listener, as a Python rank has none,
 and the native rank is told they live on another host. The last tests hold
 the choice itself: who is tried over which family, and whose name is whose.
 
+A rank that awaits a frame looks for it without blocking for a bounded time
+before it sleeps (``hostsock::poll_budget_s``): the client in a wait for the
+answer to its own request, the daemon after a turn that carried traffic. The
+budget is 50 microseconds, which no test can aim at; what a test can decide
+is whether the awaited thing is there *before* the wait begins, so that the
+polling phase's first look finds it, or comes long after the budget has
+passed, so that the sleeping call does (the ``phase`` fixture). The
+invariants are held once per phase, and ``waits_polled`` / ``waits_slept``
+(the daemon's ``STATS`` trailer, a client's ``adlb:waits`` event) say which
+phase ended a wait.
+
 No test times the host. Every wait has a limit far above what the step
 needs, and running into it is the failure (a hang), not a slow pass.
 """
 
 import collections
+import contextlib
 import json
 import os
 import selectors
@@ -57,6 +69,14 @@ def family(request):
     return request.param
 
 
+@pytest.fixture(params=["polling", "asleep"])
+def phase(request):
+    return request.param
+
+
+ASLEEP_S = 0.05  # a thousand polling budgets: whoever still waits, sleeps
+
+
 def unix_name(port):
     """The abstract name of ``port`` (hostsock.hpp)."""
     return b"\0adlb_tpu.%d" % port
@@ -80,10 +100,12 @@ FA_PUT, FA_RESERVE, FA_LOCAL_APP_DONE = 1001, 1007, 1012
 TA_PUT_RESP, TA_ABORT, AM_APP = 1020, 1046, 1047
 FA_INFO_NUM, TA_INFO_NUM_RESP = 1037, 1043
 SS_QMSTAT, SS_EXHAUST_CHK_1, SS_PLAN_MIGRATE = 1101, 1111, 1119
+SS_END_1, SS_END_2 = 1114, 1115
 F_PAYLOAD, F_WORK_TYPE, F_PRIO, F_TARGET_RANK, F_ANSWER_RANK = 1, 2, 3, 4, 5
 F_COMMON_LEN, F_COMMON_SERVER, F_COMMON_SEQNO, F_RC, F_HINT = 6, 7, 8, 9, 10
 F_REQ_TYPES, F_HANG, F_RQSEQNO, F_COUNT, F_NBYTES, F_CODE = 11, 12, 13, 17, 18, 20
 F_APPTAG, F_DEST, F_SEQNOS, F_PUT_ID, F_MIG_ID = 26, 47, 48, 58, 77
+F_ORIGIN, F_COMPLETE = 40, 42
 
 
 # ---- a TLV codec of the test's own ----------------------------------------
@@ -262,6 +284,35 @@ class Daemons:
                 r: (own_host if r == me else peer_host(family),
                     self.ports.get(r, self.peer.port))
                 for r in range(self.world.nranks)})
+
+    def finish(self):
+        """End the world the way its ranks would and return each daemon's
+        ``STATS``: every app rank finalizes at its home server, and where
+        the test plays a server it passes the two tokens of the END ring
+        on. For worlds whose servers are all daemons, or one daemon and
+        the test."""
+        n_apps = self.world.nranks - self.world.nservers
+        servers = range(n_apps, self.world.nranks)
+        played = [r for r in servers if r not in self.procs]
+        assert len(played) <= 1 and len(servers) <= 2
+        master = n_apps
+        for app in range(n_apps):
+            home = n_apps + app % self.world.nservers
+            if home in self.procs:
+                with _connect(self.ports[home], "tcp") as c:
+                    c.sendall(tlv(FA_LOCAL_APP_DONE, app))
+        for me in played:  # the ring is master -> me -> master
+            with _connect(self.ports[master], "tcp") as c:
+                for tag in (SS_END_1, SS_END_2):
+                    self.peer.expect(tag)
+                    c.sendall(tlv(tag, me, [(F_ORIGIN, master),
+                                            (F_COMPLETE, 1)]))
+        out = {}
+        for r, p in self.procs.items():
+            stats, _abort, rc = daemon_mod.collect_stats(p, timeout=LIMIT_S)
+            assert rc == 0, (r, rc)
+            out[r] = stats
+        return out
 
     def __enter__(self):
         return self
@@ -442,6 +493,130 @@ def test_periodic_keeps_its_deadlines_while_one_client_sends_without_pause(
         assert w.procs[2].poll() is None
 
 
+@contextlib.contextmanager
+def _held_to(cpu, others):
+    """This process on ``cpu`` and each of ``others`` (pid: cpu) on its
+    own, for the length of the block."""
+    before = os.sched_getaffinity(0)
+    for pid, its in others.items():
+        os.sched_setaffinity(pid, {its})
+    os.sched_setaffinity(0, {cpu})
+    try:
+        yield
+    finally:
+        os.sched_setaffinity(0, before)
+
+
+def _paced(sock, frame, every_s, n):
+    """Write ``frame`` ``n`` times, one every ``every_s`` by a busy clock."""
+    t_next = time.perf_counter()
+    for _ in range(n):
+        while time.perf_counter() < t_next:
+            pass
+        sock.sendall(frame)
+        t_next += every_s
+
+
+def test_periodic_keeps_its_deadlines_under_a_frame_every_20_microseconds(
+        family):
+    """The polling phase never loses the reactor to its peer. One rank
+    sends a query every 20 microseconds, well inside the budget, so every
+    wait of the daemon ends in its polling phase and it never sleeps; the
+    qmstat broadcast due every interval still goes out, because each look
+    is one turn of the same loop and ``periodic`` runs at its top. Ten
+    broadcasts have to show before a quarter of a million frames have gone
+    by (they are due every thousand), and the daemon's own count says the
+    waits were polled. Sender and daemon are held to two processors: a
+    sender that spins on its clock never sleeps, and a scheduler that wakes
+    the daemon on the sender's processor lets the two take turns, which is
+    another experiment."""
+    cpus = sorted(os.sched_getaffinity(0))
+    if len(cpus) < 2:
+        pytest.skip("one processor: the budget is 0, nobody polls")
+    cfg = Config(server_impl="native", qmstat_interval=0.02,
+                 exhaust_check_interval=0.02)
+    with Daemons(2, 2, [2], cfg=cfg, family=family) as w, \
+            _held_to(cpus[0], {w.procs[2].pid: cpus[1]}):
+        flood = _connect(w.ports[2], family)
+        frame = tlv(FA_INFO_NUM, 1, [(F_WORK_TYPE, 1)])
+        sent = acked = seen = 0
+        while seen < 10 and sent < 250_000:
+            _paced(flood, frame, 20e-6, 64)
+            sent += 64
+            w.peer.pump(0)
+            while w.peer.frames:
+                tag, _src, _f = w.peer.frames.popleft()
+                acked += tag == TA_INFO_NUM_RESP
+                seen += acked > 0 and tag == SS_QMSTAT
+        assert seen >= 10, (sent, acked, seen)
+        while acked < sent:
+            w.peer.expect(TA_INFO_NUM_RESP)
+            acked += 1
+        stats = w.finish()[2]
+        assert stats["waits_polled"] > stats["waits_slept"], stats
+
+
+def test_a_burst_is_read_by_the_polling_phase_and_a_trickle_by_the_sleep(
+        family, phase):
+    """A frame split across reads, in both phases, and the counter that
+    tells them apart. ``polling``: 600 puts of 1 KB written at once are ten
+    reads of 64 KB, each ending inside a frame; every turn but the first
+    finds its bytes already there, so the polling phase reads them.
+    ``asleep``: twenty puts, each written in two halves a thousand budgets
+    apart and the next only after the answer, so every read finds the
+    daemon asleep. Both ways every put is answered, in order."""
+    with Daemons(1, 1, [1], family=family) as w:
+        c = _connect(w.ports[1], family)
+        if phase == "polling":
+            n = 600
+            c.sendall(b"".join(put_frame(0, bytes(1024), put_id=i + 1)
+                               for i in range(n)))
+            acks = [w.peer.expect(TA_PUT_RESP)[2] for _ in range(n)]
+        else:
+            n, acks = 20, []
+            for i in range(n):
+                frame = put_frame(0, bytes(1024), put_id=i + 1)
+                c.sendall(frame[:500])
+                time.sleep(ASLEEP_S)
+                c.sendall(frame[500:])
+                acks.append(w.peer.expect(TA_PUT_RESP)[2])
+                time.sleep(ASLEEP_S)
+        assert [f[F_PUT_ID] for f in acks] == list(range(1, n + 1))
+        assert all(f[F_RC] == ADLB_SUCCESS for f in acks)
+        stats = w.finish()[1]
+        if phase == "polling":
+            assert stats["waits_polled"] >= 1, stats
+        else:
+            # nothing of the trickle was there within the budget; what was
+            # is the world's end (the END token the daemon sends itself, an
+            # end of file behind a last frame)
+            assert stats["waits_polled"] <= 3, stats
+            assert stats["waits_slept"] >= 2 * n, stats
+
+
+def test_a_connection_opened_while_the_daemon_waits_is_accepted(
+        family, phase):
+    """``polling``: a second rank connects and puts while the daemon is
+    still working through another's burst, so between turns that carry
+    traffic; ``asleep``: into an idle daemon. It is served either way, and
+    the burst is too."""
+    with Daemons(2, 1, [2], family=family) as w:
+        first = _connect(w.ports[2], family)
+        n = 600 if phase == "polling" else 1
+        first.sendall(b"".join(put_frame(0, bytes(1024), put_id=i + 1)
+                               for i in range(n)))
+        ids = []
+        if phase == "asleep":
+            ids.append(w.peer.expect(TA_PUT_RESP)[2][F_PUT_ID])
+            time.sleep(ASLEEP_S)
+        second = _connect(w.ports[2], family)
+        second.sendall(put_frame(1, b"late", put_id=7777))
+        while len(ids) < n + 1:
+            ids.append(w.peer.expect(TA_PUT_RESP)[2][F_PUT_ID])
+        assert sorted(ids) == list(range(1, n + 1)) + [7777]
+        assert w.procs[2].poll() is None
+
+
 # ---- the client library ---------------------------------------------------
 
 CLIENT = r"""
@@ -477,6 +652,12 @@ for line in sys.stdin:
             m = lib.ADLB_App_recv(buf, 64, ctypes.byref(src), ctypes.byref(tag))
             got.append((src.value, tag.value, buf.raw[:m].decode()))
         print("APP", got, flush=True)
+    elif cmd == "reserve":  # reserve <type>: blocks until work or the end
+        req = (I * 2)(int(args[0]), -1)
+        wt, wp, wl, ar = I(), I(), I(), I()
+        print("RESERVE", lib.ADLB_Reserve(
+            req, ctypes.byref(wt), ctypes.byref(wp), (I * 5)(),
+            ctypes.byref(wl), ctypes.byref(ar)), flush=True)
     elif cmd == "finalize":
         print("FINALIZE", lib.ADLB_Finalize(), threads(), flush=True)
         break
@@ -490,15 +671,18 @@ class Client:
     OTHER_HOST, so all it opens is TCP. ``ADLB_TRACE`` is set: its
     end-of-run record is ``self.trace``."""
 
-    def __init__(self, tmp_path, n_apps=1, family="unix", port=None):
+    def __init__(self, tmp_path, n_apps=1, family="unix", port=None,
+                 server_port=None):
         from adlb_tpu.native.capi import build_libadlb
 
         self.port = port or local_addr_map(1)[0][1]
         self.peer = Peer(family=family)
         rv = tmp_path / "world.adlb"
+        ports = dict.fromkeys(range(1, n_apps + 1), self.peer.port)
+        if server_port:  # the one server, the last rank, is a daemon
+            ports[n_apps] = server_port
         rv.write_text(f"0 127.0.0.1 {self.port}\n" + "".join(
-            f"{r} {peer_host(family)} {self.peer.port}\n"
-            for r in range(1, n_apps + 1)))
+            f"{r} {peer_host(family)} {p}\n" for r, p in ports.items()))
         script = tmp_path / "client.py"
         script.write_text(CLIENT)
         self.trace = tmp_path / "t.0.trace.json"
@@ -521,12 +705,16 @@ class Client:
         """The child's next line of output, split; [] once it has gone."""
         return self.proc.stdout.readline().split()
 
-    def conns(self):
-        """The connections the finalized client counted, by family."""
+    def counted(self, name):
+        """What the finalized client counted under the event ``name``."""
         assert self.proc.wait(LIMIT_S) == 0
         (ev,) = [e for e in json.loads(self.trace.read_text())
-                 if e["name"] == "adlb:conns"]
+                 if e["name"] == name]
         return ev["args"]
+
+    def conns(self):
+        """Its connections, by family."""
+        return self.counted("adlb:conns")
 
     def __enter__(self):
         return self
@@ -645,6 +833,193 @@ def test_a_send_that_would_block_does_not_stop_the_clients_reads(
         assert got.startswith("APP [(1, 0, '") and got.count("(1, ") == n
 
 
+def _back_connection(c, family):
+    """A connection into the client that it has accepted and read from: one
+    put, answered over it a thousand budgets late (so that wait slept)."""
+    c.tell("put 8")
+    c.peer.expect(FA_PUT)
+    back = _connect(c.port, family)
+    time.sleep(ASLEEP_S)
+    back.sendall(tlv(TA_PUT_RESP, 1, [(F_RC, ADLB_SUCCESS)]))
+    assert c.line() == ["PUT", "1"]
+    return back
+
+
+def test_an_answer_within_the_budget_is_polled_and_a_late_one_slept_for(
+        tmp_path, family, phase):
+    """The mechanism's two counters. ``polling``: each of twenty answers is
+    in the client's socket before the put that awaits it is made, so the
+    first look of the polling phase reads it: twenty waits polled, and not
+    one sleeping call beyond the first put's. ``asleep``: each answer comes
+    a thousand budgets after its put: every wait slept, none polled, which
+    is also what says the polling phase ends."""
+    with Client(tmp_path, family=family) as c:
+        back = _back_connection(c, family)
+        for _ in range(20):
+            if phase == "polling":
+                back.sendall(tlv(TA_PUT_RESP, 1, [(F_RC, ADLB_SUCCESS)]))
+                time.sleep(0.01)  # it is there before the put is made
+                c.tell("put 8")
+                c.peer.expect(FA_PUT)
+            else:
+                c.tell("put 8")
+                c.peer.expect(FA_PUT)
+                time.sleep(ASLEEP_S)
+                back.sendall(tlv(TA_PUT_RESP, 1, [(F_RC, ADLB_SUCCESS)]))
+            assert c.line() == ["PUT", "1"]
+        c.tell("finalize")
+        assert c.line() == ["FINALIZE", "1", "1"]
+        want = {"waits_polled": 20, "waits_slept": 1}
+        if phase == "asleep":
+            want = {"waits_polled": 0, "waits_slept": 21}
+        assert c.counted("adlb:waits") == want
+
+
+def test_answers_over_two_connections_in_turn_are_polled_all_the_same(
+        tmp_path, family):
+    """The read of one connection ahead of each look is for a rank whose
+    answers keep coming over one connection, and is not in the way of one
+    whose answers do not: twenty answers, each there before its put is
+    made, come over two connections in turn, and the look at all of them
+    finds every one; then the connection of the last answers closes, the
+    next wait reads its end first, and the answer that comes over the other
+    ends that wait."""
+    with Client(tmp_path, family=family) as c:
+        backs = [_back_connection(c, family), _back_connection(c, family)]
+        for i in range(20):
+            backs[i % 2].sendall(tlv(TA_PUT_RESP, 1, [(F_RC, ADLB_SUCCESS)]))
+            time.sleep(0.01)
+            c.tell("put 8")
+            c.peer.expect(FA_PUT)
+            assert c.line() == ["PUT", "1"]
+        for _ in range(2):  # now backs[0] alone delivers, so it is read first
+            backs[0].sendall(tlv(TA_PUT_RESP, 1, [(F_RC, ADLB_SUCCESS)]))
+            time.sleep(0.01)
+            c.tell("put 8")
+            c.peer.expect(FA_PUT)
+            assert c.line() == ["PUT", "1"]
+        backs[0].close()  # what the next wait reads first is its end
+        time.sleep(0.01)
+        c.tell("put 8")
+        c.peer.expect(FA_PUT)
+        time.sleep(ASLEEP_S)
+        backs[1].sendall(tlv(TA_PUT_RESP, 1, [(F_RC, ADLB_SUCCESS)]))
+        assert c.line() == ["PUT", "1"]
+        c.tell("finalize")
+        assert c.line() == ["FINALIZE", "1", "1"]
+        # the two that made the connections and the last slept
+        assert c.counted("adlb:waits") == {"waits_polled": 22,
+                                           "waits_slept": 3}
+
+
+def test_frames_of_other_tags_read_in_a_wait_for_an_answer_are_handled(
+        tmp_path, family, phase):
+    """Fifty app messages arrive while the client awaits the answer to a
+    put: before the put is made, so that the polling phase reads them, or
+    once it sleeps. They are stashed in order on the way, the answer still
+    ends the put, and ``App_recv`` then finds them without a read."""
+    msgs = b"".join(
+        tlv(AM_APP, 1, [(F_PAYLOAD, b"m%d" % i), (F_APPTAG, 100 + i)])
+        for i in range(50))
+    with Client(tmp_path, n_apps=2, family=family) as c:
+        back = _back_connection(c, family)
+        if phase == "polling":
+            back.sendall(msgs)
+            time.sleep(0.01)
+        c.tell("put 8")
+        c.peer.expect(FA_PUT)
+        time.sleep(ASLEEP_S)
+        if phase == "asleep":
+            back.sendall(msgs)
+            time.sleep(ASLEEP_S)
+        back.sendall(tlv(TA_PUT_RESP, 2, [(F_RC, ADLB_SUCCESS)]))
+        assert c.line() == ["PUT", "1"]
+        c.tell("app_recv 50")
+        want = [(1, 100 + i, "m%d" % i) for i in range(50)]
+        assert " ".join(c.line()) == "APP " + str(want)
+
+
+def test_a_connection_opened_while_the_client_awaits_an_answer_is_accepted(
+        tmp_path, family, phase):
+    """The answer to a put comes over a connection the client has not seen
+    yet: opened, and the answer written, before the put is made, so that it
+    waits in the listener's queue for the polling phase to accept and read
+    it; or opened once the client sleeps."""
+    with Client(tmp_path, family=family) as c:
+        if phase == "polling":
+            back = _connect(c.port, family)
+            back.sendall(tlv(TA_PUT_RESP, 1, [(F_RC, ADLB_SUCCESS)]))
+            time.sleep(0.01)
+        c.tell("put 8")
+        c.peer.expect(FA_PUT)
+        if phase == "asleep":
+            time.sleep(ASLEEP_S)
+            back = _connect(c.port, family)
+            back.sendall(tlv(TA_PUT_RESP, 1, [(F_RC, ADLB_SUCCESS)]))
+        assert c.line() == ["PUT", "1"]
+        c.tell("finalize")
+        assert c.line() == ["FINALIZE", "1", "1"]
+        assert c.conns()["conns_" + family] == 2  # opened one, accepted one
+
+
+def test_a_peer_that_sends_more_than_the_sockets_hold_into_a_wait_for_an_answer(
+        tmp_path, family):
+    """The blocked-send invariant from inside the polling phase and the
+    sleep behind it: the client has a small put out and awaits its answer
+    while a peer writes it 8 MB of app messages in one go. The client reads
+    all the while, in both phases of the wait, or the peer would stand in
+    its send; then the answer comes and the put returns."""
+    chunk, n = 60, (8 << 20) // 90
+    msgs = b"".join(
+        tlv(AM_APP, 1, [(F_PAYLOAD, b"x" * chunk), (F_APPTAG, i)])
+        for i in range(n))
+    with Client(tmp_path, n_apps=2, family=family) as c:
+        back = _back_connection(c, family)
+        c.tell("put 8")
+        c.peer.expect(FA_PUT)
+        back.settimeout(LIMIT_S)
+        back.sendall(msgs)  # a client that stopped reading ends this in a timeout
+        back.sendall(tlv(TA_PUT_RESP, 2, [(F_RC, ADLB_SUCCESS)]))
+        assert c.line() == ["PUT", "1"]
+        c.tell(f"app_recv {n}")
+        got = " ".join(c.line())
+        assert got.startswith("APP [(1, 0, '") and got.count("(1, ") == n
+
+
+def _cpu_s(pid):
+    """User and system time of ``pid`` so far, in seconds."""
+    with open(f"/proc/{pid}/stat") as f:
+        fields = f.read().rsplit(")", 1)[1].split()
+    return (int(fields[11]) + int(fields[12])) / os.sysconf("SC_CLK_TCK")
+
+
+def test_an_idle_daemon_and_a_parked_client_use_no_processor_time(tmp_path):
+    """What fails if the polling phase ever loses its bound. A daemon that
+    has served a put and a client parked at it in a ``Reserve`` nobody will
+    answer (the exhaustion vote is a minute away) each use under 5% of a
+    core over two seconds: the client polled for one budget and sleeps, the
+    daemon's turns end on their timeouts and poll nothing."""
+    import types
+
+    port = local_addr_map(1)[0][1]
+    cfg = Config(server_impl="native", exhaust_check_interval=60.0)
+    stub = types.SimpleNamespace(port=port, close=lambda: None)
+    with Daemons(1, 1, [1], cfg=cfg, types=(1,), peer=stub) as w, \
+            Client(tmp_path, port=port, server_port=w.ports[1]) as c:
+        c.tell("put 8")
+        assert c.line() == ["PUT", "1"]
+        c.tell("reserve 1")
+        assert c.line() == ["RESERVE", "1"]  # its own unit
+        c.tell("reserve 1")  # and now nothing is left: parked
+        time.sleep(0.5)
+        pids = {"daemon": w.procs[1].pid, "client": c.proc.pid}
+        before = {k: _cpu_s(pid) for k, pid in pids.items()}
+        time.sleep(2.0)
+        used = {k: _cpu_s(pid) - before[k] for k, pid in pids.items()}
+        assert all(u < 0.05 * 2.0 for u in used.values()), used
+        assert w.procs[1].poll() is None and c.proc.poll() is None
+
+
 # ---- which family, and whose name -----------------------------------------
 
 def _examples():
@@ -677,11 +1052,15 @@ def test_a_one_host_native_world_is_unix_between_its_native_ranks(
         assert stats[rank]["conns_unix"] >= 2, stats[rank]
         assert stats[rank]["conns_tcp"] in (
             (0,) if balancer == "steal" else (1, 2)), stats[rank]
+        # every wait of the reactor ended one way or the other
+        assert stats[rank]["waits_polled"] + stats[rank]["waits_slept"] > 0
     for rank in range(3):
         events = json.loads((tmp_path / f"t.{rank}.trace.json").read_text())
         (ev,) = [e for e in events if e["name"] == "adlb:conns"]
         assert ev["args"]["conns_tcp"] == 0
         assert ev["args"]["conns_unix"] >= 2  # opened one, accepted one
+        (ev,) = [e for e in events if e["name"] == "adlb:waits"]
+        assert ev["args"]["waits_polled"] + ev["args"]["waits_slept"] > 0
 
 
 def test_a_python_peer_reaches_a_daemon_and_is_reached_by_it_over_tcp():

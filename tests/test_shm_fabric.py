@@ -365,13 +365,18 @@ def _kill_mid_ring(ctx):
         time.sleep(0.004)
 
 
-def test_shm_worker_sigkill_mid_ring_reclaimed():
+def test_shm_worker_sigkill_mid_ring_reclaimed(monkeypatch):
     """chaos leg: a peer dying mid-ring (SIGKILL between reserve and
     fetch) over the shm fabric — leases reclaimed, world completes
     around the casualty, segments swept."""
     import glob
 
-    before = set(glob.glob("/dev/shm/adlb*"))
+    from adlb_tpu.runtime import transport_shm
+
+    # the world's own key, so that what is looked for afterwards is this
+    # world's and no other's: spawn_world draws it with new_world_key()
+    key = transport_shm.new_world_key()
+    monkeypatch.setattr(transport_shm, "new_world_key", lambda: key)
     res = spawn_world(
         4, 2, [T], _kill_mid_ring,
         cfg=Config(fabric="shm", on_worker_failure="reclaim",
@@ -384,7 +389,7 @@ def test_shm_worker_sigkill_mid_ring_reclaimed():
     # reserved-but-unfetched unit was reclaimed and re-delivered
     consumed = sum(v for k, v in res.app_results.items())
     assert consumed == 24 - 1
-    # the world sweep left nothing NEW behind (scoped to this world:
-    # concurrent/previous worlds' teardown must not flake this)
-    leaked = set(glob.glob("/dev/shm/adlb*")) - before
+    # the world sweep left nothing of THIS world behind (its key alone:
+    # a world that another test process opened meanwhile is not a leak)
+    leaked = glob.glob(f"/dev/shm/{key}.*")
     assert not leaked, f"leaked shm artifacts: {sorted(leaked)}"
