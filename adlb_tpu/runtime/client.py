@@ -317,8 +317,14 @@ class Client:
         dying on the first OSError. Under ``on_server_failure="failover"``
         a server destination additionally resolves through the takeover
         map (stamped ``fo_from`` so the buddy translates content
-        addresses), and exhausted retries wait out one takeover window
-        before giving up; otherwise an unreachable peer is terminal."""
+        addresses), and the first failure waits out one takeover window
+        before the retries are spent: there a server that refuses its
+        connection is more likely dead than late, so the connect's grace
+        is short and the takeover note is looked for at once — a rank
+        whose first call finds its home gone (it slept through the death,
+        and the note may be in its queue already) is re-homed in the
+        time the promotion takes, not after every reconnect has timed
+        out. Otherwise an unreachable peer is terminal."""
         attempts = self.cfg.reconnect_attempts
         if dest in getattr(self.ep, "binary_peers", ()):
             # native servers implement none of the duplicate-request
@@ -326,6 +332,7 @@ class Client:
             # re-send protocol relies on — fail fast rather than risk a
             # double-stored put or a double-consumed fetch
             attempts = 0
+        failover = self._failover_policy()
         waited_takeover = False
         sleep = 0.0
         attempt = 0
@@ -333,23 +340,27 @@ class Client:
             routed = self._route(dest)
             if routed != dest and self.world.is_server(dest):
                 m.data["fo_from"] = dest
+            await_note = (
+                failover and not waited_takeover
+                and self.world.is_server(routed)
+            )
             try:
-                self.ep.send(routed, m)
+                if await_note:
+                    self.ep.send(routed, m, connect_grace=0.2)
+                else:
+                    self.ep.send(routed, m)
                 return
             except OSError as e:
                 attempt += 1
-                if attempt > attempts:
-                    if (
-                        self._failover_policy()
-                        and self.world.is_server(routed)
-                        and not waited_takeover
-                        and self._await_takeover(routed)
-                    ):
+                if await_note:
+                    waited_takeover = True
+                    if self._await_takeover(routed):
                         # buddy announced itself: restart the retry
                         # budget toward the new destination
-                        waited_takeover = True
+                        waited_takeover = False
                         attempt = 0
                         continue
+                if attempt > attempts:
                     # a permanently unreachable protocol peer ends this
                     # client — raise the conn-lost error the harnesses
                     # classify (abort collateral / casualty), never a
@@ -1201,6 +1212,9 @@ class Client:
         for put_id, req in list(self._pending_puts.items()):
             if self._route(req["server"]) != req["server"]:
                 req["server"] = self._route(req["server"])
+                # marked, so the buddy can count what crossed the death
+                # (and what its replicated window absorbed of it)
+                req["fo_resend"] = True
                 self._send_iput(put_id, req)
         if home_moved and self._active_stream is not None:
             self._active_stream._on_takeover()
@@ -1291,6 +1305,8 @@ class Client:
             pm.data["job_id"] = req["job"]
         if req.get("trace"):
             pm.data["trace_id"] = req["trace"]
+        if req.get("fo_resend"):
+            pm.data["fo_resend"] = 1
         self._send_retry(req["server"], pm)
 
     def _settle_put(self, m: Msg) -> None:

@@ -71,6 +71,23 @@ from adlb_tpu.types import (
 )
 
 
+def _book_by_second(by_s: deque, t0: float, t1: float) -> None:
+    """Add the stretch ``[t0, t1]`` to ``by_s``, ``[second, seconds]``
+    pairs by CLOCK_MONOTONIC second in time order, split where it
+    straddles a second: a reader can take the share of any window, and no
+    second reads over one."""
+    while True:
+        sec = int(t0)
+        upto = min(t1, sec + 1.0)
+        if by_s and by_s[-1][0] == sec:
+            by_s[-1][1] += upto - t0
+        else:
+            by_s.append([sec, upto - t0])
+        if upto >= t1:
+            return
+        t0 = upto
+
+
 class _BalancerWorker(threading.Thread):
     """The balancer brain, off the reactor thread.
 
@@ -394,6 +411,8 @@ class Server:
         # seconds the reactor spent in _flush_wal (write-out and group
         # commit), by CLOCK_MONOTONIC second as _reactor_busy_by_s
         self._wal_flush_by_s: deque = deque(maxlen=7200)
+        # the same for _flush_repl's sending turns (failover worlds)
+        self._repl_flush_by_s: deque = deque(maxlen=7200)
 
         # ---- elastic membership (adlb_tpu/runtime/membership.py) ----
         # master's id pool for attached ranks / scale-out servers: above
@@ -685,6 +704,24 @@ class Server:
             self._m_wal_bytes = self.metrics.counter("wal_bytes")
         self._m_jobs_done = self.metrics.counter("jobs_done")
         self._g_fo_mttr = self.metrics.gauge("failover_mttr_ms")
+        # fixed here: a clean drain borrows the plane (and sets
+        # _failover) in worlds that were never configured for it
+        self._fo_metered = self._failover
+        if self._fo_metered:
+            # what the replication stream costs and how far a frame is
+            # amortised (primary side: frames, entries and bytes sent,
+            # seconds per sending flush), what the buddy applied, and
+            # what a promotion adopted and absorbed (a world without the
+            # policy mints none of these)
+            self._m_repl_frames = self.metrics.counter("repl_frames")
+            self._m_repl_entries = self.metrics.counter("repl_entries")
+            self._m_repl_bytes = self.metrics.counter("repl_bytes")
+            self._h_repl_flush = self.metrics.histogram("repl_flush_s")
+            self._m_repl_applied = self.metrics.counter("repl_applied")
+            self._m_fo_adopted = self.metrics.counter("failover_adopted")
+            self._m_fo_resent = self.metrics.counter("failover_resent_puts")
+            self._m_fo_deduped = self.metrics.counter(
+                "failover_deduped_puts")
         # elastic-membership surface: counted ONCE fleet-wide (attach/
         # detach at the home server, joins/drains at the master)
         self._m_attached = self.metrics.counter("ranks_attached")
@@ -1153,17 +1190,7 @@ class Server:
             # take the share of any window, and no second reads over one
             busy = max((t1 - now) - asleep, 0.0)
             self._reactor_busy_s += busy
-            start = t1 - busy
-            while True:
-                sec = int(start)
-                upto = min(t1, sec + 1.0)
-                if busy_by_s[-1][0] == sec:
-                    busy_by_s[-1][1] += upto - start
-                else:
-                    busy_by_s.append([sec, upto - start])
-                if upto >= t1:
-                    break
-                start = upto
+            _book_by_second(busy_by_s, t1 - busy, t1)
             self._reactor_t1 = t1
 
     def _handle(self, m: Msg) -> None:
@@ -2434,9 +2461,16 @@ class Server:
         # response (pipelined puts match out-of-band responses by it; all
         # puts get re-send dedup from it)
         put_id = m.data.get("put_id")
+        resent = self._fo_metered and m.data.get("fo_resend")
+        if resent:
+            # a pipelined put re-sent across a takeover, under its id
+            self._m_fo_resent.inc()
         if put_id is not None and self._put_seen(m.src, put_id):
             # duplicate of an already-accepted put (the client re-sent
             # after a send error): idempotent ack, nothing stored twice
+            if resent:
+                # ... which the replicated window absorbs
+                self._m_fo_deduped.inc()
             self._send_app(
                 m.src,
                 msg(Tag.TA_PUT_RESP, self.rank, rc=ADLB_SUCCESS,
@@ -5403,20 +5437,8 @@ class Server:
         if w.syncs != synced_before:
             self._m_wal_syncs.inc(w.syncs - synced_before)
             self._h_wal_fsync.observe(w.last_fsync_s)
-        # booked as the reactor's busy time is, a stretch split where it
-        # straddles a second, so a reader can take any window's share
-        t1 = time.monotonic()
-        by_s = self._wal_flush_by_s
-        while True:
-            sec = int(t0)
-            upto = min(t1, sec + 1.0)
-            if by_s and by_s[-1][0] == sec:
-                by_s[-1][1] += upto - t0
-            else:
-                by_s.append([sec, upto - t0])
-            if upto >= t1:
-                break
-            t0 = upto
+        # booked as the reactor's busy time is
+        _book_by_second(self._wal_flush_by_s, t0, time.monotonic())
 
     def _release_wal_acks(self, acks) -> None:
         """Send the put acks a group commit (or compaction) released;
@@ -5595,6 +5617,26 @@ class Server:
             "wal_flush_by_second": {
                 sec: spent for sec, spent in self._wal_flush_by_s},
         }
+
+    def failover_stats(self) -> dict:
+        """A replicating server's own account, for ``finalize_stats()``:
+        what its stream sent (frames, entries, bytes) and applied as a
+        buddy, what a promotion adopted, the re-sent puts it met and
+        absorbed, the master succession's gauge where one happened, and
+        the reactor's seconds in sending ``_flush_repl`` turns by
+        CLOCK_MONOTONIC second."""
+        value = self.metrics.value
+        out = {name: int(value(name)) for name in (
+            "repl_frames", "repl_entries", "repl_bytes", "repl_applied",
+            "failover_adopted", "failover_resent_puts",
+            "failover_deduped_puts")}
+        out["repl_flush_s"] = self._h_repl_flush.sum
+        out["repl_flush_by_second"] = {
+            sec: spent for sec, spent in self._repl_flush_by_s}
+        succession_ms = value("master_failover_mttr_ms")  # reads, mints not
+        if succession_ms:
+            out["master_failover_mttr_ms"] = succession_ms
+        return out
 
     def _void_killed_unit(self, seqno: int) -> None:
         self._killed_units.add(seqno)
@@ -7209,17 +7251,33 @@ class Server:
         r = self.repl
         if r is None:
             return
-        self._g_repl_lag.set(r.pending)
-        blob = r.take()
-        if blob is None:
+        entries = r.pending
+        self._g_repl_lag.set(entries)
+        if not entries:
             return
-        try:
-            self.ep.send(
-                r.buddy, msg(Tag.SS_REPL, self.rank, blob=blob, seq=r.seq)
-            )
-        except OSError:
-            self.flight.record("replication flush failed (buddy gone?)")
-            self._note_server_unreachable(r.buddy)
+        # one frame toward the buddy, as the reactor thread sees it: the
+        # span lands in a profiler session of the process (a traced
+        # master's host plane) and in span_s of the flight artefact
+        metered = self._fo_metered
+        t0 = time.monotonic()
+        with span("adlb.repl.flush", self.metrics if metered else None):
+            blob = r.take()
+            try:
+                self.ep.send(
+                    r.buddy,
+                    msg(Tag.SS_REPL, self.rank, blob=blob, seq=r.seq),
+                )
+            except OSError:
+                self.flight.record("replication flush failed (buddy gone?)")
+                self._note_server_unreachable(r.buddy)
+        if not metered:
+            return
+        t1 = time.monotonic()
+        self._m_repl_frames.inc()
+        self._m_repl_entries.inc(entries)
+        self._m_repl_bytes.inc(len(blob))
+        self._h_repl_flush.observe(t1 - t0)
+        _book_by_second(self._repl_flush_by_s, t0, t1)
 
     def _brain_doc(self) -> dict:
         """The master-only durable control-plane state, as one pickled
@@ -7337,9 +7395,13 @@ class Server:
             return  # a misconfigured peer's stream is ignorable
         from adlb_tpu.runtime import replica
 
-        self.mirrors.setdefault(
+        mirror = self.mirrors.setdefault(
             m.src, replica.ReplicaMirror(m.src)
-        ).apply(m.blob)
+        )
+        before = mirror.entries_applied
+        mirror.apply(m.blob)
+        if self._fo_metered:  # a drain's stream reaches other worlds too
+            self._m_repl_applied.inc(mirror.entries_applied - before)
 
     # -- death detection & fan-out ------------------------------------------
 
@@ -7653,6 +7715,11 @@ class Server:
         ranks."""
         if self.done:
             return
+        with span("adlb.failover.promote",
+                  self.metrics if self._fo_metered else None):
+            self._promote_shard(dead)
+
+    def _promote_shard(self, dead: int) -> None:
         clean = dead in self._clean_retire
         mirror = self.mirrors.pop(dead, None)
         if mirror is None:
@@ -7846,8 +7913,11 @@ class Server:
             # control plane, take the master role under a bumped epoch,
             # and fan the succession before any termination verdict can
             # conclude (the takeover barrier gates exhaustion/END)
-            self._promote_master(dead, mirror, t0)
+            with span("adlb.failover.promote_master", self.metrics):
+                self._promote_master(dead, mirror, t0)
         mttr_ms = (time.monotonic() - t0) * 1e3
+        if self._fo_metered:
+            self._m_fo_adopted.inc(adopted)
         if not clean:
             # a drain is not a failover: the promote machinery is shared
             # but the death metrics (and their acceptance oracles —
@@ -8314,6 +8384,8 @@ class Server:
             sec: busy for sec, busy in self._reactor_busy_by_s}
         if self.wal is not None:
             out.update(self.wal_stats())
+        if self._fo_metered:
+            out.update(self.failover_stats())
         if self.is_master:
             # which path planned (balancer/engine.py solver_facts): the
             # one non-InfoKey entry, so a caller can tell a device solve
