@@ -88,7 +88,10 @@ def _parse_trailer(lines):
     daemon reports by name beside them (``conns_unix``, ``conns_tcp``: the
     connections it opened and accepted, by socket family; ``waits_polled``,
     ``waits_slept``: its reactor's waits that ended inside the polling
-    phase, and those that went on to sleep) keeps its name."""
+    phase, and those that went on to sleep; ``frames_ring``,
+    ``frames_sock``: frames it received, by path; ``bells_rung``,
+    ``bells_elided``: wake-up bytes it sent, and publishes that found their
+    reader awake) keeps its name."""
     import sys
 
     stats: Optional[dict] = None

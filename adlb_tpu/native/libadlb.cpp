@@ -10,10 +10,16 @@
 // Threads: none of its own. The thread that blocks in a call does the reads:
 // it looks at the listeners and the inbound connections with poll(), accepts,
 // reads and decodes what arrives and returns the frame it waited for
-// (poll_inbound). A call that awaits the answer to its own request looks
-// without blocking for a bounded time first (hostsock::poll_budget_s: the
-// answer is as a rule microseconds away, and a wake-up costs more) and
-// sleeps in poll() only when nothing came; every other wait sleeps at once
+// (poll_inbound). Between two native ranks of one host the frames travel
+// through a ring in shared memory and the Unix-domain socket carries only
+// wake-ups and the peer's death (hostsock.hpp): a look at such a
+// connection is a read of its ring's tail, and this rank is sent a wake-up
+// only while it is marked asleep in poll(). A call that awaits the answer
+// to its own request looks without blocking for a bounded time first
+// (hostsock::poll_budget_s: the answer is as a rule microseconds away, and
+// a wake-up costs more; while the answers come through a ring the looks
+// are memory reads and make no system call) and sleeps in poll() only when
+// nothing came; every other wait sleeps at once
 // (wait_for). Inbound traffic therefore makes progress only inside
 // library calls, as the reference's client makes none outside MPI calls:
 // between calls an abort, a pipelined put's response or an app message waits
@@ -274,6 +280,18 @@ struct InConn {
   std::string buf;  // bytes received and not yet decoded: at most one
                     // partial frame once parse_frames has run
   bool established = false;  // has delivered a decodable frame
+  // a Unix connection begins with the connector's hello, which may bring a
+  // ring: then the frames come through it and the socket carries bells
+  bool hello_due = false;
+  hostsock::HelloRx hello;
+  hostsock::RingRx ring;
+};
+
+// A connection this rank opened: the socket, and the ring it made for it
+// when the peer is a native rank of this host.
+struct OutConn {
+  int fd = -1;
+  hostsock::RingTx ring;
 };
 
 struct Ctx {
@@ -289,15 +307,17 @@ struct Ctx {
   // waits for an answer that ended inside the polling phase, and waits that
   // went on to sleep in poll() (wait_for; ADLB_TRACE reports them)
   int64_t waits_polled = 0, waits_slept = 0;
-  // the connection that delivered the last frame, and whether the one
-  // before came over it too: a rank whose answers keep coming over one
-  // connection (its home server's, as a rule) reads that one first
+  // the connection that delivered the last frame, whether the one before
+  // came over it too, and whether it came through a ring: a rank whose
+  // answers keep coming over one connection (its home server's, as a rule)
+  // looks at that one first
   int last_fd = -1;
   bool last_fd_twice = false;
+  bool last_ring = false;
   std::vector<InConn> in;   // inbound connections, read by whoever waits
   std::deque<Msg> inbox;    // decoded frames no call has looked at yet
   std::deque<Msg> app_inbox;  // stashed AM_APP frames (the app_comm channel)
-  std::map<int, int> out_fds;
+  std::map<int, OutConn> out;
 
   int rr = 0;       // round-robin cursor over servers
   bool route_home = false;  // ADLB_PUT_ROUTING=home: untargeted puts -> home
@@ -398,61 +418,142 @@ bool parse_frames(InConn &c) {
       c.established = true;
     }
     g->inbox.push_back(std::move(m));
+    ++(c.ring.on() ? hostsock::ring_stats().frames_ring
+                   : hostsock::ring_stats().frames_sock);
   }
   c.buf.erase(0, off);
   return keep;
 }
 
-// One read of what has arrived on c, never blocking, then parse. The buffer
-// grows with the bytes actually received, never with the advertised length:
-// a connection that sends a large length prefix and then stalls pins
-// neither that memory nor the calling thread. False at EOF, error or
-// garbage: the caller closes the connection.
-bool read_conn(InConn &c) {
-  char chunk[65536];
-  ssize_t r = recv(c.fd, chunk, sizeof chunk, MSG_DONTWAIT);
-  if (r < 0 && (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR))
-    return true;
-  if (r <= 0) return false;
-  c.buf.append(chunk, (size_t)r);
+// Parse what a read brought and remember which connection delivered.
+bool parse_read(InConn &c) {
   size_t had = g->inbox.size();
   bool keep = parse_frames(c);
   if (g->inbox.size() > had) {
     g->last_fd_twice = g->last_fd == c.fd;
     g->last_fd = c.fd;
+    g->last_ring = c.ring.on();
   }
   return keep;
 }
 
-// The library's one look at its sockets: poll() over the two listeners,
-// every inbound connection and (optionally) one outbound socket a send is
-// stuck on; accepts, reads and decodes whatever is ready into g->inbox. With
-// `block` it sleeps there and returns once `wfd` is writable or, given none,
-// once the inbox holds a frame; without, it takes what is there now and
-// returns (the polling phase of wait_for is this form, repeated).
-void poll_inbound(bool block, int wfd = -1) {
+// Take what c's ring holds, give the room back (with a bell if the writer
+// waits for it) and parse. Memory only, but for that bell. False: garbage.
+bool take_ring(InConn &c) {
+  bool bell;
+  ssize_t n = c.ring.take(c.buf, &bell);
+  if (n < 0) {
+    if (c.established) die("ring cursors corrupt on an established connection");
+    return false;
+  }
+  if (n == 0) return true;
+  if (bell) hostsock::ring_bell(c.fd);  // a lost peer shows as EOF by itself
+  return parse_read(c);
+}
+
+// One read of what has arrived on c, never blocking, then parse. The buffer
+// grows with the bytes actually received, never with the advertised length:
+// a connection that sends a large length prefix and then stalls pins
+// neither that memory nor the calling thread. On a connection with a ring
+// the socket's bytes are bells and the frames are taken from the ring; at
+// EOF what the ring still holds comes first. False at EOF, error or
+// garbage (a Unix connection that does not begin with the hello is
+// garbage): the caller closes the connection.
+bool read_conn(InConn &c) {
+  if (c.hello_due) {
+    switch (hostsock::recv_hello(c.fd, c.hello, &c.ring)) {
+      case hostsock::Hello::kMore: return true;
+      case hostsock::Hello::kBad: return false;
+      case hostsock::Hello::kRing: c.ring.sleeps(false); break;
+      case hostsock::Hello::kSocket: break;
+    }
+    c.hello_due = false;
+  }
+  char chunk[65536];
+  ssize_t r = recv(c.fd, chunk, sizeof chunk, MSG_DONTWAIT);
+  bool open = r > 0 || (r < 0 && (errno == EAGAIN || errno == EWOULDBLOCK ||
+                                  errno == EINTR));
+  if (c.ring.on()) return take_ring(c) && open;
+  if (r <= 0) return open;
+  c.buf.append(chunk, (size_t)r);
+  return parse_read(c);
+}
+
+template <class Conn>  // InConn or OutConn: the socket and the mapping
+void close_conn(Conn &c) {
+  close(c.fd);
+  c.ring.close();
+  c.fd = -1;
+}
+
+void drop_closed() {
+  g->in.erase(std::remove_if(g->in.begin(), g->in.end(),
+                             [](const InConn &c) { return c.fd < 0; }),
+              g->in.end());
+}
+
+// A look at every inbound ring: memory reads, one line a ring. What the
+// rings hold goes to the inbox.
+void scan_rings() {
+  bool closed = false;
+  for (InConn &c : g->in)
+    if (c.ring.on() && c.ring.ready() && !take_ring(c)) {
+      close_conn(c);
+      closed = true;
+    }
+  if (closed) drop_closed();
+}
+
+// Mark this rank asleep (or awake again) in every inbound ring, so that a
+// writer rings the socket's bell. True: some ring holds bytes after the
+// mark and the fence, so there is nothing to sleep for.
+bool mark_asleep(bool on) {
+  bool any = false;
+  for (InConn &c : g->in)
+    if (c.ring.on()) {
+      c.ring.sleeps(on);
+      any = true;
+    }
+  if (!on || !any) return false;
+  hostsock::sleep_fence();
+  for (InConn &c : g->in)
+    if (c.ring.on() && c.ring.ready()) return true;
+  return false;
+}
+
+// The library's one look at its connections: the inbound rings first (memory),
+// then poll() over the two listeners, every inbound connection and
+// (optionally) one outbound socket a send is stuck on (`wev`: POLLOUT for a
+// full socket, POLLIN for the bell of a full ring); accepts, reads and
+// decodes whatever is ready into g->inbox. With `block` it sleeps there and
+// returns once `wfd` is ready or, given none, once the inbox holds a frame
+// (at once, and without the system call, if the rings held one); without,
+// it takes what is there now and returns (the polling phase of wait_for is
+// this form, repeated, while answers come over a socket). Before it sleeps
+// it marks this rank asleep in its rings, fences and looks at them once
+// more; nothing insures that sleep but the handshake (hostsock.hpp).
+void poll_inbound(bool block, int wfd = -1, short wev = POLLOUT) {
   static std::vector<struct pollfd> pfds;
   for (;;) {
+    scan_rings();
+    if (block && wfd < 0 && !g->inbox.empty()) return;
+    bool marked = block && !mark_asleep(true);
     pfds.clear();
     // a listener that is not there is -1, which poll() passes over
     pfds.push_back({g->listen_fd, POLLIN, 0});
     pfds.push_back({g->listen_unix_fd, POLLIN, 0});
     for (const InConn &c : g->in) pfds.push_back({c.fd, POLLIN, 0});
-    if (wfd >= 0) pfds.push_back({wfd, POLLOUT, 0});
-    int n = poll(pfds.data(), pfds.size(), block ? -1 : 0);
+    if (wfd >= 0) pfds.push_back({wfd, wev, 0});
+    int n = poll(pfds.data(), pfds.size(), marked ? -1 : 0);
     if (n < 0 && errno != EINTR) die("poll: %s", strerror(errno));
+    if (block) mark_asleep(false);
     if (n > 0) {
       size_t nconn = g->in.size();
       for (size_t i = 0; i < nconn; i++) {
         InConn &c = g->in[i];
-        if (pfds[2 + i].revents != 0 && !read_conn(c)) {
-          close(c.fd);
-          c.fd = -1;
-        }
+        if (pfds[2 + i].revents != 0 && !read_conn(c)) close_conn(c);
       }
-      g->in.erase(std::remove_if(g->in.begin(), g->in.end(),
-                                 [](const InConn &c) { return c.fd < 0; }),
-                  g->in.end());
+      drop_closed();
       for (int l = 0; l < 2; l++) {
         if (pfds[l].revents == 0) continue;
         for (;;) {  // the listeners are non-blocking: take all that wait
@@ -460,25 +561,40 @@ void poll_inbound(bool block, int wfd = -1) {
           if (fd < 0) break;
           g->in.emplace_back();
           g->in.back().fd = fd;
+          g->in.back().hello_due = l == 1;
           ++(l == 0 ? g->conns_tcp : g->conns_unix);
         }
       }
       if (wfd >= 0 && pfds.back().revents != 0) return;
     }
     if (!block || (wfd < 0 && !g->inbox.empty())) return;
-    // woke for a connection, a partial frame or a signal: sleep again
+    // woke for a connection, a partial frame, a ring or a signal: again
   }
 }
 
 // A send that would block never stops this rank's reads: while the socket
-// is full the thread waits in poll_inbound, so two ranks sending each other
-// more than the socket buffers hold both get through.
-bool write_all(int fd, const void *p, size_t n) {
+// or the ring is full the thread waits in poll_inbound, so two ranks sending
+// each other more than their buffers hold both get through. Through a ring
+// the frame goes in as many installments as its size asks for, each
+// published (and the reader woken, if it sleeps) as it is written.
+bool write_all(OutConn &oc, const void *p, size_t n) {
   const char *c = (const char *)p;
+  while (n > 0 && oc.ring.on()) {
+    size_t w = oc.ring.write(c, n);
+    c += w;
+    n -= w;
+    if (w > 0) {
+      if (!oc.ring.kick(oc.fd)) return false;
+      continue;
+    }
+    if (!oc.ring.wait_room()) continue;
+    poll_inbound(true, oc.fd, POLLIN);  // the reader's bell, or its death
+    if (!hostsock::drain_bells(oc.fd)) return false;
+  }
   while (n > 0) {
-    ssize_t r = send(fd, c, n, MSG_DONTWAIT | MSG_NOSIGNAL);
+    ssize_t r = send(oc.fd, c, n, MSG_DONTWAIT | MSG_NOSIGNAL);
     if (r < 0 && (errno == EAGAIN || errno == EWOULDBLOCK)) {
-      poll_inbound(true, fd);
+      poll_inbound(true, oc.fd);
       continue;
     }
     if (r < 0 && errno == EINTR) continue;
@@ -493,8 +609,9 @@ bool write_all(int fd, const void *p, size_t n) {
 // (hostsock.hpp): a destination on this rank's host is tried at its port's
 // Unix name first, on every attempt, so a peer that is not up yet (it
 // refuses both) never pins the pair on TCP; a peer with no such listener (a
-// Python rank) and a destination on another host get TCP.
-int connect_to(int dest) {
+// Python rank) and a destination on another host get TCP. A Unix connection
+// begins with the hello, and with it the ring when one can be made.
+OutConn connect_to(int dest) {
   auto &hp = g->addr[dest];
   bool local = hostsock::same_host(hp.first, g->addr[g->rank].first);
   struct addrinfo hints = {}, *res = nullptr;
@@ -504,10 +621,15 @@ int connect_to(int dest) {
   snprintf(port, sizeof port, "%d", hp.second);
   // servers may come up after us: retry with backoff for ~15 s
   for (int attempt = 0; attempt < 60; attempt++) {
-    int ufd = local ? hostsock::connect_unix(hp.second) : -1;
-    if (ufd >= 0) {
-      g->conns_unix++;
-      return ufd;
+    OutConn oc;
+    oc.fd = local ? hostsock::connect_unix(hp.second) : -1;
+    if (oc.fd >= 0) {
+      if (oc.ring.open(oc.fd)) {
+        g->conns_unix++;
+        return oc;
+      }
+      close(oc.fd);  // gone between connect and hello: try again
+      oc.fd = -1;
     }
     if (getaddrinfo(hp.first.c_str(), port, &hints, &res) == 0) {
       int fd = socket(res->ai_family, res->ai_socktype, res->ai_protocol);
@@ -516,7 +638,8 @@ int connect_to(int dest) {
         setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof one);
         freeaddrinfo(res);
         g->conns_tcp++;
-        return fd;
+        oc.fd = fd;
+        return oc;
       }
       if (fd >= 0) close(fd);
       freeaddrinfo(res);
@@ -525,22 +648,17 @@ int connect_to(int dest) {
     usleep(250 * 1000);
   }
   die("cannot connect to rank %d at %s:%d", dest, hp.first.c_str(), hp.second);
-  return -1;
+  return OutConn();
 }
 
 void send_msg(int dest, Encoder &enc) {
   std::string frame = enc.finish();
-  auto it = g->out_fds.find(dest);
-  int fd = it == g->out_fds.end() ? -1 : it->second;
-  if (fd < 0) {
-    fd = connect_to(dest);
-    g->out_fds[dest] = fd;
-  }
-  if (!write_all(fd, frame.data(), frame.size())) {
-    close(fd);
-    fd = connect_to(dest);  // one reconnect attempt
-    g->out_fds[dest] = fd;
-    if (!write_all(fd, frame.data(), frame.size()))
+  OutConn &oc = g->out[dest];
+  if (oc.fd < 0) oc = connect_to(dest);
+  if (!write_all(oc, frame.data(), frame.size())) {
+    close_conn(oc);
+    oc = connect_to(dest);  // one reconnect attempt
+    if (!write_all(oc, frame.data(), frame.size()))
       die("send to rank %d failed", dest);
   }
 }
@@ -658,28 +776,36 @@ void dispatch_passive(Msg m) {
 }
 
 // Until the inbox holds a frame: look for it without blocking for the
-// polling budget, over the same descriptors as the sleep (so connections
-// are accepted and other peers' frames read meanwhile), then sleep. The
-// caller has a request out, so the frame is on its way. Where the last two
-// frames came over one connection, each look starts with a read of that
-// connection alone: a hit there costs one system call, not poll() and then
-// the read; the others are still looked at in the same turn.
+// polling budget, then sleep. The caller has a request out, so the frame is
+// on its way. While the answers come through a ring a look is a read of the
+// inbound rings' tails (a handful of cache lines at a client, so no ring is
+// looked at first): no system call, and the descriptors (a frame from a TCP
+// peer, a new connection) wait for the sleep that ends the phase, one
+// budget at most. While they come over a socket a look is poll() over the
+// same descriptors as the sleep, and where the last two frames came over
+// one connection it starts with a read of that connection alone: a hit
+// there costs one system call, not poll() and then the read; the others are
+// still looked at in the same turn.
 void await_answer() {
   double budget = hostsock::poll_budget_s();
   if (budget > 0) {
     double deadline = monotonic() + budget;
     do {
-      auto last = g->last_fd_twice
-                      ? std::find_if(g->in.begin(), g->in.end(),
-                                     [](const InConn &c) {
-                                       return c.fd == g->last_fd;
-                                     })
-                      : g->in.end();
-      if (last != g->in.end() && !read_conn(*last)) {
-        close(last->fd);
-        g->in.erase(last);
+      if (g->last_ring) {
+        scan_rings();
+      } else {
+        auto last = g->last_fd_twice
+                        ? std::find_if(g->in.begin(), g->in.end(),
+                                       [](const InConn &c) {
+                                         return c.fd == g->last_fd;
+                                       })
+                        : g->in.end();
+        if (last != g->in.end() && !read_conn(*last)) {
+          close_conn(*last);
+          g->in.erase(last);
+        }
+        if (g->inbox.empty()) poll_inbound(false);
       }
-      if (g->inbox.empty()) poll_inbound(false);
       if (!g->inbox.empty()) {
         g->waits_polled++;
         return;
@@ -810,6 +936,17 @@ static void trace_flush(int rank) {
           monotonic() * 1e6, rank, rank,
           (long long)(g ? g->waits_polled : 0),
           (long long)(g ? g->waits_slept : 0));
+  // and what the rings did (hostsock.hpp): frames received by path, bells
+  // sent, publishes that found the reader awake
+  const hostsock::RingStats &rs = hostsock::ring_stats();
+  fprintf(f,
+          ",{\"name\":\"adlb:rings\",\"ph\":\"C\",\"ts\":%.3f,"
+          "\"pid\":%d,\"tid\":%d,\"args\":{\"frames_ring\":%lld,"
+          "\"frames_sock\":%lld,\"bells_rung\":%lld,"
+          "\"bells_elided\":%lld}}",
+          monotonic() * 1e6, rank, rank, (long long)rs.frames_ring,
+          (long long)rs.frames_sock, (long long)rs.bells_rung,
+          (long long)rs.bells_elided);
   fprintf(f, "]\n");
   fclose(f);
 }
@@ -1199,12 +1336,14 @@ int ADLBP_Finalize(void) {
   }
   Encoder e(T_FA_LOCAL_APP_DONE, g->rank);
   send_msg(g->home, e);
-  for (auto &kv : g->out_fds) {
-    shutdown(kv.second, SHUT_WR);  // FIN after data; no unread inbound
-    close(kv.second);
+  for (auto &kv : g->out) {
+    // FIN after data; no unread inbound. What a ring holds is the reader's
+    // to take after the EOF: its mapping outlives this one
+    shutdown(kv.second.fd, SHUT_WR);
+    close_conn(kv.second);
   }
-  g->out_fds.clear();
-  for (InConn &c : g->in) close(c.fd);
+  g->out.clear();
+  for (InConn &c : g->in) close_conn(c);
   g->in.clear();
   close(g->listen_fd);
   g->listen_fd = -1;

@@ -517,24 +517,32 @@ NMsg decode(std::string_view body) {
 // sockets have bytes queued; it accepts, reads, decodes and flushes on the
 // calling thread, so a request is dispatched and answered by the thread
 // that read it and the daemon has no other. After a turn that carried
-// traffic recv asks without blocking for a bounded time before it sleeps
+// traffic recv looks without blocking for a bounded time before it sleeps
 // there (hostsock::poll_budget_s: a synchronous peer's next request follows
 // its answer within microseconds, and a wake-up costs more); after a turn
 // that ended on its timeout it sleeps at once, so an idle daemon polls
 // nothing. Wire form as the native
 // client's transport (libadlb.cpp) and the Python TcpEndpoint: persistent
-// outbound stream sockets, 4-byte LE length prefix per frame. Two listeners,
-// the TCP port and that port's Unix name: a native rank of this host
-// connects to the name, everyone else to the port, and this end does the
-// same when it connects (hostsock.hpp says how the family is chosen from
-// the address map and the peer's answer; nothing selects it). Above the
-// socket the two families are one code path.
+// outbound stream connections, 4-byte LE length prefix per frame. Two
+// listeners, the TCP port and that port's Unix name: a native rank of this
+// host connects to the name, everyone else to the port, and this end does
+// the same when it connects (hostsock.hpp says how the family is chosen from
+// the address map and the peer's answer; nothing selects it). A Unix
+// connection begins with the connector's hello and, when that brings one,
+// carries its frames through a ring in shared memory: the socket is then
+// the doorbell (a byte wakes a reader that marked itself asleep, or a
+// writer that waits for room) and the sign of the peer's death. A look at
+// such a connection is a read of its ring's tail; while the traffic comes
+// through rings the polling phase repeats those reads and asks epoll once
+// a budget, and a frame that finds its reader awake costs no system call
+// at either end. Above the connection socket and ring are one code path.
 //
 // A send never blocks. Frames are queued per destination and handed to the
-// sockets when the reactor has dispatched what it had read (flush_pending);
-// what a socket does not take at once stays queued and goes when epoll
-// reports it writable, so two daemons shipping each other more than the
-// socket buffers hold both keep reading.
+// sockets and rings when the reactor has dispatched what it had read
+// (flush_pending); what one does not take at once stays queued and goes
+// when epoll reports the socket writable or the ring's reader rings for
+// room, so two daemons shipping each other more than their buffers hold
+// both keep reading.
 
 class Endpoint {
  public:
@@ -585,7 +593,7 @@ class Endpoint {
 
   void send(int dest, const NMsg& m) {
     OutConn& oc = out_[dest];
-    if (oc.fd < 0) oc.fd = connect_to(dest);
+    if (oc.fd < 0) connect_to(dest, oc);
     if (oc.fd < 0) {
       // peer unreachable after the retry window (shutdown races): drop this
       // frame loudly, but leave the slot retryable so a recovered peer is
@@ -623,9 +631,13 @@ class Endpoint {
   //
   // The answers leave first (flush_pending). Then, if frames were handed
   // out since the last wait (the turn that just ended carried traffic),
-  // the sockets are asked without blocking until a frame is in, the polling
-  // budget has passed or `timeout` is due; only then does the reactor
-  // sleep. The budget is shorter than any of periodic()'s intervals.
+  // the connections are looked at without blocking until a frame is in, the
+  // polling budget has passed or `timeout` is due; only then does the
+  // reactor sleep. A look is wait_io(0) while the frames come over sockets;
+  // while they come through rings it is a scan of the rings' tails, and
+  // epoll is asked once a budget (under a flood of hits too, so a TCP
+  // peer's frame or a new connection waits one budget at most). The budget
+  // is shorter than any of periodic()'s intervals.
   bool recv(NMsg* out, double timeout) {
     if (inbox_.empty()) {
       flush_pending();
@@ -633,10 +645,12 @@ class Endpoint {
       served_ = false;
       double budget = std::min(hostsock::poll_budget_s(), timeout);
       if (traffic && budget > 0) {
-        double deadline = monotonic() + budget;
+        double now = monotonic(), deadline = now + budget;
         do {
-          wait_io(0);
-        } while (inbox_.empty() && monotonic() < deadline);
+          if (!last_ring_ || now - asked_at_ >= budget) wait_io(0);
+          else scan_rings();
+          now = monotonic();
+        } while (inbox_.empty() && now < deadline);
       }
       if (inbox_.empty()) {
         ++waits_slept_;
@@ -671,6 +685,7 @@ class Endpoint {
     closed_ = true;
     if (lsock_ >= 0) close(lsock_);
     if (usock_ >= 0) close(usock_);
+    // what a ring still holds is its reader's to take after the EOF
     for (auto& kv : out_)
       if (kv.second.fd >= 0) {
         shutdown(kv.second.fd, SHUT_WR);
@@ -683,17 +698,26 @@ class Endpoint {
   enum Kind : uint64_t { kListener = 0, kInbound = 1, kOutbound = 2 };
 
   struct InConn {
+    int fd = -1;
     std::string buf;  // received, not yet decoded: at most a partial frame
                       // once parse_frames has run
     int32_t last_src = -1;
     bool established = false;  // has delivered a decodable frame
+    // a Unix connection begins with the connector's hello, which may bring
+    // a ring: then the frames come through it, the socket carries bells
+    bool hello_due = false;
+    hostsock::HelloRx hello;
+    hostsock::RingRx ring;
   };
 
   struct OutConn {
     int fd = -1;
+    hostsock::RingTx ring;      // made at connect, toward a native local rank
     std::deque<std::string> q;  // whole frames, head partly sent
-    size_t off = 0;             // bytes of q.front() the socket has taken
-    bool armed = false;         // in the epoll set, waiting for EPOLLOUT
+    size_t off = 0;             // bytes of q.front() already taken
+    // waiting to go on: a socket for EPOLLOUT (in the epoll set for that
+    // alone), a ring for its reader's bell (always in the set, for EPOLLIN)
+    bool armed = false;
     bool pending = false;       // in pending_, waiting for flush_pending()
   };
 
@@ -713,12 +737,26 @@ class Endpoint {
 
   // The daemon's one wait: sleep until a socket is ready or `timeout`
   // seconds pass (0: ask and return, the form recv's polling phase repeats),
-  // then do what each ready socket asks for. Level-triggered, one read a
-  // ready connection a turn: a flooding peer gets its 64 KB and the loop
-  // goes round, so periodic() keeps its deadlines.
+  // then do what each ready socket asks for, and look at every ring.
+  // Level-triggered, one read a ready connection a turn: a flooding peer
+  // gets its 64 KB and the loop goes round, so periodic() keeps its
+  // deadlines. Before a sleep the reactor marks itself asleep in its
+  // inbound rings, fences and looks at them once more: a writer that
+  // published before it could see the mark is found by that look, one that
+  // publishes after it rings the bell (hostsock.hpp; no timer insures it).
   void wait_io(double timeout) {
     epoll_event evs[64];
     if (timeout < 0) timeout = 0;
+    bool marked = timeout > 0 && !rings_.empty();
+    if (marked) {
+      for (InConn* c : rings_) c->ring.sleeps(true);
+      hostsock::sleep_fence();
+      for (InConn* c : rings_)
+        if (c->ring.ready()) {
+          timeout = 0;
+          break;
+        }
+    }
     timespec ts;
     ts.tv_sec = time_t(timeout);
     ts.tv_nsec = long((timeout - double(ts.tv_sec)) * 1e9);
@@ -729,6 +767,9 @@ class Endpoint {
     }
     if (!have_pwait2_)  // kernels before 5.11: whole milliseconds
       n = epoll_wait(epfd_, evs, 64, int(std::ceil(timeout * 1e3)));
+    if (marked)
+      for (InConn* c : rings_) c->ring.sleeps(false);
+    asked_at_ = monotonic();
     if (n < 0) {
       if (errno == EINTR) return;
       die("epoll wait: %s", strerror(errno));
@@ -740,41 +781,96 @@ class Endpoint {
         case kInbound: read_conn(id); break;
         case kOutbound: {
           auto it = out_.find(id);
-          if (it != out_.end() && it->second.armed) flush(id, it->second);
+          if (it == out_.end()) break;
+          if (it->second.ring.on()) out_bell(id, it->second);
+          else if (it->second.armed) flush(id, it->second);
           break;
         }
       }
     }
+    scan_rings();
   }
 
   void accept_all(int lsock) {
     for (;;) {
       int conn = accept(lsock, nullptr, nullptr);
       if (conn < 0) return;
-      in_[conn];
+      InConn& c = in_[conn];
+      c.fd = conn;
+      c.hello_due = lsock == usock_;
       watch(EPOLL_CTL_ADD, conn, EPOLLIN, kInbound, conn);
       ++(lsock == usock_ ? conns_unix_ : conns_tcp_);
     }
+  }
+
+  // A look at every inbound ring: memory reads, one line a ring; what they
+  // hold goes to the inbox in order.
+  void scan_rings() {
+    for (size_t i = 0; i < rings_.size(); ++i) {
+      InConn* c = rings_[i];
+      if (c->ring.ready() && !take_ring(*c)) {
+        end_conn(c->fd);  // takes c out of rings_
+        --i;
+      }
+    }
+  }
+
+  // Take what c's ring holds, give the room back (with a bell if the writer
+  // waits for it) and decode. False: garbage, the connection must close.
+  bool take_ring(InConn& c) {
+    bool bell;
+    ssize_t n = c.ring.take(c.buf, &bell);
+    if (n < 0) {
+      if (c.established)
+        die("ring cursors corrupt on the connection of rank %d", c.last_src);
+      return false;
+    }
+    if (n == 0) return true;
+    if (bell) hostsock::ring_bell(c.fd);  // a lost peer shows as EOF by itself
+    return parse_frames(c);
   }
 
   // One read of what has arrived, never blocking; every whole frame goes to
   // the inbox in order. The buffer grows with the bytes actually received,
   // never with the advertised length: a connection that sends a large
   // length prefix and then stalls pins neither that memory nor the reactor.
+  // On a connection with a ring the socket's bytes are bells and the frames
+  // are taken from the ring; at EOF what the ring still holds comes first.
+  // A Unix connection that does not begin with the hello is a stray and is
+  // closed.
   void read_conn(int conn) {
     auto it = in_.find(conn);
     if (it == in_.end()) return;
     InConn& c = it->second;
+    if (c.hello_due) {
+      switch (hostsock::recv_hello(conn, c.hello, &c.ring)) {
+        case hostsock::Hello::kMore: return;
+        case hostsock::Hello::kBad: end_conn(conn); return;
+        case hostsock::Hello::kRing:
+          c.ring.sleeps(false);
+          rings_.push_back(&c);  // in_'s nodes stay where they are
+          break;
+        case hostsock::Hello::kSocket: break;
+      }
+      c.hello_due = false;
+    }
     char chunk[65536];
     ssize_t r = ::recv(conn, chunk, sizeof chunk, MSG_DONTWAIT);
-    if (r < 0 && (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR))
-      return;
-    bool open = r > 0;
-    if (open) {
+    bool open = r > 0 || (r < 0 && (errno == EAGAIN || errno == EWOULDBLOCK ||
+                                    errno == EINTR));
+    if (c.ring.on()) {
+      open = take_ring(c) && open;
+    } else if (r > 0) {
       c.buf.append(chunk, size_t(r));
       open = parse_frames(c);
     }
-    if (open) return;
+    if (!open) end_conn(conn);
+  }
+
+  void end_conn(int conn) {
+    auto it = in_.find(conn);
+    if (it == in_.end()) return;
+    InConn& c = it->second;
     // EOF after the peer's frames: synthetic in-order signal so the
     // reactor can tell a finalized peer from a dead one (the reference's
     // failure model is rank-death-kills-job, src/adlb.c:2508-2526)
@@ -783,6 +879,10 @@ class Endpoint {
       eof.tag = T_PEER_EOF;
       eof.src = c.last_src;
       inbox_.push_back(std::move(eof));
+    }
+    if (c.ring.on()) {
+      rings_.erase(std::find(rings_.begin(), rings_.end(), &c));
+      c.ring.close();
     }
     in_.erase(it);
     close(conn);  // leaves the epoll set with its last descriptor
@@ -851,60 +951,105 @@ class Endpoint {
       c.established = true;
       c.last_src = m.src;
       inbox_.push_back(std::move(m));
+      last_ring_ = c.ring.on();
+      ++(last_ring_ ? hostsock::ring_stats().frames_ring
+                    : hostsock::ring_stats().frames_sock);
     }
     c.buf.erase(0, off);
     return keep;
   }
 
-  // Hand the queue to the socket, up to 16 frames a system call, until it
-  // is empty or the socket is full; then the destination waits in the
-  // epoll set for EPOLLOUT. A socket error restarts the head frame from
-  // its first byte on a fresh connection, once; a second one drops what is
-  // queued, loudly.
+  // Hand the queue to the connection until it is empty or the connection is
+  // full: to a socket up to 16 frames a system call, after which the
+  // destination waits in the epoll set for EPOLLOUT; to a ring as many
+  // frames as fit (a frame larger than the room in installments), published
+  // together, after which the destination waits for the reader's bell. An
+  // error restarts the head frame from its first byte on a fresh
+  // connection, once; a second one drops what is queued, loudly.
   void flush(int dest, OutConn& oc) {
     bool retried = false;
     while (!oc.q.empty()) {
-      iovec iov[16];
-      msghdr mh{};
-      mh.msg_iov = iov;
-      size_t skip = oc.off;
-      for (auto f = oc.q.begin(); f != oc.q.end() && mh.msg_iovlen < 16; ++f) {
-        iov[mh.msg_iovlen].iov_base = const_cast<char*>(f->data()) + skip;
-        iov[mh.msg_iovlen].iov_len = f->size() - skip;
-        mh.msg_iovlen += 1;
-        skip = 0;
-      }
-      ssize_t n = sendmsg(oc.fd, &mh, MSG_NOSIGNAL | MSG_DONTWAIT);
-      if (n < 0 && (errno == EAGAIN || errno == EWOULDBLOCK)) {
-        if (!oc.armed) watch(EPOLL_CTL_ADD, oc.fd, EPOLLOUT, kOutbound, dest);
-        oc.armed = true;
-        return;
-      }
-      if (n < 0 && errno == EINTR) continue;
-      if (n <= 0) {
-        close(oc.fd);  // leaves the epoll set with it
-        oc.armed = false;
-        oc.off = 0;
-        oc.fd = retried ? -1 : connect_to(dest);
+      if (oc.fd < 0) {  // lost: one fresh connection, or what is queued goes
+        if (!retried) connect_to(dest, oc);
         retried = true;
         if (oc.fd < 0) {
           std::fprintf(stderr,
                        "[adlb_serverd] dropping %zu queued frame(s) to "
                        "rank %d: send failed\n", oc.q.size(), dest);
           oc.q.clear();
+          break;
         }
-        continue;
       }
-      oc.off += size_t(n);
-      while (!oc.q.empty() && oc.off >= oc.q.front().size()) {
-        oc.off -= oc.q.front().size();
-        oc.q.pop_front();
+      bool lost = false;
+      if (oc.ring.on()) {
+        size_t wrote = 0;
+        while (!oc.q.empty()) {
+          const std::string& f = oc.q.front();
+          size_t n = oc.ring.write(f.data() + oc.off, f.size() - oc.off);
+          wrote += n;
+          oc.off += n;
+          if (oc.off < f.size()) break;  // the ring is full
+          oc.q.pop_front();
+          oc.off = 0;
+        }
+        lost = wrote > 0 && !oc.ring.kick(oc.fd);
+        if (!lost && !oc.q.empty() && oc.ring.wait_room()) {
+          oc.armed = true;  // out_bell comes back here
+          return;
+        }
+      } else {
+        iovec iov[16];
+        msghdr mh{};
+        mh.msg_iov = iov;
+        size_t skip = oc.off;
+        for (auto f = oc.q.begin(); f != oc.q.end() && mh.msg_iovlen < 16;
+             ++f) {
+          iov[mh.msg_iovlen].iov_base = const_cast<char*>(f->data()) + skip;
+          iov[mh.msg_iovlen].iov_len = f->size() - skip;
+          mh.msg_iovlen += 1;
+          skip = 0;
+        }
+        ssize_t n = sendmsg(oc.fd, &mh, MSG_NOSIGNAL | MSG_DONTWAIT);
+        if (n < 0 && (errno == EAGAIN || errno == EWOULDBLOCK)) {
+          if (!oc.armed)
+            watch(EPOLL_CTL_ADD, oc.fd, EPOLLOUT, kOutbound, dest);
+          oc.armed = true;
+          return;
+        }
+        if (n < 0 && errno == EINTR) continue;
+        lost = n <= 0;
+        if (!lost) {
+          oc.off += size_t(n);
+          while (!oc.q.empty() && oc.off >= oc.q.front().size()) {
+            oc.off -= oc.q.front().size();
+            oc.q.pop_front();
+          }
+        }
       }
+      if (lost) drop_out(oc);
     }
-    if (oc.armed) {
+    if (oc.armed && !oc.ring.on())
       epoll_ctl(epfd_, EPOLL_CTL_DEL, oc.fd, nullptr);
-      oc.armed = false;
-    }
+    oc.armed = false;
+  }
+
+  // Close a destination's connection; what is queued stays, its head frame
+  // to start again from its first byte.
+  void drop_out(OutConn& oc) {
+    close(oc.fd);  // leaves the epoll set with it
+    oc.ring.close();
+    oc.fd = -1;
+    oc.armed = false;
+    oc.off = 0;
+  }
+
+  // The socket of a connection with a ring is readable: the reader rang for
+  // room, or it is gone. A reader that is gone is found here and not at the
+  // next send, as a full socket would have told it.
+  void out_bell(int dest, OutConn& oc) {
+    bool gone = !hostsock::drain_bells(oc.fd);
+    if (gone) drop_out(oc);  // with nothing queued the next send connects
+    if (gone || oc.armed) flush(dest, oc);
   }
 
   // The family comes from the address map and the peer's answer alone
@@ -912,8 +1057,11 @@ class Endpoint {
   // port's Unix name first, on every attempt, so a peer that is not up yet
   // (it refuses both) never pins the pair on TCP; a peer with no such
   // listener (the Python sidecar, debug server or app rank) and a
-  // destination on another host get TCP.
-  int connect_to(int dest) {
+  // destination on another host get TCP. A Unix connection begins with the
+  // hello, and with it the ring when one can be made; its socket stays in
+  // the epoll set for the reader's bells and its death. oc.fd is -1 when
+  // nobody answered within the retry window.
+  void connect_to(int dest, OutConn& oc) {
     auto it = addr_map_.find(dest);
     if (it == addr_map_.end()) die("no address for rank %d", dest);
     auto self = addr_map_.find(rank_);
@@ -922,9 +1070,16 @@ class Endpoint {
     double deadline = monotonic() + 15.0;
     for (;;) {
       int usock = local ? hostsock::connect_unix(it->second.second) : -1;
+      if (usock >= 0 && !oc.ring.open(usock)) {
+        close(usock);  // gone between connect and hello: try again
+        usock = -1;
+      }
       if (usock >= 0) {
         ++conns_unix_;
-        return usock;
+        oc.fd = usock;
+        if (oc.ring.on())
+          watch(EPOLL_CTL_ADD, usock, EPOLLIN, kOutbound, dest);
+        return;
       }
       int sock = socket(AF_INET, SOCK_STREAM, 0);
       sockaddr_in addr{};
@@ -935,10 +1090,11 @@ class Endpoint {
         int one = 1;
         setsockopt(sock, IPPROTO_TCP, TCP_NODELAY, &one, sizeof(one));
         ++conns_tcp_;
-        return sock;
+        oc.fd = sock;
+        return;
       }
       close(sock);
-      if (monotonic() >= deadline || closed_) return -1;
+      if (monotonic() >= deadline || closed_) return;
       usleep(50000);
     }
   }
@@ -951,10 +1107,13 @@ class Endpoint {
   int conns_unix_ = 0, conns_tcp_ = 0;
   int64_t waits_polled_ = 0, waits_slept_ = 0;
   bool served_ = false;  // a frame was handed out since recv last waited
+  bool last_ring_ = false;  // the last frame decoded came through a ring
+  double asked_at_ = 0.0;   // when epoll was last asked (wait_io)
   bool closed_ = false;
   bool have_pwait2_ = true;  // until the kernel says ENOSYS
   std::map<int, std::pair<std::string, int>> addr_map_;
   std::unordered_map<int, InConn> in_;   // by descriptor
+  std::vector<InConn*> rings_;  // those of in_ whose frames come by ring
   std::unordered_map<int, OutConn> out_;  // by destination rank
   std::vector<int> pending_;  // destinations with frames flush_pending() owes
   std::deque<NMsg> inbox_;  // read and decoded, not yet dispatched
@@ -1110,6 +1269,13 @@ class Server {
     // and how the reactor's waits ended: polling, or asleep in epoll
     os << ", \"waits_polled\": " << ep_->waits_polled()
        << ", \"waits_slept\": " << ep_->waits_slept();
+    // and what the rings did (hostsock.hpp): frames received by path, bells
+    // sent, publishes that found the reader awake
+    const hostsock::RingStats& rs = hostsock::ring_stats();
+    os << ", \"frames_ring\": " << rs.frames_ring
+       << ", \"frames_sock\": " << rs.frames_sock
+       << ", \"bells_rung\": " << rs.bells_rung
+       << ", \"bells_elided\": " << rs.bells_elided;
     os << "}";
     std::printf("%s\n", os.str().c_str());
     std::fflush(stdout);
@@ -1401,8 +1567,6 @@ class Server {
         now - last_event_snap_ >= cfg_.balancer_min_gap)
       flush_event_deltas(now);
     if (now >= next_qmstat_) {
-      next_qmstat_ = cfg_.tpu_mode ? now + cfg_.balancer_interval
-                                   : now + cfg_.qmstat_interval;
       if (cfg_.tpu_mode) {
         // O(wq) walk: fast cadence only while someone is parked AND this
         // server could contribute (inventory for the solve, or its own
@@ -1418,6 +1582,16 @@ class Server {
         broadcast_qmstat();
       }
       if (mem_under_pressure()) try_push();
+      // The next one is due an interval after this one is DONE. A snapshot
+      // walks and sorts the whole queue (some 30 ms at 170,000 units), and
+      // counted from its start a duty that outlasts its interval is due
+      // again the moment it ends: run()'s drain then stops at its first
+      // frame, every turn, and the reactor serves one frame a snapshot.
+      // Parked workers keep `hungry_` up, which keeps the cadence fast, so
+      // a server that got there stayed there (a flood that outran the
+      // planner's first migrations did it).
+      next_qmstat_ = monotonic() + (cfg_.tpu_mode ? cfg_.balancer_interval
+                                                  : cfg_.qmstat_interval);
     }
     if (master_ && now >= next_exhaust_) {
       next_exhaust_ = now + cfg_.exhaust_check_interval;

@@ -10,13 +10,25 @@ sockets (its own little TLV encoder, so garbage is as easy as sense).
 
 Two native ranks of one host talk over a Unix-domain socket named after the
 listener's TCP port, and over TCP with everyone else (``hostsock.hpp``); the
-family is chosen from the address map and the peer's answer, and everything
-above the socket is one code path. So the invariants are held once per
-family (the ``family`` fixture): with ``unix`` the test's own ranks listen
+family is chosen from the address map and the peer's answer. A Unix
+connection begins with the connector's hello, which brings a ring in shared
+memory when one can be made: the frames then go through the ring, and the
+socket carries wake-ups (one byte, a bell) and the peer's death. Everything
+above the connection is one code path. So the invariants are held once per
+family (the ``family`` fixture): with ``ring`` the test's own ranks listen
 on their port's name and connect to the native rank's, as a native rank
-would; with ``tcp`` they have no such listener, as a Python rank has none,
-and the native rank is told they live on another host. The last tests hold
-the choice itself: who is tried over which family, and whose name is whose.
+would, and play both ends of the rings from Python (``RingConn``,
+``_RingIn``: the layout is ``hostsock.hpp``'s ``RingHdr``); with ``unix``
+nobody has a segment, so every Unix connection carries its bytes on the
+socket: the test's hello says so, and the native ranks are a test-only build
+whose segment constructor fails (``-DADLB_TEST_NO_SEGMENT``; nothing at run
+time selects that); with ``tcp`` the test's ranks have no Unix listener, as
+a Python rank has none, and the native rank is told they live on another
+host. Then come the ring's own invariants (frames of every size against the
+ring's, a full ring toward a stopped reader, the lost wake-up, a peer killed
+with frames in its ring, a connection without a hello, the counters), and
+the last tests hold the choice itself: who is tried over which family, and
+whose name is whose.
 
 A rank that awaits a frame looks for it without blocking for a bounded time
 before it sleeps (``hostsock::poll_budget_s``): the client in a wait for the
@@ -35,10 +47,14 @@ needs, and running into it is the failure (a hang), not a slow pass.
 
 import collections
 import contextlib
+import ctypes
 import json
+import mmap
 import os
+import random
 import selectors
 import shutil
+import signal
 import socket
 import struct
 import subprocess
@@ -64,9 +80,45 @@ LIMIT_S = 60.0  # a step that takes this long has hung
 OTHER_HOST = "0.0.0.0"
 
 
-@pytest.fixture(params=["tcp", "unix"])
+def _noseg_builds():
+    """(adlb_serverd, libadlb.so) built with ``-DADLB_TEST_NO_SEGMENT``: the
+    shipped sources, but ``hostsock::make_segment`` fails, so every hello
+    these ranks send says "no ring" and their connections carry the bytes on
+    the socket. Content-keyed beside the shipped builds."""
+    from adlb_tpu.native import build, capi
+
+    flag = "-DADLB_TEST_NO_SEGMENT"
+    serverd = build.build_artifact(
+        "adlb_serverd",
+        ["g++", "-O2", "-std=c++17", flag, "-o", "{out}", build._SERVERD_SRC],
+        [build._SERVERD_SRC, build._WQ_HDR, build.HOSTSOCK_HDR])
+    lib = build.build_artifact(
+        "libadlb.so",
+        ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", flag,
+         f"-I{capi._INCLUDE}", "-o", "{out}", capi._SRC, capi._FSRC],
+        [capi._SRC, capi._FSRC, capi._HDR, build.HOSTSOCK_HDR])
+    return serverd, lib
+
+
+@pytest.fixture
+def noseg(monkeypatch):
+    """The native ranks this test starts are the build without segments."""
+    serverd, lib = _noseg_builds()
+    monkeypatch.setattr("adlb_tpu.native.build.ensure_serverd",
+                        lambda: serverd)
+    monkeypatch.setattr("adlb_tpu.native.capi.build_libadlb", lambda: lib)
+
+
+@pytest.fixture(params=["tcp", "unix", "ring"])
 def family(request):
+    if request.param == "unix":
+        request.getfixturevalue("noseg")
     return request.param
+
+
+def sock_of(family):
+    """The socket family under ``family``: a ring rides a Unix connection."""
+    return "tcp" if family == "tcp" else "unix"
 
 
 @pytest.fixture(params=["polling", "asleep"])
@@ -90,7 +142,127 @@ def bound_names():
 
 def peer_host(family):
     """Where a native rank is told the test's ranks live."""
-    return "127.0.0.1" if family == "unix" else OTHER_HOST
+    return OTHER_HOST if family == "tcp" else "127.0.0.1"
+
+
+# ---- hostsock.hpp's hello and ring, from Python ---------------------------
+
+RING = 1 << 16  # kRingBytes
+_HDR = 256  # sizeof(RingHdr); the data area follows
+_MAGIC = 0x31676E6972424C44  # kRingMagic
+_TAIL, _WAITS, _HEAD, _SLEEPS = 64, 72, 128, 136
+
+
+def hello(ring_bytes, version=1, magic=b"ADLBring"):
+    return magic + struct.pack("<II", version, ring_bytes)
+
+
+def _cursors(m):
+    """The segment's four shared words as ctypes views of the mapping: each
+    load and store is one aligned access, as the native side's atomics are
+    (``struct.pack_into`` writes byte by byte, and a reader would see a
+    cursor torn)."""
+    return {name: kind.from_buffer(m, off) for name, kind, off in (
+        ("tail", ctypes.c_uint64, _TAIL), ("waits", ctypes.c_uint32, _WAITS),
+        ("head", ctypes.c_uint64, _HEAD), ("sleeps", ctypes.c_uint32, _SLEEPS))}
+
+
+class RingConn:
+    """The connecting end of a Unix connection with a ring, as a native rank
+    makes it: an anonymous segment, the hello with its descriptor attached,
+    then every byte through the ring. Looks like the socket it wraps where
+    the tests use one. Python has no fences, so after each publish it rings
+    the bell whatever the reader's mark says (a bell too many is only a
+    byte the reader drains); ``bell=False`` leaves the frames in the ring
+    unannounced."""
+
+    def __init__(self, sock):
+        self.sock = sock
+        fd = os.memfd_create("test.ring")
+        os.ftruncate(fd, _HDR + RING)
+        self.m = mmap.mmap(fd, _HDR + RING)
+        struct.pack_into("<QI", self.m, 0, _MAGIC, RING)
+        self.c = _cursors(self.m)
+        self.c["sleeps"].value = 1
+        socket.send_fds(sock, [hello(RING)], [fd])
+        os.close(fd)
+        self.tail = 0
+
+    def sendall(self, data, bell=True):
+        view = memoryview(data)
+        deadline = _now() + (self.sock.gettimeout() or LIMIT_S)
+        while len(view):
+            room = RING - (self.tail - self.c["head"].value)
+            if room == 0:
+                # full: ask for the reader's bell and wait for it (or look
+                # again shortly: the flag and the look are not fenced here)
+                self.c["waits"].value = 1
+                assert _now() < deadline, "the ring's reader stopped reading"
+                self.sock.settimeout(0.005)
+                try:
+                    if self.sock.recv(64) == b"":
+                        raise BrokenPipeError("the ring's reader is gone")
+                except (socket.timeout, BlockingIOError):
+                    pass
+                finally:
+                    self.sock.settimeout(deadline - _now())
+                continue
+            self.c["waits"].value = 0
+            n = min(room, len(view))
+            at = self.tail % RING
+            first = min(n, RING - at)
+            self.m[_HDR + at:_HDR + at + first] = view[:first]
+            self.m[_HDR:_HDR + n - first] = view[first:n]
+            self.tail += n
+            self.c["tail"].value = self.tail
+            view = view[n:]
+            if bell:
+                try:
+                    self.sock.send(b"\1", socket.MSG_DONTWAIT)
+                except BlockingIOError:
+                    pass  # a bell is pending already
+
+    def recv(self, n):
+        return self.sock.recv(n)
+
+    def settimeout(self, t):
+        self.sock.settimeout(t)
+
+    def close(self):
+        self.sock.close()
+        self.c.clear()  # the views go before the mapping they hold
+        self.m.close()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+
+
+class _RingIn:
+    """The accepting end: what a native rank's hello brought, mapped. It
+    never clears ``reader_sleeps`` (set by the connector), so every publish
+    of the native writer rings the socket and ``Peer.pump`` finds it."""
+
+    def __init__(self, fd):
+        self.m = mmap.mmap(fd, _HDR + RING)
+        os.close(fd)
+        assert struct.unpack_from("<QI", self.m, 0) == (_MAGIC, RING)
+        self.c = _cursors(self.m)
+        self.head = 0
+
+    def take(self):
+        """(the bytes the ring holds, whether there were any)."""
+        tail = self.c["tail"].value
+        n = tail - self.head
+        assert 0 <= n <= RING
+        at = self.head % RING
+        first = min(n, RING - at)
+        data = self.m[_HDR + at:_HDR + at + first] + self.m[_HDR:_HDR + n - first]
+        self.head = tail
+        self.c["head"].value = self.head
+        return data, n > 0
 
 ADLB_SUCCESS = 1
 ADLB_PUT_REJECTED = -999999996
@@ -101,6 +273,7 @@ TA_PUT_RESP, TA_ABORT, AM_APP = 1020, 1046, 1047
 FA_INFO_NUM, TA_INFO_NUM_RESP = 1037, 1043
 SS_QMSTAT, SS_EXHAUST_CHK_1, SS_PLAN_MIGRATE = 1101, 1111, 1119
 SS_END_1, SS_END_2 = 1114, 1115
+SS_STATE, SS_HUNGRY, F_HUNGRY = 1117, 1124, 60
 F_PAYLOAD, F_WORK_TYPE, F_PRIO, F_TARGET_RANK, F_ANSWER_RANK = 1, 2, 3, 4, 5
 F_COMMON_LEN, F_COMMON_SERVER, F_COMMON_SEQNO, F_RC, F_HINT = 6, 7, 8, 9, 10
 F_REQ_TYPES, F_HANG, F_RQSEQNO, F_COUNT, F_NBYTES, F_CODE = 11, 12, 13, 17, 18, 20
@@ -182,13 +355,16 @@ class Peer:
         self.sel = selectors.DefaultSelector()
         self.sel.register(self.lsock, selectors.EVENT_READ, "tcp")
         self.usock = None
-        if family == "unix":
+        if family != "tcp":
             self.usock = socket.socket(socket.AF_UNIX)
             self.usock.bind(unix_name(self.port))
             self.usock.listen(64)
             self.sel.register(self.usock, selectors.EVENT_READ, "unix")
         self.accepted = collections.Counter()
         self.bufs = {}
+        self.hellos = {}  # Unix connections whose hello is still due
+        self.rings = {}  # connections whose frames come through a ring
+        self.paths = collections.Counter()  # frames by path: "ring" / "sock"
         self.frames = collections.deque()
         self.reading = True  # False: accept, but leave the bytes unread
 
@@ -199,23 +375,50 @@ class Peer:
                 c, _ = s.accept()
                 self.accepted[key.data] += 1
                 self.bufs[c] = bytearray()
+                if key.data == "unix":
+                    self.hellos[c] = b""
                 if self.reading:
                     self.sel.register(c, selectors.EVENT_READ)
                 continue
+            if s in self.hellos:  # a native rank's first bytes: its hello
+                got, fds, _flags, _addr = socket.recv_fds(
+                    s, 16 - len(self.hellos[s]), 1)
+                assert got, "a Unix connection ended before its hello"
+                self.hellos[s] += got
+                if fds:
+                    self.rings[s] = _RingIn(fds[0])
+                if len(self.hellos[s]) < 16:
+                    continue
+                h = self.hellos.pop(s)
+                assert h in (hello(0), hello(RING)), h
+                assert (h == hello(RING)) == (s in self.rings)
+                continue  # what follows it is another event
             data = s.recv(1 << 20)
-            if not data:
-                self.sel.unregister(s)
-                s.close()
-                del self.bufs[s]
-                continue
             buf = self.bufs[s]
-            buf += data
+            if s in self.rings:  # the socket's bytes are bells
+                taken, any_taken = self.rings[s].take()
+                buf += taken
+                if data and any_taken:
+                    # room was made: ring, whether the writer says it waits
+                    # or not (no fences here; a bell too many harms nobody)
+                    try:
+                        s.send(b"\1", socket.MSG_DONTWAIT)
+                    except OSError:
+                        pass
+            else:
+                buf += data
             while len(buf) >= 4:
                 (n,) = struct.unpack_from("<I", buf, 0)
                 if len(buf) < 4 + n:
                     break
                 self.frames.append(untlv(bytes(buf[4:4 + n])))
+                self.paths["ring" if s in self.rings else "sock"] += 1
                 del buf[:4 + n]
+            if not data:
+                self.sel.unregister(s)
+                s.close()
+                del self.bufs[s]
+                self.rings.pop(s, None)
 
     def resume_reading(self):
         self.reading = True
@@ -251,11 +454,16 @@ def _now():
 
 
 def _connect(port, family="tcp"):
-    """A raw connection to the rank that listens at ``port``."""
-    if family == "unix":
+    """A connection to the rank that listens at ``port``, as a rank of
+    ``family`` opens it: over Unix with the hello first, which under
+    ``ring`` brings a segment and under ``unix`` says there is none."""
+    if family != "tcp":
         s = socket.socket(socket.AF_UNIX)
         s.settimeout(LIMIT_S)
         s.connect(unix_name(port))
+        if family == "ring":
+            return RingConn(s)
+        s.sendall(hello(0))
         return s
     s = socket.create_connection(("127.0.0.1", port), timeout=LIMIT_S)
     s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
@@ -270,7 +478,7 @@ class Daemons:
     daemons alike."""
 
     def __init__(self, n_apps, nservers, daemon_ranks, cfg=None, types=(1, 2),
-                 family="unix", peer=None, own_host="127.0.0.1"):
+                 family="unix", peer=None, own_host="127.0.0.1", others=None):
         self.world = WorldSpec(nranks=n_apps + nservers, nservers=nservers,
                                types=tuple(types))
         cfg = cfg or Config(server_impl="native")
@@ -279,11 +487,14 @@ class Daemons:
                       for r in daemon_ranks}
         self.ports = {r: daemon_mod.read_hello(p, r)
                       for r, p in self.procs.items()}
+        # ``others``: ranks that are neither daemons nor the peer's, by port
+        # (the planner's pseudo-rank, nranks, is told to a daemon this way)
+        there = {**(others or {}), **self.ports}
         for me, p in self.procs.items():
             daemon_mod.send_addrs(p, {
                 r: (own_host if r == me else peer_host(family),
-                    self.ports.get(r, self.peer.port))
-                for r in range(self.world.nranks)})
+                    there.get(r, self.peer.port))
+                for r in sorted({*range(self.world.nranks), *there})})
 
     def finish(self):
         """End the world the way its ranks would and return each daemon's
@@ -346,7 +557,9 @@ def test_one_connection_is_handled_in_order_and_its_eof_comes_last(family):
         assert not [fr for fr in w.peer.frames if fr[0] == TA_PUT_RESP]
         assert w.procs[1].wait(LIMIT_S) == 2
         assert "ABORT -3" in w.procs[1].stdout.read()
-        assert set(w.peer.accepted) == {family}  # the answers' family too
+        assert set(w.peer.accepted) == {sock_of(family)}  # the answers' too
+        if family != "tcp":  # and their path
+            assert set(w.peer.paths) == {"ring" if family == "ring" else "sock"}
 
 
 GARBAGE = {
@@ -493,6 +706,51 @@ def test_periodic_keeps_its_deadlines_while_one_client_sends_without_pause(
         assert w.procs[2].poll() is None
 
 
+def test_a_snapshot_that_outlasts_its_interval_does_not_starve_the_reactor(
+        family):
+    """A planner-mode daemon with parked ranks somewhere (``SS_HUNGRY``)
+    sends the planner a snapshot every ``balancer_interval``, and a snapshot
+    walks and sorts its whole queue: with 400,000 units queued that takes
+    longer than the interval (twice, here). The next one is due an interval
+    after the last one is DONE, so the reactor serves in between: five
+    thousand more puts are answered within a snapshot or two. Counted from
+    the snapshot's start it was due again the moment it ended, the turn's
+    drain stopped at its first frame, and the daemon served one frame a
+    snapshot for as long as anyone was hungry: five thousand puts, five
+    thousand snapshots, minutes (what a flood that outran the planner's
+    first migrations did to a whole world)."""
+    n, more = 400_000, 5_000
+    cfg = Config(server_impl="native", balancer="tpu",
+                 exhaust_check_interval=60.0)
+    peer = Peer(family=family)  # rank 0 and the planner's pseudo-rank, 2
+    with Daemons(1, 1, [1], cfg=cfg, types=(1,), family=family, peer=peer,
+                 others={2: peer.port}) as w:
+        c = _connect(w.ports[1], family)
+        frame = put_frame(0, b"12345678")
+        acked = 0
+        for _ in range(0, n, 1000):
+            c.sendall(frame * 1000)
+            w.peer.pump(0)
+            acked += sum(fr[0] == TA_PUT_RESP for fr in w.peer.frames)
+            w.peer.frames.clear()
+        while acked < n:
+            w.peer.expect(TA_PUT_RESP)
+            acked += 1
+        planner = _connect(w.ports[1], family)
+        planner.sendall(tlv(SS_HUNGRY, 2, [(F_HUNGRY, 1)]))
+        w.peer.frames.clear()
+        for _ in range(3):  # the fast cadence is on: full snapshots come
+            assert len(w.peer.expect(SS_STATE)[2]) >= 3
+        w.peer.frames.clear()
+        c.sendall(b"".join(put_frame(0, b"12345678", put_id=i + 1)
+                           for i in range(more)))
+        ids = [w.peer.expect(TA_PUT_RESP)[2][F_PUT_ID] for _ in range(more)]
+        assert ids == list(range(1, more + 1))
+        snapshots = sum(fr[0] == SS_STATE for fr in w.peer.frames)
+        assert snapshots <= more // 10, snapshots
+        assert w.procs[1].poll() is None
+
+
 @contextlib.contextmanager
 def _held_to(cpu, others):
     """This process on ``cpu`` and each of ``others`` (pid: cpu) on its
@@ -635,8 +893,31 @@ print("READY", threads(), flush=True)
 for line in sys.stdin:
     cmd, *args = line.split()
     if cmd == "put":  # put <bytes>: one blocking ADLB_Put
-        buf = ctypes.create_string_buffer(b"p" * int(args[0]), int(args[0]))
-        print("PUT", lib.ADLB_Put(buf, int(args[0]), -1, -1, 1, 0), flush=True)
+        n = int(args[0])
+        buf = ctypes.create_string_buffer((bytes(range(251)) * (n // 251 + 1))[:n], n)
+        print("PUT", lib.ADLB_Put(buf, n, -1, -1, 1, 0), flush=True)
+    elif cmd == "puts":  # puts <count> <pause_us>: blocking puts of 64 bytes,
+        # a random pause of up to pause_us microseconds ahead of each
+        import random, time
+        buf = ctypes.create_string_buffer(b"q" * 64, 64)
+        ok = 0
+        for _ in range(int(args[0])):
+            until = time.perf_counter() + random.random() * int(args[1]) * 1e-6
+            while time.perf_counter() < until:
+                pass
+            ok += lib.ADLB_Put(buf, 64, -1, -1, 1, 0) == 1
+        print("PUTS", ok, flush=True)
+    elif cmd == "fetch":  # fetch <type> <bytes>: Reserve and Get_reserved
+        import zlib
+        req = (I * 2)(int(args[0]), -1)
+        wt, wp, wl, ar = I(), I(), I(), I()
+        handle = (I * 5)()
+        rc = lib.ADLB_Reserve(req, ctypes.byref(wt), ctypes.byref(wp), handle,
+                              ctypes.byref(wl), ctypes.byref(ar))
+        buf = ctypes.create_string_buffer(int(args[1]))
+        rc2 = lib.ADLB_Get_reserved(buf, handle)
+        print("FETCH", rc, rc2, wl.value, zlib.crc32(buf.raw[:wl.value]),
+              flush=True)
     elif cmd == "iput":  # iput <count>
         for i in range(int(args[0])):
             buf = ctypes.create_string_buffer(b"%08d" % i, 8)
@@ -758,9 +1039,9 @@ def test_app_messages_sent_before_app_recv_arrive_in_order(tmp_path, family):
         assert c.line() == ["FINALIZE", "1", "1"]
         c.peer.expect(FA_LOCAL_APP_DONE)
         # one connection accepted, one opened (to its home server)
-        assert c.conns() == {"conns_unix": 2 * (family == "unix"),
+        assert c.conns() == {"conns_unix": 2 * (family != "tcp"),
                              "conns_tcp": 2 * (family == "tcp")}
-        assert dict(c.peer.accepted) == {family: 1}
+        assert dict(c.peer.accepted) == {sock_of(family): 1}
 
 
 def test_two_thousand_iputs_settle_on_the_callers_own_thread(tmp_path, family):
@@ -959,7 +1240,8 @@ def test_a_connection_opened_while_the_client_awaits_an_answer_is_accepted(
         assert c.line() == ["PUT", "1"]
         c.tell("finalize")
         assert c.line() == ["FINALIZE", "1", "1"]
-        assert c.conns()["conns_" + family] == 2  # opened one, accepted one
+        # opened one, accepted one
+        assert c.conns()["conns_" + sock_of(family)] == 2
 
 
 def test_a_peer_that_sends_more_than_the_sockets_hold_into_a_wait_for_an_answer(
@@ -993,12 +1275,23 @@ def _cpu_s(pid):
     return (int(fields[11]) + int(fields[12])) / os.sysconf("SC_CLK_TCK")
 
 
-def test_an_idle_daemon_and_a_parked_client_use_no_processor_time(tmp_path):
+@pytest.fixture(params=["ring", "socket"])
+def build(request):
+    """Which native ranks a test of real ranks starts: the shipped build,
+    whose Unix connections carry rings, or the one without segments."""
+    if request.param == "socket":
+        request.getfixturevalue("noseg")
+    return request.param
+
+
+def test_an_idle_daemon_and_a_parked_client_use_no_processor_time(
+        tmp_path, build):
     """What fails if the polling phase ever loses its bound. A daemon that
     has served a put and a client parked at it in a ``Reserve`` nobody will
     answer (the exhaustion vote is a minute away) each use under 5% of a
     core over two seconds: the client polled for one budget and sleeps, the
-    daemon's turns end on their timeouts and poll nothing."""
+    daemon's turns end on their timeouts and poll nothing. With rings each
+    sleeps marked asleep, and looks at no ring meanwhile."""
     import types
 
     port = local_addr_map(1)[0][1]
@@ -1020,6 +1313,441 @@ def test_an_idle_daemon_and_a_parked_client_use_no_processor_time(tmp_path):
         assert w.procs[1].poll() is None and c.proc.poll() is None
 
 
+# ---- the ring ---------------------------------------------------------------
+
+_PUT = len(put_frame(0, b"", put_id=1))  # a put's frame with no payload
+
+# whole frames, length prefix and all; the first two by their payload
+SIZES = {"0B": _PUT, "64B": _PUT + 64, "ring-1": RING - 1, "ring": RING,
+         "8xring": 8 * RING}
+
+
+@pytest.mark.parametrize("size", list(SIZES))
+def test_frames_of_every_size_against_the_rings_arrive_whole_and_in_order(
+        size):
+    """The ring is a byte stream: a frame that fits to the byte, one that
+    leaves one byte free, and one of eight rings (which goes through in
+    installments, the reader ringing for each refill) arrive as they were
+    sent, between small frames that keep their places; so do a put of no
+    payload and one of 64 bytes. Three of the size, at three different
+    offsets of the ring."""
+    with Daemons(1, 1, [1], family="ring") as w:
+        c = _connect(w.ports[1], "ring")
+        frames, want_bytes = [], 0
+        for k in range(3):
+            small = put_frame(0, b"s%d" % k, put_id=2 * k + 1)
+            payload = bytes(i % 251 for i in range(SIZES[size] - _PUT))
+            big = put_frame(0, payload, put_id=2 * k + 2)
+            assert len(big) == SIZES[size]
+            frames += [small, big]
+            want_bytes += 2 + len(payload)
+        c.sendall(b"".join(frames))
+        acks = [w.peer.expect(TA_PUT_RESP)[2] for _ in frames]
+        assert [f[F_PUT_ID] for f in acks] == list(range(1, 7))
+        assert all(f[F_RC] == ADLB_SUCCESS for f in acks)
+        c.sendall(tlv(FA_INFO_NUM, 0, [(F_WORK_TYPE, 1)]))
+        f = w.peer.expect(TA_INFO_NUM_RESP)[2]
+        assert (f[F_COUNT], f[F_NBYTES]) == (6, want_bytes)
+        assert set(w.peer.paths) == {"ring"}  # the answers' path too
+        stats = w.finish()[1]
+        assert stats["frames_ring"] >= 7, stats
+
+
+def test_a_unit_of_eight_rings_crosses_both_rings_of_a_native_pair(tmp_path):
+    """Client and daemon, both native, both with the shipped build: a put
+    of eight rings' worth goes to the daemon in installments and comes back
+    in installments with the fetch, byte for byte; each end counts its
+    frames as the ring's."""
+    import types
+    import zlib
+
+    n = 8 * RING + 13
+    port = local_addr_map(1)[0][1]
+    stub = types.SimpleNamespace(port=port, close=lambda: None)
+    with Daemons(1, 1, [1], types=(1,), peer=stub) as w, \
+            Client(tmp_path, port=port, server_port=w.ports[1]) as c:
+        c.tell(f"put {n}")
+        assert c.line() == ["PUT", "1"]
+        c.tell(f"fetch 1 {n}")
+        want = zlib.crc32((bytes(range(251)) * (n // 251 + 1))[:n])
+        assert c.line() == ["FETCH", "1", "1", str(n), str(want)]
+        c.tell("finalize")
+        assert c.line() == ["FINALIZE", "1", "1"]
+        rings = c.counted("adlb:rings")
+        assert rings["frames_ring"] >= 3 and rings["frames_sock"] == 0, rings
+        stats, _abort, rc = daemon_mod.collect_stats(w.procs[1], LIMIT_S)
+        assert rc == 0
+        assert stats["frames_ring"] >= 4 and stats["frames_sock"] == 0, stats
+        # each side published the unit in eight installments at least
+        for end in (stats, rings):
+            assert end["bells_rung"] + end["bells_elided"] >= 8, end
+
+
+@pytest.mark.parametrize("family", ["unix", "ring"])
+def test_a_full_connection_toward_a_stopped_reader_stops_neither_reads_nor_periodic(
+        family, request):
+    """A daemon's send never blocks, ring or socket. One app rank does not
+    read, so the answers to the 40,000 puts sent in its name fill the
+    daemon's connection toward it (a ring holds some 1,500 of them) and
+    queue behind it. The daemon still reads every put, answers another
+    rank's query with all of them counted, and sends the qmstat broadcast
+    due every interval; when the stopped rank reads again it gets its
+    answers, every one, in order."""
+    if family == "unix":
+        request.getfixturevalue("noseg")
+    n = 40_000
+    cfg = Config(server_impl="native", qmstat_interval=0.02,
+                 exhaust_check_interval=60.0)
+    stopped = Peer(family=family)
+    stopped.reading = False
+    try:
+        with Daemons(2, 2, [2], cfg=cfg, family=family,
+                     others={1: stopped.port}) as w:
+            flood = _connect(w.ports[2], family)
+            for at in range(0, n, 500):
+                flood.sendall(b"".join(
+                    put_frame(1, b"12345678", put_id=i + 1)
+                    for i in range(at, at + 500)))
+                stopped.pump(0)  # accepts; reads nothing
+            ask = _connect(w.ports[2], family)
+            deadline = _now() + LIMIT_S
+            while True:
+                ask.sendall(tlv(FA_INFO_NUM, 0, [(F_WORK_TYPE, 1)]))
+                if w.peer.expect(TA_INFO_NUM_RESP)[2][F_COUNT] == n:
+                    break
+                assert _now() < deadline, "the daemon stopped reading"
+            w.peer.frames.clear()
+            for _ in range(5):  # and periodic() still keeps its deadlines
+                w.peer.expect(SS_QMSTAT)
+            assert not stopped.frames
+            stopped.resume_reading()
+            ids = [stopped.expect(TA_PUT_RESP)[2][F_PUT_ID] for _ in range(n)]
+            assert ids == list(range(1, n + 1))
+            assert w.procs[2].poll() is None
+    finally:
+        stopped.close()
+
+
+WAKEUP_CPP = r"""
+// Two processes, two rings (frames one way, acknowledgements the other), both
+// ends under the discipline of hostsock.hpp: spin a random while, then mark
+// asleep, fence, look once more, poll() WITHOUT a timeout; the writer
+// publishes at a random offset after the acknowledgement that sends the
+// reader toward its sleep. A lost wake-up is a hang.
+#include <poll.h>
+#include <sys/wait.h>
+#include <cstdio>
+#include <cstdlib>
+#include "hostsock.hpp"
+using namespace hostsock;
+static long slept = 0;
+static void await(RingRx& rx, int fd, std::string& buf, unsigned spins) {
+  bool bell;
+  for (;;) {
+    if (rx.ready()) { rx.take(buf, &bell); if (buf.size() >= 8) break; }
+    if (spins > 0) { --spins; continue; }
+    rx.sleeps(true);
+    sleep_fence();
+    if (!rx.ready()) {
+      pollfd p{fd, POLLIN, 0};
+      if (poll(&p, 1, -1) < 0) _exit(5);
+      ++slept;
+      char b[64];
+      if (recv(fd, b, sizeof b, MSG_DONTWAIT) == 0) _exit(6);
+    }
+    rx.sleeps(false);
+  }
+  buf.erase(0, 8);
+}
+static void end_of(int lport, int cport, bool writer, int rounds) {
+  int ls = listen_unix(lport, 4);
+  if (ls < 0) _exit(2);
+  int out;
+  while ((out = connect_unix(cport)) < 0) usleep(1000);
+  RingTx tx;
+  if (!tx.open(out) || !tx.on()) _exit(3);
+  int in;
+  while ((in = accept(ls, nullptr, nullptr)) < 0) usleep(100);
+  HelloRx h; RingRx rx; Hello hr;
+  while ((hr = recv_hello(in, h, &rx)) == Hello::kMore) {}
+  if (hr != Hello::kRing) _exit(4);
+  rx.sleeps(false);
+  std::string buf;
+  unsigned seed = writer ? 12345u : 54321u;
+  char frame[8] = {0};
+  for (int i = 0; i < rounds; ++i) {
+    if (writer) {
+      for (volatile unsigned k = rand_r(&seed) % 3000; k > 0; --k) {}
+      tx.write(frame, 8);
+      if (!tx.kick(out)) _exit(7);
+    }
+    await(rx, in, buf, rand_r(&seed) % 600);
+    if (!writer) {
+      tx.write(frame, 8);
+      if (!tx.kick(out)) _exit(7);
+    }
+  }
+  std::printf("%s rounds=%d slept=%ld rung=%lld elided=%lld\n",
+              writer ? "WRITER" : "READER", rounds, slept,
+              (long long)ring_stats().bells_rung,
+              (long long)ring_stats().bells_elided);
+  std::fflush(stdout);
+}
+int main(int argc, char** argv) {
+  int port = std::atoi(argv[1]), rounds = std::atoi(argv[2]);
+  pid_t pid = fork();
+  if (pid == 0) { end_of(port, port + 1, false, rounds); _exit(0); }
+  end_of(port + 1, port, true, rounds);
+  int st = 0;
+  waitpid(pid, &st, 0);
+  (void)argc;
+  return WIFEXITED(st) ? WEXITSTATUS(st) : 9;
+}
+"""
+
+
+def test_no_wake_up_is_lost_in_a_hundred_thousand_entries_into_sleep(tmp_path):
+    """The handshake, with no timer behind it. A writer publishes at random
+    offsets around its reader's entry into sleep, 10**5 times, each end
+    sleeping in ``poll`` with no timeout: store, fence, load on both sides
+    means one of the two always sees the other, so the run ends. Both
+    branches are taken many times (bells rung for a sleeper, bells elided
+    for a reader that was awake), or the offsets missed the window."""
+    from adlb_tpu.native import build
+
+    src = tmp_path / "wakeup.cpp"
+    src.write_text(WAKEUP_CPP)
+    exe = build.build_artifact(
+        "ring_wakeup",
+        ["g++", "-O2", "-std=c++17", f"-I{os.path.dirname(build.HOSTSOCK_HDR)}",
+         "-o", "{out}", str(src)],
+        [str(src), build.HOSTSOCK_HDR])
+    port = local_addr_map(2)[0][1]
+    out = subprocess.run([exe, str(port), "100000"], capture_output=True,
+                         text=True, timeout=4 * LIMIT_S)
+    assert out.returncode == 0, (out.returncode, out.stdout, out.stderr)
+    seen = {ln.split()[0]: dict(kv.split("=") for kv in ln.split()[1:])
+            for ln in out.stdout.splitlines()}
+    assert set(seen) == {"WRITER", "READER"}, out.stdout
+    for end in seen.values():
+        assert int(end["rounds"]) == 100_000
+    if len(os.sched_getaffinity(0)) > 1:
+        assert all(int(e["slept"]) > 100 and int(e["elided"]) > 100
+                   for e in seen.values()), seen
+
+
+def test_puts_at_random_offsets_around_the_daemons_entry_into_sleep(tmp_path):
+    """The same through both native files: 20,000 blocking puts, each up to
+    a hundred microseconds after the last one's answer, so around the end
+    of the daemon's polling budget. The daemon's own deadlines are a minute
+    away and the client's sleep has none, so a wake-up lost on either side
+    is a hang. Both ends saw both cases."""
+    import types
+
+    port = local_addr_map(1)[0][1]
+    cfg = Config(server_impl="native", qmstat_interval=60.0,
+                 exhaust_check_interval=60.0)
+    stub = types.SimpleNamespace(port=port, close=lambda: None)
+    with Daemons(1, 1, [1], cfg=cfg, types=(1,), peer=stub) as w, \
+            Client(tmp_path, port=port, server_port=w.ports[1]) as c:
+        c.tell("puts 20000 100")
+        assert c.line() == ["PUTS", "20000"]
+        c.tell("finalize")
+        assert c.line() == ["FINALIZE", "1", "1"]
+        rings = c.counted("adlb:rings")
+        stats, _abort, rc = daemon_mod.collect_stats(w.procs[1], LIMIT_S)
+        assert rc == 0
+        assert rings["frames_ring"] >= 20_000 and rings["frames_sock"] == 0
+        assert stats["frames_ring"] >= 20_000 and stats["frames_sock"] == 0
+        if len(os.sched_getaffinity(0)) > 1:
+            assert rings["bells_rung"] > 0 and rings["bells_elided"] > 0, rings
+            assert stats["waits_slept"] > 0 and stats["waits_polled"] > 0, stats
+
+
+def test_a_peer_killed_with_frames_in_its_ring_is_read_out_before_its_death():
+    """500 puts lie in the ring, unannounced (no bell, the daemon asleep a
+    minute from its next deadline), when their writer is ended by SIGKILL.
+    What tells the daemon is the socket's EOF; it takes what the ring still
+    holds first, answers all 500 in order, and only then reads the lost
+    connection as rank death."""
+    cfg = Config(server_impl="native", qmstat_interval=60.0,
+                 exhaust_check_interval=60.0)
+    with Daemons(1, 1, [1], cfg=cfg, family="ring") as w:
+        first = _connect(w.ports[1], "ring")
+        first.sendall(put_frame(0, b"first", put_id=1000))
+        assert w.peer.expect(TA_PUT_RESP)[2][F_PUT_ID] == 1000
+        time.sleep(ASLEEP_S)  # the daemon sleeps, a minute from a deadline
+        r, wr = os.pipe()
+        pid = os.fork()
+        if pid == 0:  # the rank that dies
+            try:
+                c = _connect(w.ports[1], "ring")
+                c.sendall(b"".join(put_frame(0, struct.pack("<q", i),
+                                             put_id=i + 1)
+                                   for i in range(500)), bell=False)
+                os.write(wr, b"k")
+                signal.pause()
+            finally:
+                os._exit(1)
+        assert os.read(r, 1) == b"k"
+        os.kill(pid, signal.SIGKILL)
+        os.waitpid(pid, 0)
+        os.close(r)
+        os.close(wr)
+        ids = [w.peer.expect(TA_PUT_RESP)[2] for _ in range(500)]
+        assert [f[F_PUT_ID] for f in ids] == list(range(1, 501))
+        _tag, _src, f = w.peer.expect(TA_ABORT)
+        assert f[F_CODE] == -3
+        assert not [fr for fr in w.peer.frames if fr[0] == TA_PUT_RESP]
+        assert w.procs[1].wait(LIMIT_S) == 2
+        first.close()
+
+
+def _was_closed(sock):
+    """Did the other end close ``sock``? (With bytes of ours unread, a Unix
+    socket says so by a reset.)"""
+    try:
+        return sock.recv(16) == b""
+    except ConnectionResetError:
+        return True
+
+
+NO_HELLO = {
+    "a-frame-first": put_frame(0, b"a perfectly good frame, and no hello"),
+    "wrong-magic": hello(0, magic=b"ADLBrinG"),
+    "wrong-version": hello(0, version=2),
+    "a-size-and-no-segment": hello(RING),
+    "another-size": hello(RING // 2),
+    "two-bytes": b"\x99\x99",
+}
+
+
+@pytest.mark.parametrize("kind", sorted(NO_HELLO))
+def test_a_unix_connection_that_does_not_begin_with_the_hello_is_closed(kind):
+    """An untrusted stray, as garbage before a first frame is: the daemon
+    closes that connection (at the first bytes that cannot be a hello's) and
+    goes on serving the others, ring and socket."""
+    with Daemons(1, 1, [1], family="ring") as w:
+        served = [_connect(w.ports[1], "ring"), _connect(w.ports[1], "unix")]
+        for c in served:
+            c.sendall(put_frame(0, b"a"))
+            assert w.peer.expect(TA_PUT_RESP)[2][F_RC] == ADLB_SUCCESS
+        stray = socket.socket(socket.AF_UNIX)
+        stray.settimeout(LIMIT_S)
+        stray.connect(unix_name(w.ports[1]))
+        stray.sendall(NO_HELLO[kind])
+        assert _was_closed(stray)
+        stray.close()
+        for c in served:
+            c.sendall(put_frame(0, b"b"))
+            assert w.peer.expect(TA_PUT_RESP)[2][F_RC] == ADLB_SUCCESS
+        assert w.procs[1].poll() is None
+
+
+def test_an_abort_without_a_hello_does_not_end_the_client(tmp_path):
+    """The client's side of the same policy: the frame that would end the
+    rank had it come from a rank (an abort) arrives on a Unix connection
+    with no hello before it; the connection is closed, the rank lives and
+    its next put is answered."""
+    with Client(tmp_path, family="ring") as c:
+        stray = socket.socket(socket.AF_UNIX)
+        stray.settimeout(LIMIT_S)
+        stray.connect(unix_name(c.port))
+        stray.sendall(tlv(TA_ABORT, 1, [(F_CODE, 7)]))
+        back = _back_connection(c, "ring")  # a wait that reads the stray too
+        assert _was_closed(stray)
+        c.tell("put 8")
+        c.peer.expect(FA_PUT)
+        back.sendall(tlv(TA_PUT_RESP, 1, [(F_RC, ADLB_SUCCESS)]))
+        assert c.line() == ["PUT", "1"]
+        c.tell("finalize")
+        assert c.line() == ["FINALIZE", "1", "1"]
+
+
+def test_a_python_tcp_peer_is_served_while_a_ring_peer_floods():
+    """While its frames come through rings the daemon's polling phase reads
+    memory, and asks its descriptors once a budget: so a ``TcpEndpoint``
+    peer (the sidecar, a Python rank of a mixed world) is not starved by a
+    native peer that never lets the daemon sleep. One rank keeps 256
+    queries in flight through a ring without a gap; a Python endpoint makes
+    twenty blocking puts over TCP meanwhile, and each is answered while the
+    flood lasts."""
+    from adlb_tpu.runtime.messages import Tag, msg
+    from adlb_tpu.runtime.transport_tcp import TcpEndpoint
+
+    tcp_port = local_addr_map(1)[0][1]
+    with Daemons(2, 1, [2], family="ring", others={1: tcp_port}) as w:
+        addr = {1: ("127.0.0.1", tcp_port), 2: ("127.0.0.1", w.ports[2])}
+        ep = TcpEndpoint(1, addr, binary_peers={2})
+        done = []
+
+        def tcp_puts():
+            for _ in range(20):
+                ep.send(2, msg(Tag.FA_PUT, 1, payload=b"abc", work_type=1,
+                               prio=0, target_rank=-1, answer_rank=-1,
+                               common_len=0, common_server=-1,
+                               common_seqno=-1))
+                resp = ep.recv(LIMIT_S)
+                assert resp is not None and resp.tag is Tag.TA_PUT_RESP
+                done.append(resp.rc)
+
+        try:
+            flood = _connect(w.ports[2], "ring")
+            query = tlv(FA_INFO_NUM, 0, [(F_WORK_TYPE, 2)])
+            sent = acked = 0
+            putter = threading.Thread(target=tcp_puts, daemon=True)
+            putter.start()
+            while putter.is_alive() and sent < 2_000_000:
+                if sent - acked < 256:
+                    flood.sendall(query * 128)
+                    sent += 128
+                w.peer.pump(0 if sent - acked < 256 else 1.0)
+                acked += len(w.peer.frames)
+                w.peer.frames.clear()
+            assert done == [ADLB_SUCCESS] * 20, (done, sent, acked)
+            assert sent - acked <= 256 + 128  # it was a flood to the end
+            while acked < sent:
+                w.peer.expect(TA_INFO_NUM_RESP)
+                acked += 1
+            stats = w.finish()[2]
+            assert stats["frames_ring"] >= sent, stats
+            assert stats["frames_sock"] >= 21, stats
+            assert stats["conns_tcp"] >= 2, stats
+        finally:
+            ep.close()
+
+
+def test_the_counters_of_an_idle_world_say_the_ring_engaged(tmp_path):
+    """Three thousand blocking puts between a client and a daemon that have
+    a processor each: every frame either way came through a ring
+    (``frames_ring`` is the puts and a few more, ``frames_sock`` nothing),
+    and nine publishes in ten found their reader awake and rang no bell
+    (``bells_elided`` against ``bells_rung``), on both ends."""
+    import types
+
+    cpus = sorted(os.sched_getaffinity(0))
+    if len(cpus) < 3:
+        pytest.skip("needs a processor each for client, daemon and test")
+    port = local_addr_map(1)[0][1]
+    cfg = Config(server_impl="native", exhaust_check_interval=60.0)
+    stub = types.SimpleNamespace(port=port, close=lambda: None)
+    with Daemons(1, 1, [1], cfg=cfg, types=(1,), peer=stub) as w, \
+            Client(tmp_path, port=port, server_port=w.ports[1]) as c, \
+            _held_to(cpus[0], {w.procs[1].pid: cpus[1], c.proc.pid: cpus[2]}):
+        c.tell("puts 3000 0")
+        assert c.line() == ["PUTS", "3000"]
+        c.tell("finalize")
+        assert c.line() == ["FINALIZE", "1", "1"]
+        rings = c.counted("adlb:rings")
+        stats, _abort, rc = daemon_mod.collect_stats(w.procs[1], LIMIT_S)
+        assert rc == 0
+        for end in (rings, stats):
+            assert 3000 <= end["frames_ring"] <= 3010, end
+            assert end["frames_sock"] == 0, end
+            assert end["bells_elided"] > 9 * end["bells_rung"], end
+            assert end["bells_elided"] + end["bells_rung"] >= 3000, end
+
+
 # ---- which family, and whose name -----------------------------------------
 
 def _examples():
@@ -1030,12 +1758,15 @@ def _examples():
 
 @pytest.mark.parametrize("balancer", ["steal", "tpu"])
 def test_a_one_host_native_world_is_unix_between_its_native_ranks(
-        tmp_path, balancer):
+        tmp_path, balancer, build):
     """C clients and C++ daemons of one host: every connection between two
     of them is a Unix-domain one, on both ends' counters. TCP appears only
     toward a Python peer, and the one such peer a native world can have is
     the planner's sidecar (``balancer="tpu"``): each daemon opens one
-    connection to it and accepts at most one from it."""
+    connection to it and accepts at most one from it. With the shipped
+    build every frame between two native ranks came through a ring, and
+    what came over a socket is the sidecar's; with the build whose segment
+    constructor fails the same world runs, every frame over a socket."""
     if shutil.which("gcc") is None:
         pytest.skip("no C toolchain")
     from adlb_tpu.native.capi import build_example, run_native_world
@@ -1054,6 +1785,14 @@ def test_a_one_host_native_world_is_unix_between_its_native_ranks(
             (0,) if balancer == "steal" else (1, 2)), stats[rank]
         # every wait of the reactor ended one way or the other
         assert stats[rank]["waits_polled"] + stats[rank]["waits_slept"] > 0
+        if build == "socket":
+            assert stats[rank]["frames_ring"] == 0, stats[rank]
+            assert stats[rank]["bells_rung"] == 0, stats[rank]
+            assert stats[rank]["frames_sock"] > 0, stats[rank]
+        else:
+            assert stats[rank]["frames_ring"] > 0, stats[rank]
+            if balancer == "steal":  # no Python peer: nothing over a socket
+                assert stats[rank]["frames_sock"] == 0, stats[rank]
     for rank in range(3):
         events = json.loads((tmp_path / f"t.{rank}.trace.json").read_text())
         (ev,) = [e for e in events if e["name"] == "adlb:conns"]
@@ -1061,6 +1800,10 @@ def test_a_one_host_native_world_is_unix_between_its_native_ranks(
         assert ev["args"]["conns_unix"] >= 2  # opened one, accepted one
         (ev,) = [e for e in events if e["name"] == "adlb:waits"]
         assert ev["args"]["waits_polled"] + ev["args"]["waits_slept"] > 0
+        (ev,) = [e for e in events if e["name"] == "adlb:rings"]
+        path, other = (("frames_sock", "frames_ring") if build == "socket"
+                       else ("frames_ring", "frames_sock"))
+        assert ev["args"][path] > 0 and ev["args"][other] == 0, ev["args"]
 
 
 def test_a_python_peer_reaches_a_daemon_and_is_reached_by_it_over_tcp():
