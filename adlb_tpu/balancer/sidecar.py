@@ -152,7 +152,7 @@ def run_sidecar(world, cfg, ep, abort_event=None) -> dict:
     executed (the flight artifact carries both, error or not)."""
     from adlb_tpu.balancer.engine import PlanEngine, round_gap
     from adlb_tpu.obs.metrics import Registry, attach
-    from adlb_tpu.runtime.trace import span
+    from adlb_tpu.runtime.trace import clock_mark, span
 
     # the sidecar is its own process/thread: it owns its registry (round
     # duration, plan ages, pairs) and instruments its endpoint's per-tag
@@ -291,6 +291,7 @@ def run_sidecar(world, cfg, ep, abort_event=None) -> dict:
         while ended < servers:
             if abort_event is not None and abort_event.is_set():
                 break
+            clock_mark()
             with span("adlb.sidecar.wait", metrics):
                 m = ep.recv(timeout=0.25)
             with span("adlb.sidecar.ingest", metrics):

@@ -13,6 +13,7 @@ One place for the stdin/stdout handshake both launchers speak
 from __future__ import annotations
 
 import json
+import os
 import subprocess
 from typing import Optional
 
@@ -20,6 +21,7 @@ from typing import Optional
 def spawn_daemon(world, cfg, rank: int) -> subprocess.Popen:
     """Start adlb_serverd for one server rank and ship its config."""
     from adlb_tpu.native.build import ensure_serverd
+    from adlb_tpu.obs.flight import resolve_flight_dir
 
     proc = subprocess.Popen(
         [ensure_serverd()],
@@ -42,6 +44,15 @@ def spawn_daemon(world, cfg, rank: int) -> subprocess.Popen:
     ]
     if cfg.restore_path:
         lines.append(f"restore_path {cfg.restore_path}")
+    # where the daemon leaves flight-serverd-r<rank>-p<pid>.json at its end
+    # (docs/USERGUIDE.md §5), resolved as the Python ranks resolve theirs
+    flight_dir = resolve_flight_dir(cfg.flight_dir)
+    if flight_dir:
+        try:
+            os.makedirs(flight_dir, exist_ok=True)
+        except OSError:
+            pass  # costs the artefact, not the world (obs/flight.py)
+        lines.append(f"flight_dir {os.path.abspath(flight_dir)}")
     if cfg.balancer == "tpu":
         # the JAX balancer sidecar listens at pseudo-rank world.nranks
         lines += [
@@ -91,7 +102,12 @@ def _parse_trailer(lines):
     phase, and those that went on to sleep; ``frames_ring``,
     ``frames_sock``: frames it received, by path; ``bells_rung``,
     ``bells_elided``: wake-up bytes it sent, and publishes that found their
-    reader awake) keeps its name."""
+    reader awake) keeps its name. They end in ``WorldResult.server_stats``
+    and ``run_native_world``'s stats; a daemon with a flight directory
+    writes the same eight into its ``flight-serverd-r<rank>-p<pid>.json``,
+    where ``benchmarks/reduce/daemons.py`` (a traced run's earlier lines,
+    the hot daemon beside the others) and ``scripts/obs_report.py`` read
+    them."""
     import sys
 
     stats: Optional[dict] = None

@@ -511,6 +511,356 @@ NMsg decode(std::string_view body) {
   return m;
 }
 
+// ---- where the reactor's time goes ------------------------------------------
+// Every instant of the reactor thread is under one phase, named as the
+// Python reactor names its own (obs/profile.py's phase markers, trace.py's
+// srv:<TAG> spans): `asleep` (in epoll with a timeout, marked asleep in the
+// rings), `poll` (a look that brought no frame), `decode` (a look that read
+// and decoded frames into the inbox), `handler:<TAG>` (one dispatch),
+// `flush` (the answers of a turn that got a frame leaving), and
+// `periodic:snapshot` (send_snapshot and flush_event_deltas, wherever they
+// are called from) and `periodic:other` (the rest of periodic(), and what it
+// queued leaving). The stamps are the clock reads run() and recv made
+// already: a stretch is labelled (enter) some time before the read that ends
+// it (stamp), and the next begins where it ended. Kept always, so it is two
+// adds a stretch: seconds and count a phase, for the whole world; once a
+// second (roll) the totals are cut into groups for that CLOCK_MONOTONIC
+// second, a stretch split where it straddles one, so the groups of a whole
+// second sum to it. Under ADLB_TRACE the handlers, decode, flush and
+// snapshot stretches are also kept as events. The flight artefact
+// (Server::write_flight) and the trace file (write_trace) carry it out;
+// benchmarks/reduce/daemons.py and scripts/obs_report.py read the artefact.
+
+enum Phase : uint8_t {
+  P_ASLEEP, P_POLL, P_DECODE, P_FLUSH, P_SNAPSHOT, P_PERIODIC,
+  P_HANDLER,  // the first of the handlers, in kHandlers' order
+};
+enum Group : uint8_t {
+  G_ASLEEP, G_POLL, G_DECODE, G_FLUSH, G_PUT, G_FETCH, G_ENACT, G_SNAPSHOT,
+  G_OTHER, G_COUNT,
+};
+constexpr const char* kGroupNames[G_COUNT] = {
+    "asleep", "poll", "decode", "flush", "put", "fetch", "enact", "snapshot",
+    "other"};
+
+struct HandlerName {
+  uint16_t tag;
+  const char* name;
+  Group group;
+};
+// every tag dispatch() has an arm for; a tag that has none dies there
+constexpr HandlerName kHandlers[] = {
+    {T_FA_PUT, "FA_PUT", G_PUT},
+    {T_FA_PUT_COMMON, "FA_PUT_COMMON", G_PUT},
+    {T_FA_BATCH_DONE, "FA_BATCH_DONE", G_PUT},
+    {T_FA_DID_PUT_AT_REMOTE, "FA_DID_PUT_AT_REMOTE", G_PUT},
+    {T_FA_RESERVE, "FA_RESERVE", G_FETCH},
+    {T_FA_GET_RESERVED, "FA_GET_RESERVED", G_FETCH},
+    {T_FA_GET_COMMON, "FA_GET_COMMON", G_FETCH},
+    {T_SS_PLAN_MATCH, "SS_PLAN_MATCH", G_ENACT},
+    {T_SS_PLAN_MIGRATE, "SS_PLAN_MIGRATE", G_ENACT},
+    {T_SS_MIGRATE_WORK, "SS_MIGRATE_WORK", G_ENACT},
+    {T_SS_MIGRATE_ACK, "SS_MIGRATE_ACK", G_ENACT},
+    {T_SS_RFR, "SS_RFR", G_ENACT},
+    {T_SS_RFR_RESP, "SS_RFR_RESP", G_ENACT},
+    {T_FA_NO_MORE_WORK, "FA_NO_MORE_WORK", G_OTHER},
+    {T_FA_LOCAL_APP_DONE, "FA_LOCAL_APP_DONE", G_OTHER},
+    {T_FA_ABORT, "FA_ABORT", G_OTHER},
+    {T_FA_INFO_NUM_WORK_UNITS, "FA_INFO_NUM_WORK_UNITS", G_OTHER},
+    {T_FA_INFO_GET, "FA_INFO_GET", G_OTHER},
+    {T_FA_CHECKPOINT, "FA_CHECKPOINT", G_OTHER},
+    {T_FA_HEARTBEAT, "FA_HEARTBEAT", G_OTHER},
+    {T_TA_ABORT, "TA_ABORT", G_OTHER},
+    {T_SS_QMSTAT, "SS_QMSTAT", G_OTHER},
+    {T_SS_UNRESERVE, "SS_UNRESERVE", G_OTHER},
+    {T_SS_PUSH_QUERY, "SS_PUSH_QUERY", G_OTHER},
+    {T_SS_PUSH_QUERY_RESP, "SS_PUSH_QUERY_RESP", G_OTHER},
+    {T_SS_PUSH_WORK, "SS_PUSH_WORK", G_OTHER},
+    {T_SS_PUSH_DEL, "SS_PUSH_DEL", G_OTHER},
+    {T_SS_MOVING_TARGETED_WORK, "SS_MOVING_TARGETED_WORK", G_OTHER},
+    {T_SS_NO_MORE_WORK, "SS_NO_MORE_WORK", G_OTHER},
+    {T_SS_EXHAUST_CHK_1, "SS_EXHAUST_CHK_1", G_OTHER},
+    {T_SS_EXHAUST_CHK_2, "SS_EXHAUST_CHK_2", G_OTHER},
+    {T_SS_DONE_BY_EXHAUSTION, "SS_DONE_BY_EXHAUSTION", G_OTHER},
+    {T_SS_END_1, "SS_END_1", G_OTHER},
+    {T_SS_END_2, "SS_END_2", G_OTHER},
+    {T_SS_ABORT, "SS_ABORT", G_OTHER},
+    {T_SS_PERIODIC_STATS, "SS_PERIODIC_STATS", G_OTHER},
+    {T_SS_HUNGRY, "SS_HUNGRY", G_OTHER},
+    {T_SS_CHECKPOINT, "SS_CHECKPOINT", G_OTHER},
+    {T_PEER_EOF, "PEER_EOF", G_OTHER},
+};
+constexpr int kNumHandlers = int(sizeof kHandlers / sizeof kHandlers[0]);
+constexpr int kNumPhases = P_HANDLER + kNumHandlers + 1;  // + handler:other
+
+class Phases {
+ public:
+  Phases() {
+    std::memset(by_tag_, P_HANDLER + kNumHandlers, sizeof by_tag_);
+    for (int i = 0; i < kNumHandlers; ++i)
+      by_tag_[kHandlers[i].tag] = uint8_t(P_HANDLER + i);
+    seconds_.reserve(4096);  // rarely more in a run: no growth while it serves
+  }
+
+  // the thread's time counts from here; with a prefix the stretches of the
+  // traced phases are kept as events too
+  void start(double now, const char* trace_prefix) {
+    t_start_ = since_ = now;
+    sec_end_ = std::floor(now) + 1.0;
+    if (trace_prefix != nullptr && trace_prefix[0] != '\0') {
+      trace_prefix_ = trace_prefix;
+      tracing_ = true;
+      events_.reserve(kEventsAtStart);
+    }
+  }
+
+  uint8_t handler(uint16_t tag) const {
+    return by_tag_[tag < kTagSpace ? tag : 0];
+  }
+  // label the stretch that is open, and count it
+  void enter(uint8_t phase) {
+    cur_ = phase;
+    n_[phase] += 1;
+  }
+  // label it without counting: a phase going on after one nested in it
+  void resume(uint8_t phase) { cur_ = phase; }
+  uint8_t current() const { return cur_; }
+  // the open stretch ends at `now` (a clock read the caller made anyway),
+  // the next begins there under the same label until someone gives another
+  void stamp(double now) {
+    if (tracing_) keep_event(now);
+    if (now >= sec_end_) {
+      roll(now);
+      return;
+    }
+    s_[cur_] += now - since_;
+    since_ = now;
+  }
+
+  static std::string name(int phase) {
+    static const char* fixed[P_HANDLER] = {
+        "asleep", "poll", "decode", "flush", "periodic:snapshot",
+        "periodic:other"};
+    if (phase < P_HANDLER) return fixed[phase];
+    if (phase < P_HANDLER + kNumHandlers)
+      return std::string("handler:") + kHandlers[phase - P_HANDLER].name;
+    return "handler:other";
+  }
+  static Group group(int phase) {
+    static const Group fixed[P_HANDLER] = {
+        G_ASLEEP, G_POLL, G_DECODE, G_FLUSH, G_SNAPSHOT, G_OTHER};
+    if (phase < P_HANDLER) return fixed[phase];
+    if (phase < P_HANDLER + kNumHandlers)
+      return kHandlers[phase - P_HANDLER].group;
+    return G_OTHER;
+  }
+
+  // "phase_s": {...}, "phase_n": {...}, "groups": [...], "by_second": {...}
+  // of the flight artefact; the second that is open goes in as it stands
+  void write_json(std::ostream& os) {
+    cut_second();
+    char num[64];
+    for (int pass = 0; pass < 2; ++pass) {
+      os << (pass == 0 ? "\"phase_s\": {" : ", \"phase_n\": {");
+      bool first = true;
+      for (int p = 0; p < kNumPhases; ++p) {
+        if (n_[p] == 0 && s_[p] == 0.0) continue;
+        if (pass == 0) std::snprintf(num, sizeof num, "%.9f", s_[p]);
+        else std::snprintf(num, sizeof num, "%lld", (long long)n_[p]);
+        os << (first ? "" : ", ") << "\"" << name(p) << "\": " << num;
+        first = false;
+      }
+      os << "}";
+    }
+    os << ", \"groups\": [";
+    for (int g = 0; g < G_COUNT; ++g)
+      os << (g ? ", " : "") << "\"" << kGroupNames[g] << "\"";
+    os << "], \"by_second\": {";
+    for (size_t i = 0; i < seconds_.size(); ++i) {
+      const Second& sec = seconds_[i];
+      os << (i ? ", " : "") << "\"" << sec.sec << "\": {\"s\": [";
+      for (int g = 0; g < G_COUNT; ++g) {
+        std::snprintf(num, sizeof num, "%.9f", sec.s[g]);
+        os << (g ? ", " : "") << num;
+      }
+      os << "], \"n\": [";
+      for (int g = 0; g < G_COUNT; ++g) os << (g ? ", " : "") << sec.n[g];
+      os << "]}";
+    }
+    os << "}";
+  }
+
+  double t_start() const { return t_start_; }
+  double t_end() const { return since_; }
+  bool tracing() const { return tracing_; }
+
+  // <prefix>.<rank>.trace.json, the Chrome format of the C client's file
+  // (libadlb.cpp trace_flush) with the Python servers' pid (trace.py
+  // PID_SERVER): one array, to be concatenated with the clients'
+  void write_trace(int rank) const {
+    std::string path =
+        trace_prefix_ + "." + std::to_string(rank) + ".trace.json";
+    FILE* f = std::fopen(path.c_str(), "w");
+    if (f == nullptr) return;
+    std::fprintf(f,
+                 "[{\"name\":\"process_name\",\"ph\":\"M\",\"ts\":0,"
+                 "\"pid\":1,\"tid\":0,\"args\":{\"name\":\"servers\"}},"
+                 "{\"name\":\"adlb:clock\",\"ph\":\"M\",\"ts\":0,\"pid\":1,"
+                 "\"tid\":%d,\"args\":{\"clock\":\"CLOCK_MONOTONIC\","
+                 "\"unit\":\"us\",\"events\":%zu,\"dropped\":%lld}}",
+                 rank, events_.size(), (long long)dropped_);
+    for (const Event& e : events_) {
+      // srv:<TAG> as the Python reactor's spans; srv:decode, srv:flush,
+      // srv:snapshot
+      std::string nm = name(e.phase);
+      nm = "srv:" + nm.substr(nm.find(':') + 1);  // npos + 1 == 0: the whole
+      std::fprintf(f,
+                   ",{\"name\":\"%s\",\"ph\":\"X\",\"ts\":%.3f,\"dur\":%.3f,"
+                   "\"pid\":1,\"tid\":%d}",
+                   nm.c_str(), e.t0 * 1e6, double(e.dur) * 1e6, rank);
+    }
+    std::fprintf(f, "]\n");
+    std::fclose(f);
+  }
+
+ private:
+  static constexpr int kTagSpace = 2000;  // T_PEER_EOF is the highest tag
+  static constexpr size_t kEventsAtStart = size_t(1) << 19;
+  static constexpr size_t kEventsAtMost = size_t(1) << 22;  // 64 MB
+
+  struct Second {
+    int64_t sec;
+    double s[G_COUNT];
+    int64_t n[G_COUNT];
+  };
+  struct Event {
+    double t0;
+    float dur;
+    uint8_t phase;
+  };
+
+  // `now` is in a later second than the open stretch began in: the stretch
+  // is split at each boundary it crosses and the finished seconds are cut
+  void roll(double now) {
+    while (now >= sec_end_) {
+      s_[cur_] += sec_end_ - since_;
+      since_ = sec_end_;
+      cut_second();
+      sec_end_ += 1.0;
+    }
+    s_[cur_] += now - since_;
+    since_ = now;
+  }
+
+  // what the groups gained since the last cut is the second that ends at
+  // sec_end_ (the last one: at since_)
+  void cut_second() {
+    double s[G_COUNT] = {};
+    int64_t n[G_COUNT] = {};
+    for (int p = 0; p < kNumPhases; ++p) {
+      s[group(p)] += s_[p];
+      n[group(p)] += n_[p];
+    }
+    Second sec;
+    sec.sec = int64_t(sec_end_) - 1;
+    for (int g = 0; g < G_COUNT; ++g) {
+      sec.s[g] = s[g] - cut_s_[g];
+      sec.n[g] = n[g] - cut_n_[g];
+      cut_s_[g] = s[g];
+      cut_n_[g] = n[g];
+    }
+    seconds_.push_back(sec);
+  }
+
+  void keep_event(double now) {
+    bool traced = cur_ >= P_HANDLER || cur_ == P_DECODE || cur_ == P_FLUSH ||
+                  cur_ == P_SNAPSHOT;
+    if (!traced || now <= since_) return;
+    if (events_.size() == events_.capacity()) {
+      if (events_.capacity() >= kEventsAtMost) {
+        dropped_ += 1;
+        return;
+      }
+      events_.reserve(events_.capacity() * 2);
+    }
+    events_.push_back({since_, float(now - since_), cur_});
+  }
+
+  uint8_t by_tag_[kTagSpace];
+  uint8_t cur_ = P_PERIODIC;
+  bool tracing_ = false;
+  double since_ = 0.0, sec_end_ = 0.0, t_start_ = 0.0;
+  double s_[kNumPhases] = {};
+  int64_t n_[kNumPhases] = {};
+  double cut_s_[G_COUNT] = {};
+  int64_t cut_n_[G_COUNT] = {};
+  std::vector<Second> seconds_;
+  std::vector<Event> events_;
+  int64_t dropped_ = 0;
+  std::string trace_prefix_;
+};
+
+// A stretch of `phase` inside whatever phase is open: that one is closed
+// here and goes on, not counted again, when this ends.
+struct Nested {
+  Phases& ph;
+  uint8_t outer;
+  Nested(Phases& p, uint8_t phase, double now) : ph(p), outer(p.current()) {
+    ph.stamp(now);
+    ph.enter(phase);
+  }
+  ~Nested() {
+    ph.stamp(monotonic());
+    ph.resume(outer);
+  }
+};
+
+// What ended a parked reserve's wait: a put into this server or a match over
+// its own queue (local); a unit that SS_MIGRATE_WORK / SS_PUSH_WORK brought
+// here (migrated); an SS_RFR_RESP that answers a plan's SS_PLAN_MATCH
+// (plan), or this server's own SS_RFR (steal).
+enum Cause : uint8_t { C_LOCAL, C_MIGRATED, C_PLAN, C_STEAL, C_COUNT };
+constexpr const char* kCauseNames[C_COUNT] = {"local", "migrated", "plan",
+                                              "steal"};
+
+// How long parked reserves waited (seconds), in the JSON shape of
+// obs/metrics.py::Histogram (`bounds`, `counts`, `sum`, `n`; counts[i] holds
+// the observations <= bounds[i], the last one those beyond), so that
+// quantile_of reads it: bounds x sqrt(2) from 1 us past 100 s.
+struct WaitHist {
+  static constexpr int kBounds = 55;
+  int64_t counts[kBounds + 1] = {};
+  double sum = 0.0;
+  int64_t n = 0;
+
+  static const double* bounds() {
+    static const std::array<double, kBounds> b = [] {
+      std::array<double, kBounds> v;
+      for (int i = 0; i < kBounds; ++i) v[i] = 1e-6 * std::pow(2.0, i / 2.0);
+      return v;
+    }();
+    return b.data();
+  }
+  void observe(double x) {
+    const double* b = bounds();
+    counts[std::lower_bound(b, b + kBounds, x) - b] += 1;
+    sum += x;
+    n += 1;
+  }
+  void write_json(std::ostream& os) const {
+    char num[64];
+    os << "{\"bounds\": [";
+    for (int i = 0; i < kBounds; ++i) {
+      std::snprintf(num, sizeof num, "%.9g", bounds()[i]);
+      os << (i ? ", " : "") << num;
+    }
+    os << "], \"counts\": [";
+    for (int i = 0; i <= kBounds; ++i) os << (i ? ", " : "") << counts[i];
+    std::snprintf(num, sizeof num, "%.17g", sum);
+    os << "], \"sum\": " << num << ", \"n\": " << n << "}";
+  }
+};
+
 // ---- endpoint: one thread, one epoll set, lazy outbound --------------------
 // The reactor owns every socket. Its one wait (wait_io, from recv) asks
 // epoll about the listener, the inbound connections and whichever outbound
@@ -590,6 +940,9 @@ class Endpoint {
   // on to sleep in epoll (the STATS trailer)
   int64_t waits_polled() const { return waits_polled_; }
   int64_t waits_slept() const { return waits_slept_; }
+  // where this thread's time goes: recv labels its own stretches (asleep,
+  // poll, decode), the server the rest
+  Phases& phases() { return ph_; }
 
   void send(int dest, const NMsg& m) {
     OutConn& oc = out_[dest];
@@ -638,26 +991,40 @@ class Endpoint {
   // epoll is asked once a budget (under a flood of hits too, so a TCP
   // peer's frame or a new connection waits one budget at most). The budget
   // is shorter than any of periodic()'s intervals.
-  bool recv(NMsg* out, double timeout) {
+  //
+  // `*clock` is the caller's last clock read, which the caller has stamped
+  // (Phases); it comes back as the last one made here. Every stretch in
+  // between is labelled here, at its end, when it is known what the look
+  // brought: `decode` if frames, else `poll`; `asleep` ends where epoll
+  // returns (wait_io). What periodic() queued leaves under the caller's
+  // label.
+  bool recv(NMsg* out, double timeout, double* clock) {
     if (inbox_.empty()) {
-      flush_pending();
+      double now = *clock;
+      if (!pending_.empty()) {
+        flush_pending();
+        now = monotonic();
+        ph_.stamp(now);
+      }
       bool traffic = served_;
       served_ = false;
       double budget = std::min(hostsock::poll_budget_s(), timeout);
       if (traffic && budget > 0) {
-        double now = monotonic(), deadline = now + budget;
+        double deadline = now + budget;
         do {
           if (!last_ring_ || now - asked_at_ >= budget) wait_io(0);
           else scan_rings();
-          now = monotonic();
+          now = look_done();
         } while (inbox_.empty() && now < deadline);
       }
       if (inbox_.empty()) {
         ++waits_slept_;
         wait_io(timeout);
+        now = look_done();
       } else {
         ++waits_polled_;
       }
+      *clock = now;
     }
     return recv_now(out);
   }
@@ -721,6 +1088,14 @@ class Endpoint {
     bool pending = false;       // in pending_, waiting for flush_pending()
   };
 
+  // the clock read that ends a look, labelled by what the look brought
+  double look_done() {
+    double now = monotonic();
+    ph_.enter(inbox_.empty() ? P_POLL : P_DECODE);
+    ph_.stamp(now);
+    return now;
+  }
+
   void watch(int op, int fd, uint32_t events, Kind kind, int id) {
     epoll_event ev{};
     ev.events = events;
@@ -770,6 +1145,10 @@ class Endpoint {
     if (marked)
       for (InConn* c : rings_) c->ring.sleeps(false);
     asked_at_ = monotonic();
+    if (timeout > 0) {  // a wait that could sleep: asleep until here
+      ph_.enter(P_ASLEEP);
+      ph_.stamp(asked_at_);
+    }
     if (n < 0) {
       if (errno == EINTR) return;
       die("epoll wait: %s", strerror(errno));
@@ -1117,6 +1496,7 @@ class Endpoint {
   std::unordered_map<int, OutConn> out_;  // by destination rank
   std::vector<int> pending_;  // destinations with frames flush_pending() owes
   std::deque<NMsg> inbox_;  // read and decoded, not yet dispatched
+  Phases ph_;
 };
 
 // ---- world / config -------------------------------------------------------
@@ -1162,6 +1542,9 @@ struct Cfg {
   // reload this rank's <prefix>.<rank>.ckpt shard at startup (same shard
   // bytes as the Python servers: runtime/checkpoint.py ACK1 format)
   std::string restore_path;
+  // where the flight artefact goes at the end (Config(flight_dir) or
+  // ADLB_FLIGHT_DIR, resolved and made by daemon.py); empty: none is written
+  std::string flight_dir;
 };
 
 // ---- server state ---------------------------------------------------------
@@ -1205,7 +1588,7 @@ struct CommonEntry {
 class Server {
  public:
   Server(World w, Cfg cfg, int rank, Endpoint* ep)
-      : w_(w), cfg_(cfg), rank_(rank), ep_(ep) {
+      : w_(w), cfg_(cfg), rank_(rank), ep_(ep), ph_(ep->phases()) {
     master_ = (rank_ == w_.master_server_rank());
     for (int r = 0; r < w_.num_app_ranks(); ++r)
       if (w_.home_server(r) == rank_) local_apps_.insert(r);
@@ -1215,13 +1598,18 @@ class Server {
     if (!cfg_.restore_path.empty()) restore_from(cfg_.restore_path);
   }
 
+  // Every clock read of the loop is also a stamp of the thread's phases
+  // (Phases): the loop's top is the last turn's end, the read before recv
+  // ends periodic(), recv brings back the read that ended its last look,
+  // and the drain's read a frame ends the handler before it.
   void run() {
     double now = monotonic();
     next_qmstat_ = now;
     next_exhaust_ = now + cfg_.exhaust_check_interval;
     next_pstats_ = now + cfg_.periodic_log_interval;
+    ph_.start(now, std::getenv("ADLB_TRACE"));
     while (!done_) {
-      now = monotonic();
+      ph_.enter(P_PERIODIC);
       periodic(now);
       double deadline = next_qmstat_;
       if (master_ && next_exhaust_ < deadline) deadline = next_exhaust_;
@@ -1230,28 +1618,38 @@ class Server {
         if (d < deadline) deadline = d;  // pending delta flush is due
       }
       NMsg m;
-      bool got = ep_->recv(&m, std::max(deadline - monotonic(), 0.0));
       double t0 = monotonic();
-      if (got) {
-        dispatch(m);
-        // bounded drain of what the last reads brought: periodic() keeps
-        // its deadlines under a flood, and the answers leave together
-        for (int i = 0; i < 128 && !done_; ++i) {
-          if (monotonic() >= deadline) break;
-          NMsg m2;
-          if (!ep_->recv_now(&m2)) break;
-          dispatch(m2);
-        }
-        ep_->flush_pending();
+      ph_.stamp(t0);
+      bool got = ep_->recv(&m, std::max(deadline - t0, 0.0), &t0);
+      now = t0;
+      if (!got) continue;
+      ph_.enter(ph_.handler(m.tag));
+      dispatch(m);
+      // bounded drain of what the last reads brought: periodic() keeps
+      // its deadlines under a flood, and the answers leave together
+      for (int i = 0;; ++i) {
+        now = monotonic();
+        ph_.stamp(now);
+        if (i >= 128 || done_ || now >= deadline) break;
+        NMsg m2;
+        if (!ep_->recv_now(&m2)) break;
+        ph_.enter(ph_.handler(m2.tag));
+        dispatch(m2);
       }
-      stats_[K_LOOP_TOP_TIME] += monotonic() - t0;
+      ph_.enter(P_FLUSH);
+      ep_->flush_pending();
+      now = monotonic();
+      ph_.stamp(now);
+      // the reference's Info key: the handlers of a turn that got a frame
+      // (with the snapshots they sent) and the flush that ended it, so
+      // without a planner exactly the phases handler:* + flush
+      stats_[K_LOOP_TOP_TIME] += now - t0;
     }
   }
 
   void print_stats() {
     stats_[K_MALLOC_HWM] = double(mem_hwm_);
-    stats_[K_AVG_TIME_ON_RQ] =
-        rq_wait_n_ ? rq_wait_sum_ / double(rq_wait_n_) : 0.0;
+    stats_[K_AVG_TIME_ON_RQ] = avg_time_on_rq();
     stats_[K_MAX_WQ_COUNT] = double(wq_.max_count);
     std::ostringstream os;
     os << "STATS {";
@@ -1263,26 +1661,17 @@ class Server {
       std::snprintf(num, sizeof(num), "%.17g", stats_[k]);
       os << "\"" << k << "\": " << num;
     }
-    // beside the Info keys, by name: the transport's connections by family
-    os << ", \"conns_unix\": " << ep_->conns_unix()
-       << ", \"conns_tcp\": " << ep_->conns_tcp();
-    // and how the reactor's waits ended: polling, or asleep in epoll
-    os << ", \"waits_polled\": " << ep_->waits_polled()
-       << ", \"waits_slept\": " << ep_->waits_slept();
-    // and what the rings did (hostsock.hpp): frames received by path, bells
-    // sent, publishes that found the reader awake
-    const hostsock::RingStats& rs = hostsock::ring_stats();
-    os << ", \"frames_ring\": " << rs.frames_ring
-       << ", \"frames_sock\": " << rs.frames_sock
-       << ", \"bells_rung\": " << rs.bells_rung
-       << ", \"bells_elided\": " << rs.bells_elided;
+    write_counters(os);
     os << "}";
     std::printf("%s\n", os.str().c_str());
     std::fflush(stdout);
+    if (!cfg_.flight_dir.empty()) write_flight();
+    if (ph_.tracing()) ph_.write_trace(rank_);
   }
 
   bool aborted() const { return aborted_; }
   int abort_code() const { return abort_code_; }
+
 
   void notify_balancer_end() {
     if (cfg_.tpu_mode && cfg_.balancer_rank >= 0)
@@ -1292,6 +1681,74 @@ class Server {
   }
 
  private:
+  // Beside the Info keys, by name: the transport's connections by family;
+  // how the reactor's waits ended (polling, or asleep in epoll); and what
+  // the rings did (hostsock.hpp: frames received by path, bells sent,
+  // publishes that found the reader awake). The trailer carries them to
+  // WorldResult.server_stats, the flight artefact to
+  // benchmarks/reduce/daemons.py, which prints them for the hot daemon and
+  // the others, and to scripts/obs_report.py.
+  void write_counters(std::ostream& os) const {
+    const hostsock::RingStats& rs = hostsock::ring_stats();
+    os << ", \"conns_unix\": " << ep_->conns_unix()
+       << ", \"conns_tcp\": " << ep_->conns_tcp()
+       << ", \"waits_polled\": " << ep_->waits_polled()
+       << ", \"waits_slept\": " << ep_->waits_slept()
+       << ", \"frames_ring\": " << rs.frames_ring
+       << ", \"frames_sock\": " << rs.frames_sock
+       << ", \"bells_rung\": " << rs.bells_rung
+       << ", \"bells_elided\": " << rs.bells_elided;
+  }
+
+  // flight-serverd-r<rank>-p<pid>.json in the world's flight directory
+  // (obs/flight.py names the Python ranks' files alike), once, at the end:
+  // everything this daemon counted, on its own clock. Written whole and
+  // renamed, so a reader never sees a torn file; a directory that cannot be
+  // written costs the artefact, not the world.
+  void write_flight() {
+    std::ostringstream os;
+    char num[64];
+    os << "{\"schema\": 1, \"role\": \"serverd\", \"rank\": " << rank_
+       << ", \"pid\": " << getpid() << ", \"reason\": \""
+       << (aborted_ ? "aborted" : "exit")
+       << "\", \"clock\": \"CLOCK_MONOTONIC\"";
+    std::snprintf(num, sizeof num, "%.9f", ph_.t_start());
+    os << ", \"t_start\": " << num;
+    std::snprintf(num, sizeof num, "%.9f", ph_.t_end());
+    os << ", \"t_end\": " << num << ", ";
+    ph_.write_json(os);
+    os << ", \"park_wait_s\": {";
+    for (int c = 0; c < C_COUNT; ++c) {
+      os << (c ? ", " : "") << "\"" << kCauseNames[c] << "\": ";
+      park_wait_[c].write_json(os);
+    }
+    os << "}, \"plan_entries\": " << plan_entries_
+       << ", \"plan_stale\": " << plan_stale_;
+    write_counters(os);
+    os << "}\n";
+    std::string path = cfg_.flight_dir + "/flight-serverd-r" +
+                       std::to_string(rank_) + "-p" +
+                       std::to_string(getpid()) + ".json";
+    std::string tmp = path + ".tmp";
+    FILE* f = std::fopen(tmp.c_str(), "w");
+    if (f == nullptr) return;
+    const std::string doc = os.str();
+    bool ok = std::fwrite(doc.data(), 1, doc.size(), f) == doc.size();
+    ok = std::fclose(f) == 0 && ok;
+    if (!ok || std::rename(tmp.c_str(), path.c_str()) != 0)
+      std::remove(tmp.c_str());
+  }
+
+  double avg_time_on_rq() const {
+    double sum = 0.0;
+    int64_t n = 0;
+    for (const WaitHist& h : park_wait_) {
+      sum += h.sum;
+      n += h.n;
+    }
+    return n ? sum / double(n) : 0.0;
+  }
+
   // ---- memory accounting (reference src/adlb.c:3419-3474) -----------------
   bool mem_try_alloc(int64_t n) {
     if (cfg_.max_malloc > 0 && double(mem_curr_ + n) > cfg_.max_malloc)
@@ -1440,19 +1897,18 @@ class Server {
   }
 
   void satisfy_parked(const RqEntry& e, const adlbwq::Unit& u,
-                      const Meta& meta) {
+                      const Meta& meta, Cause cause) {
     int app = e.world_rank;
     bool fetch = e.fetch;
     double wait = monotonic() - e.time_stamp;
     rq_remove(app);
     rfr_excluded_.erase(app);
-    rq_wait_sum_ += wait;
-    rq_wait_n_ += 1;
+    park_wait_[cause].observe(wait);
     activity_ += 1;
     reserve_resp_ok(app, u, meta, rank_, fetch);
   }
 
-  void match_rq() {
+  void match_rq(Cause cause) {
     // local analogue of check_remote_work_for_queued_apps
     // (reference src/adlb.c:3536-3579)
     bool progressed = true;
@@ -1464,7 +1920,7 @@ class Server {
           int64_t seqno = u->seqno;
           wq_.units[seqno].pin_rank = e.world_rank;
           RqEntry copy = e;
-          satisfy_parked(copy, wq_.units[seqno], meta_[seqno]);
+          satisfy_parked(copy, wq_.units[seqno], meta_[seqno], cause);
           progressed = true;
           break;
         }
@@ -1695,7 +2151,7 @@ class Server {
     if (e != nullptr) {
       wq_.units[seqno].pin_rank = e->world_rank;
       RqEntry copy = *e;
-      satisfy_parked(copy, wq_.units[seqno], meta);
+      satisfy_parked(copy, wq_.units[seqno], meta, C_LOCAL);
     }
     NMsg r = mk(T_TA_PUT_RESP);
     r.seti(F_RC, ADLB_SUCCESS);
@@ -2142,8 +2598,7 @@ class Server {
     } else {
       double v;
       if (key == K_MALLOC_HWM) v = double(mem_hwm_);
-      else if (key == K_AVG_TIME_ON_RQ)
-        v = rq_wait_n_ ? rq_wait_sum_ / double(rq_wait_n_) : 0.0;
+      else if (key == K_AVG_TIME_ON_RQ) v = avg_time_on_rq();
       else if (key == K_MAX_WQ_COUNT) v = double(wq_.max_count);
       else v = stats_[key];
       r.seti(F_RC, ADLB_SUCCESS);
@@ -2266,7 +2721,10 @@ class Server {
 
   void on_rfr_resp(const NMsg& m) {
     int app = int(m.geti(F_FOR_RANK));
-    rfr_out_.erase(app);
+    // an answer to this server's own SS_RFR, else to a plan's SS_PLAN_MATCH
+    // at the holder (a plan's that crosses an own one in flight reads as
+    // the own one's: the frame does not say)
+    Cause cause = rfr_out_.erase(app) ? C_STEAL : C_PLAN;
     if (!m.geti(F_FOUND)) rfr_failed_ctr_ += 1;
     if (m.geti(F_FOUND)) {
       RqEntry* e = rq_find_rank(app);
@@ -2284,8 +2742,7 @@ class Server {
       double wait = monotonic() - e->time_stamp;
       rq_remove(app);
       rfr_excluded_.erase(app);
-      rq_wait_sum_ += wait;
-      rq_wait_n_ += 1;
+      park_wait_[cause].observe(wait);
       activity_ += 1;
       resolved_ctr_ += 1;
       NMsg r = mk(T_TA_RESERVE_RESP);
@@ -2325,7 +2782,7 @@ class Server {
     if (it != wq_.units.end() && it->second.pin_rank >= 0) {
       it->second.pin_rank = -1;
       wq_.index(it->second);
-      match_rq();
+      match_rq(C_LOCAL);
     }
   }
 
@@ -2451,7 +2908,7 @@ class Server {
     meta.common_seqno = m.geti(F_COMMON_SEQNO, -1);
     meta.time_stamp = m.getd(F_TIME_STAMP, monotonic());
     stats_[K_NPUSHED_TO_HERE] += 1;
-    match_rq();
+    match_rq(C_MIGRATED);
   }
 
   void on_push_del(const NMsg& m) {
@@ -3011,6 +3468,7 @@ class Server {
 
   void flush_event_deltas(double now) {
     if (pend_seqnos_.empty()) return;
+    Nested snapshot(ph_, P_SNAPSHOT, now);
     last_event_snap_ = now;
     NMsg m = mk(T_SS_STATE_DELTA);
     m.setl(F_SEQNOS, std::move(pend_seqnos_));
@@ -3027,6 +3485,9 @@ class Server {
 
   void send_snapshot() {
     if (cfg_.balancer_rank < 0) return;
+    // its own phase wherever it is called from: the walk and sort of the
+    // whole queue is what can outlast its interval (periodic())
+    Nested snapshot(ph_, P_SNAPSHOT, monotonic());
     // the full walk supersedes pending put deltas (units are in the wq)
     pend_seqnos_.clear();
     pend_wtypes_.clear();
@@ -3100,10 +3561,13 @@ class Server {
     // enact one plan entry through the RFR response path (mirrors the
     // Python server's _on_plan_match)
     int64_t seqno = m.geti(F_SEQNO);
+    plan_entries_ += 1;
     auto it = wq_.units.find(seqno);
     if (it == wq_.units.end() || it->second.pin_rank >= 0 ||
-        it->second.target_rank >= 0)
+        it->second.target_rank >= 0) {
+      plan_stale_ += 1;
       return;  // stale plan entry; next round re-plans
+    }
     int for_rank = int(m.geti(F_FOR_RANK));
     it->second.pin_rank = for_rank;
     activity_ += 1;
@@ -3139,11 +3603,14 @@ class Server {
     std::string blob;
     uint32_t n = 0;
     blob_u32(blob, 0);  // patched below
+    plan_entries_ += int64_t(seqnos->size());
     for (int64_t seqno : *seqnos) {
       auto it = wq_.units.find(seqno);
       if (it == wq_.units.end() || it->second.pin_rank >= 0 ||
-          it->second.target_rank >= 0)
+          it->second.target_rank >= 0) {
+        plan_stale_ += 1;
         continue;  // stale plan entry
+      }
       adlbwq::Unit unit = it->second;
       Meta meta = std::move(meta_[seqno]);
       meta_.erase(seqno);
@@ -3256,7 +3723,7 @@ class Server {
       wk.seti(F_BOUNCED, 1);
       ep_->send(m.src, wk);
     }
-    if (any_added) match_rq();
+    if (any_added) match_rq(C_MIGRATED);
     // immediate full snapshot: the batch ack and the post-batch
     // inventory reach the planner now, not a heartbeat later — the
     // follow-up top-up cadence rides on this. Sent for empty id-bearing
@@ -3365,8 +3832,13 @@ class Server {
   int64_t activity_ = 0;
 
   std::vector<double> stats_;
-  double rq_wait_sum_ = 0.0;
-  int64_t rq_wait_n_ = 0;
+  Phases& ph_;  // the endpoint's: one thread, one account of its time
+  // how long a parked reserve waited, by what ended the wait;
+  // K_AVG_TIME_ON_RQ is their merged sum / n
+  WaitHist park_wait_[C_COUNT];
+  // plan entries received (SS_PLAN_MATCH: one; SS_PLAN_MIGRATE: one a
+  // seqno) and those found stale: the planner's useful outcomes to attempts
+  int64_t plan_entries_ = 0, plan_stale_ = 0;
   double next_qmstat_ = 0.0, next_exhaust_ = 0.0, next_ds_log_ = 0.0;
   int64_t qm_trips_ = 0;
   int64_t puts_ctr_ = 0, resolved_ctr_ = 0, pstats_seq_ = 0;
@@ -3417,6 +3889,10 @@ int main() {
     else if (key == "restore_path") {
       is >> std::ws;
       std::getline(is, cfg.restore_path);  // rest of line: paths may have spaces
+    }
+    else if (key == "flight_dir") {
+      is >> std::ws;
+      std::getline(is, cfg.flight_dir);
     }
     else if (!key.empty()) die("unknown config key '%s'", key.c_str());
   }

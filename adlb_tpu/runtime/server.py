@@ -40,7 +40,7 @@ from adlb_tpu.obs.metrics import Registry, attach, quantile_of
 from adlb_tpu.runtime.debug import aprintf, self_diagnosis
 from adlb_tpu.runtime.hedge import HedgeManager, should_hedge
 from adlb_tpu.runtime.messages import Msg, Tag, msg
-from adlb_tpu.runtime.trace import PID_SERVER, Tracer, span
+from adlb_tpu.runtime.trace import PID_SERVER, Tracer, clock_mark, span
 from adlb_tpu.runtime.queues import (
     CommonStore,
     LeaseTable,
@@ -149,6 +149,7 @@ class _BalancerWorker(threading.Thread):
         while True:
             if prof is not None:
                 prof.set_phase("balancer_idle")
+            clock_mark()
             with span("adlb.master.wait", s.metrics):
                 self.wake.wait(timeout=idle if idle > 0 else None)
             self.wake.clear()

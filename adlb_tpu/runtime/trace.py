@@ -20,13 +20,23 @@ each planning round in ``balancer:round``, on a tracer whose ``pid``
 marks the role. Client tracers run as ``pid=0`` ("apps"), server tracers
 as ``pid=1`` ("servers"), so one merged Perfetto/chrome://tracing file
 shows both sides of every reserve as two process lanes on a shared
-clock (all ranks in one ``run_world`` share ``time.monotonic``).
+clock (all ranks in one ``run_world`` share ``time.monotonic``). The
+native ranks trace alike under ``ADLB_TRACE=<prefix>``: a C client
+(``libadlb.cpp``) its API calls, a native daemon (``serverd.cpp``) its
+reactor's ``srv:<TAG>`` handlers with ``srv:decode``, ``srv:flush`` and
+``srv:snapshot``, as ``pid=1``, each into ``<prefix>.<rank>.trace.json``
+on ``CLOCK_MONOTONIC``, which is ``time.monotonic`` here.
 
 Every span goes through one primitive, :class:`span`: it also writes the
 span into a running ``jax.profiler`` session (the device trace's clock)
 when JAX is loaded, and observes its duration in the ``span_s{name=...}``
 histogram of an obs registry. The planner's loop, round and solve carry a
 fixed set of ``adlb.*`` spans through it (docs/USERGUIDE.md §5).
+
+A profiler session has a clock of its own. :func:`clock_mark` ties it to
+``CLOCK_MONOTONIC``, so that what any process of the host stamped (Chrome
+files, the daemons' flight artefacts, an application's own logs) can be
+laid over a device trace.
 
 Events use the Chrome trace-event format (``ph: "X"``, microsecond
 timestamps, ``tid`` = world rank) so a merged dump loads directly in
@@ -104,6 +114,32 @@ class span:
                     **({"args": self._args} if self._args else {}),
                 }
             )
+
+
+#: the least time between two clock marks of a process
+CLOCK_MARK_GAP_S = 0.5
+_next_clock_mark = 0.0
+
+
+def clock_mark() -> None:
+    """Where JAX is loaded, one ``adlb.clock`` ``TraceAnnotation`` that
+    carries ``time.monotonic_ns()``, read as it starts, as its ``ns``
+    argument; at most one every ``CLOCK_MARK_GAP_S``, and nothing at all
+    without JAX. In a profiler session the mark lands on the host plane
+    with the session's own time stamp, so ``start_ns - ns`` is the offset
+    between ``CLOCK_MONOTONIC`` and the trace, and the marks of a window
+    give its spread (``benchmarks/reduce/daemons.py`` reads them). The
+    planner's loops call it between their spans."""
+    global _next_clock_mark
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return
+    now = time.monotonic()
+    if now < _next_clock_mark:
+        return
+    _next_clock_mark = now + CLOCK_MARK_GAP_S
+    with profiler.TraceAnnotation("adlb.clock", ns=time.monotonic_ns()):
+        pass
 
 
 class Tracer:

@@ -67,7 +67,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from adlb_tpu.obs.metrics import Registry  # noqa: E402
+from adlb_tpu.obs.metrics import Registry, quantile_of  # noqa: E402
 
 _SPARK = "▁▂▃▄▅▆▇█"
 
@@ -165,6 +165,42 @@ def failure_timeline(docs: list[dict]) -> list[tuple]:
     return events
 
 
+def serverd_summary(d: dict, top: int = 8) -> list[str]:
+    """A native daemon's artifact (``flight-serverd-r<rank>-p<pid>.json``,
+    USERGUIDE §5): where its reactor thread's time went, by share of the
+    world it lived, and how long parked reserves waited, by what ended
+    the wait."""
+    out = []
+    world = d.get("t_end", 0.0) - d.get("t_start", 0.0)
+    phases = d.get("phase_s", {})
+    counts = d.get("phase_n", {})
+    if world > 0 and phases:
+        out.append(f"  reactor phases over {world:.3f}s "
+                   f"({d.get('clock', '?')}):")
+        for name, sec in sorted(phases.items(), key=lambda kv: -kv[1])[:top]:
+            n = counts.get(name, 0)
+            each = f"{sec / n * 1e6:10.2f} us each" if n else ""
+            out.append(f"    {name:<28} {100.0 * sec / world:6.2f}%  "
+                       f"x {n:<9}{each}")
+    waits = []
+    for cause, h in sorted(d.get("park_wait_s", {}).items()):
+        if h.get("n"):
+            p50, p95 = (quantile_of(h["bounds"], h["counts"], h["n"], q)
+                        for q in (0.5, 0.95))
+            waits.append(f"{cause} n={h['n']} p50 {p50 * 1e3:.3f} ms "
+                         f"p95 {p95 * 1e3:.3f} ms")
+    out.append("  park waits by cause: " + ("; ".join(waits) or "none"))
+    if d.get("plan_entries"):
+        out.append(f"  plan entries {d['plan_entries']}, stale "
+                   f"{d.get('plan_stale', 0)}")
+    out.append("  transport: " + ", ".join(
+        f"{k} {d[k]}" for k in (
+            "waits_polled", "waits_slept", "frames_ring", "frames_sock",
+            "bells_rung", "bells_elided", "conns_unix", "conns_tcp")
+        if k in d))
+    return out
+
+
 def report(docs: list[dict], tail: int = 8) -> list[str]:
     out: list[str] = []
     ranked = sorted(docs, key=lambda d: d.get("rank", 1 << 30))
@@ -181,6 +217,8 @@ def report(docs: list[dict], tail: int = 8) -> list[str]:
         )
         for ts, text in events[-tail:]:
             out.append(f"  [{ts:.6f}] {text}")
+        if role == "serverd":
+            out.extend(serverd_summary(d))
 
     # -- failure timeline (merged across ranks) ------------------------------
     timeline = failure_timeline(ranked)

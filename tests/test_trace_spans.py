@@ -115,6 +115,81 @@ def test_primitive_with_no_sink_and_with_a_raising_body():
     assert span_hists(reg)["adlb.raises"]["count"] == 1  # still observed
 
 
+class FakeMark:
+    seen: list = []
+
+    def __init__(self, name, **args):
+        FakeMark.seen.append((name, args))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        pass
+
+
+@pytest.mark.parametrize("jax_loaded", [False, True])
+def test_clock_mark_is_silent_without_jax_and_paced_with_it(
+        monkeypatch, jax_loaded):
+    import time
+
+    import jax
+
+    from adlb_tpu.runtime import trace
+
+    monkeypatch.setattr(FakeMark, "seen", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeMark)
+    monkeypatch.setattr(trace, "_next_clock_mark", 0.0)
+    if not jax_loaded:
+        monkeypatch.delitem(sys.modules, "jax")
+    before = time.monotonic_ns()
+    for _ in range(50):  # a loop that turns far more often than marks go
+        trace.clock_mark()
+    after = time.monotonic_ns()
+    assert "jax" in sys.modules or not jax_loaded  # never imported by it
+    if not jax_loaded:
+        assert FakeMark.seen == []
+        return
+    ((name, args),) = FakeMark.seen  # one a CLOCK_MARK_GAP_S
+    assert name == "adlb.clock" and list(args) == ["ns"]
+    assert before <= args["ns"] <= after  # CLOCK_MONOTONIC, read at the mark
+    monkeypatch.setattr(trace, "_next_clock_mark", 0.0)  # the gap has passed
+    trace.clock_mark()
+    assert len(FakeMark.seen) == 2
+
+
+def test_a_profiler_session_keeps_the_marks_reading(tmp_path, monkeypatch):
+    """The reading a mark carries comes back out of the ``.xplane.pb`` as
+    the event's ``ns`` argument, beside the session's own time stamp: the
+    two clocks' offset, to microseconds."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from adlb_tpu.runtime import trace
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for _ in range(3):
+            monkeypatch.setattr(trace, "_next_clock_mark", 0.0)
+            trace.clock_mark()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    offsets = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name == "adlb.clock":
+                    assert plane.name.startswith("/host:")
+                    offsets.append(ev.start_ns - dict(ev.stats)["ns"])
+    assert len(offsets) == 3
+    assert max(offsets) - min(offsets) < 1e6  # within a millisecond here
+
+
 @pytest.mark.parametrize("args", [{}, {"src": 3, "tag": "PUT"}])
 def test_tracer_span_keeps_its_event(args):
     tr = Tracer(rank=3)
