@@ -1,6 +1,6 @@
 """Median of what the producer's synchronous puts took, each by the
 producer's own clock around the blocking call (``p0.puts``): the round
-trip itself, where ``producer_puts_per_s`` is its mean turned over. A
+trip itself, where ``flood_puts_per_s`` is its mean turned over. A
 pipelined producer logs none and the metric is left out."""
 
 import numpy as np

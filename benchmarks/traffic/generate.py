@@ -18,6 +18,12 @@ Mix parameters (all optional but ``put_routing``):
                  pipelined puts, acknowledged at a flush every k puts.
 ``work_mult``    ``[[multiplier, share], ...]`` of the deployment's unit
                  time (default ``[[1.0, 1.0]]``); shares sum to 1.
+``units_x``      1 (default): an unpaced producer's backlog is capacity x
+                 (``fed_warm_s`` + seconds) units. An integer k > 1: k
+                 times that, for a mix whose flood is what is timed and
+                 has to last long enough to be read. Units left when the
+                 window closes cost their workers nothing, so the drain
+                 after it is as fast as fetches.
 """
 
 from __future__ import annotations
@@ -47,7 +53,8 @@ def n_units(config: dict, mix: dict, seconds: float) -> int:
     pace = float(mix.get("pace", 0))
     if pace > 0:
         return math.ceil(pace * cap * (config["warm_s"] + seconds))
-    return math.ceil(cap * (config["fed_warm_s"] + seconds))
+    backlog = math.ceil(cap * (config["fed_warm_s"] + seconds))
+    return backlog * int(mix.get("units_x", 1))
 
 
 def tag_of(ids: np.ndarray, seed: int) -> np.ndarray:

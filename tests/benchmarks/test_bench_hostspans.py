@@ -276,7 +276,7 @@ def test_the_new_metrics_are_listed_for_both_cells(spec):
     sidecar = [cell for cell in spec.cells()
                if spec.config(cell)["plane"] == "native"]
     assert {"hotspot-native-n128.bulk", "hotspot-native-n64.bulk",
-            "hotspot-native-n64.syncput"} <= set(sidecar)
+            "hotspot-native-n128.syncput"} <= set(sidecar)
     for cell in sidecar:
         listed = [m["name"] for m in spec.metrics("per_layer", cell)]
         assert set(SPAN_METRICS + ["plan_age_p95_ms"]) <= set(listed)

@@ -74,6 +74,13 @@ def add_standin(root: str, fault: str = "none", app_ranks: int = 9,
     return cell
 
 
+def in_order(wanted: list, names: list) -> bool:
+    """Every name of ``wanted`` is in ``names``, in this order; whatever a
+    later cell or entry adds before, between or behind them is no matter."""
+    rest = iter(names)
+    return all(name in rest for name in wanted)
+
+
 def test_the_committed_benchmark_resolves_every_cell():
     spec = Spec(ROOT)
     spec.check_files()
