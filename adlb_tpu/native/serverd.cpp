@@ -1723,7 +1723,9 @@ class Server {
       park_wait_[c].write_json(os);
     }
     os << "}, \"plan_entries\": " << plan_entries_
-       << ", \"plan_stale\": " << plan_stale_;
+       << ", \"plan_stale\": " << plan_stale_
+       << ", \"snap_walked\": " << snap_walked_
+       << ", \"snap_shipped\": " << snap_shipped_;
     write_counters(os);
     os << "}\n";
     std::string path = cfg_.flight_dir + "/flight-serverd-r" +
@@ -2024,11 +2026,11 @@ class Server {
       flush_event_deltas(now);
     if (now >= next_qmstat_) {
       if (cfg_.tpu_mode) {
-        // O(wq) walk: fast cadence only while someone is parked AND this
-        // server could contribute (inventory for the solve, or its own
-        // parked requesters whose fresh stamps keep them re-plannable),
-        // or under memory pressure; slow heartbeat otherwise (parks send
-        // event snapshots themselves)
+        // fast cadence only while someone is parked AND this server could
+        // contribute (inventory for the solve, or its own parked
+        // requesters whose fresh stamps keep them re-plannable), or under
+        // memory pressure; slow heartbeat otherwise (parks send event
+        // snapshots themselves)
         bool relevant = hungry_ && (!rq_.empty() || wq_has_untargeted());
         if (relevant || mem_under_pressure() || now >= next_idle_snap_) {
           next_idle_snap_ = now + 0.25;
@@ -2039,13 +2041,13 @@ class Server {
       }
       if (mem_under_pressure()) try_push();
       // The next one is due an interval after this one is DONE. A snapshot
-      // walks and sorts the whole queue (some 30 ms at 170,000 units), and
-      // counted from its start a duty that outlasts its interval is due
-      // again the moment it ends: run()'s drain then stops at its first
-      // frame, every turn, and the reactor serves one frame a snapshot.
-      // Parked workers keep `hungry_` up, which keeps the cadence fast, so
-      // a server that got there stayed there (a flood that outran the
-      // planner's first migrations did it).
+      // reads its top K off snap_index_, but one whose walk passes many
+      // pinned units, or whose send is slow, can still outlast the
+      // interval, and counted from its start such a duty is due again the
+      // moment it ends: run()'s drain then stops at its first frame, every
+      // turn, and the reactor serves one frame a snapshot. Parked workers
+      // keep `hungry_` up, which keeps the cadence fast, so a server that
+      // got there would stay there.
       next_qmstat_ = monotonic() + (cfg_.tpu_mode ? cfg_.balancer_interval
                                                   : cfg_.qmstat_interval);
     }
@@ -2137,6 +2139,7 @@ class Server {
     if (wq_.count > wq_.max_count) wq_.max_count = wq_.count;
     wq_.total_bytes += u.payload_len;
     wq_.index(u);
+    snap_index_add(u);
     Meta& meta = meta_[seqno];
     meta.payload = *payload;
     meta.answer_rank = int32_t(m.geti(F_ANSWER_RANK, -1));
@@ -2158,8 +2161,8 @@ class Server {
     echo_pid(r);
     ep_->send(m.src, r);
     // event path for an untargeted put of a type some parked requester
-    // wants (SS_HUNGRY): an O(1) DELTA carrying just this unit, not the
-    // O(wq) snapshot walk; targeted puts match at the target's home
+    // wants (SS_HUNGRY): an O(1) DELTA carrying just this unit, not a
+    // whole snapshot; targeted puts match at the target's home
     // server and never enter snapshots, and the periodic heartbeat
     // covers everything else
     if (e == nullptr && u.target_rank < 0 && hungry_ &&
@@ -2378,6 +2381,7 @@ class Server {
       if (wq_.count > wq_.max_count) wq_.max_count = wq_.count;
       wq_.total_bytes += u.payload_len;
       wq_.index(u);
+      snap_index_add(u);
       Meta& meta = meta_[seqno];
       meta.payload.assign(data.data() + off, plen);
       off += plen;
@@ -2899,6 +2903,7 @@ class Server {
     if (wq_.count > wq_.max_count) wq_.max_count = wq_.count;
     wq_.total_bytes += u.payload_len;
     wq_.index(u);
+    snap_index_add(u);
     Meta& meta = meta_[seqno];
     meta.payload = *payload;
     meta.answer_rank = int32_t(m.geti(F_ANSWER_RANK, -1));
@@ -3483,36 +3488,85 @@ class Server {
     pend_lens_.clear();
   }
 
+  void snap_index_add(const adlbwq::Unit& u) {
+    if (cfg_.balancer_rank < 0 || u.target_rank >= 0) return;
+    // nearly all traffic has one priority: the bucket of the last append
+    if (snap_last_ == snap_index_.end() || snap_last_->first != u.prio)
+      snap_last_ = snap_index_.try_emplace(u.prio).first;
+    snap_last_->second.push_back(u.seqno);
+    snap_entries_ += 1;
+    // a walk drops the dead only where it passes; behind a front that
+    // stays (units nobody asks for) they would pile up: once they
+    // outnumber the live units, filter every bucket, order kept
+    // (O(entries), so amortized O(1) an append)
+    if (snap_entries_ > 2 * wq_.count + 1024) snap_index_compact();
+  }
+
+  void snap_index_compact() {
+    snap_entries_ = 0;
+    for (auto b = snap_index_.begin(); b != snap_index_.end();) {
+      std::deque<int64_t>& q = b->second;
+      q.erase(std::remove_if(q.begin(), q.end(),
+                             [this](int64_t seqno) {
+                               return wq_.units.count(seqno) == 0;
+                             }),
+              q.end());
+      snap_entries_ += int64_t(q.size());
+      if (q.empty()) {
+        if (b == snap_last_) snap_last_ = snap_index_.end();
+        b = snap_index_.erase(b);
+      } else {
+        ++b;
+      }
+    }
+  }
+
   void send_snapshot() {
     if (cfg_.balancer_rank < 0) return;
-    // its own phase wherever it is called from: the walk and sort of the
-    // whole queue is what can outlast its interval (periodic())
+    // its own phase wherever it is called from (periodic())
     Nested snapshot(ph_, P_SNAPSHOT, monotonic());
-    // the full walk supersedes pending put deltas (units are in the wq)
+    // the snapshot supersedes pending put deltas (units are in the wq)
     pend_seqnos_.clear();
     pend_wtypes_.clear();
     pend_prios_.clear();
     pend_lens_.clear();
-    // top-K unpinned untargeted by (prio desc, seqno asc)
-    std::vector<const adlbwq::Unit*> avail;
-    avail.reserve(wq_.units.size());
-    for (const auto& kv : wq_.units)
-      if (kv.second.pin_rank < 0 && kv.second.target_rank < 0)
-        avail.push_back(&kv.second);
-    std::sort(avail.begin(), avail.end(),
-              [](const adlbwq::Unit* a, const adlbwq::Unit* b) {
-                if (a->prio != b->prio) return a->prio > b->prio;
-                return a->seqno < b->seqno;
-              });
-    size_t k = std::min<size_t>(avail.size(), size_t(cfg_.balancer_max_tasks));
+    // top-K unpinned untargeted by (prio desc, seqno asc), read off the
+    // index: O(K + entries skipped), each dead entry dropped once
+    const size_t k = size_t(cfg_.balancer_max_tasks);
     std::vector<int64_t> tasks;
     tasks.reserve(4 * k);
-    for (size_t i = 0; i < k; ++i) {
-      tasks.push_back(avail[i]->seqno);
-      tasks.push_back(avail[i]->work_type);
-      tasks.push_back(avail[i]->prio);
-      tasks.push_back(avail[i]->payload_len);
+    size_t shipped = 0;
+    std::vector<int64_t> kept;  // a bucket's walked entries, less the dead
+    for (auto b = snap_index_.begin();
+         b != snap_index_.end() && shipped < k;) {
+      std::deque<int64_t>& q = b->second;
+      kept.clear();
+      while (!q.empty() && shipped < k) {
+        int64_t seqno = q.front();
+        q.pop_front();
+        snap_walked_ += 1;
+        auto it = wq_.units.find(seqno);
+        if (it == wq_.units.end()) {
+          snap_entries_ -= 1;
+          continue;
+        }
+        kept.push_back(seqno);
+        if (it->second.pin_rank >= 0) continue;
+        tasks.push_back(seqno);
+        tasks.push_back(it->second.work_type);
+        tasks.push_back(it->second.prio);
+        tasks.push_back(it->second.payload_len);
+        shipped += 1;
+      }
+      q.insert(q.begin(), kept.begin(), kept.end());
+      if (q.empty()) {
+        if (b == snap_last_) snap_last_ = snap_index_.end();
+        b = snap_index_.erase(b);
+      } else {
+        ++b;
+      }
     }
+    snap_shipped_ += int64_t(shipped);
     std::vector<int64_t> reqs;
     int64_t nreqs = 0;
     for (const auto& e : rq_) {
@@ -3702,6 +3756,7 @@ class Server {
       if (wq_.count > wq_.max_count) wq_.max_count = wq_.count;
       wq_.total_bytes += u.payload_len;
       wq_.index(u);
+      snap_index_add(u);
       Meta& meta = meta_[seqno];
       meta.payload.assign(blob->data() + o, plen);
       meta.answer_rank = answer;
@@ -3814,6 +3869,22 @@ class Server {
   // src server -> highest planner migration-batch id received from it
   std::map<int, int64_t> mig_acks_;
   std::map<int, int64_t> last_snap_acks_;  // acks as of last sent snapshot
+  // The snapshot's order, kept as units arrive (snap_index_add): priority
+  // descending, then a FIFO of seqnos. Every unit takes the next seqno and
+  // no path changes a unit's prio or target_rank once it is queued, so
+  // appending keeps each bucket sorted and send_snapshot reads its top K
+  // off the front. Entries are lazy: one whose unit left wq_.units is
+  // dropped when a walk reaches it (or by snap_index_compact); a pinned
+  // one is skipped and stays in place, since a released pin
+  // (on_unreserve) makes it available there. Kept only where snapshots
+  // are sent (balancer_rank >= 0).
+  using SnapIndex =
+      std::map<int32_t, std::deque<int64_t>, std::greater<int32_t>>;
+  SnapIndex snap_index_;
+  SnapIndex::iterator snap_last_ = snap_index_.end();  // last bucket appended
+  int64_t snap_entries_ = 0;  // entries in snap_index_, live or dead
+  // index entries the snapshots' walks visited, and those they shipped
+  int64_t snap_walked_ = 0, snap_shipped_ = 0;
 
   bool no_more_work_ = false;
   bool done_by_exhaustion_ = false;

@@ -193,6 +193,9 @@ def serverd_summary(d: dict, top: int = 8) -> list[str]:
     if d.get("plan_entries"):
         out.append(f"  plan entries {d['plan_entries']}, stale "
                    f"{d.get('plan_stale', 0)}")
+    if d.get("snap_walked"):
+        out.append(f"  snapshot index: walked {d['snap_walked']}, shipped "
+                   f"{d.get('snap_shipped', 0)}")
     out.append("  transport: " + ", ".join(
         f"{k} {d[k]}" for k in (
             "waits_polled", "waits_slept", "frames_ring", "frames_sock",

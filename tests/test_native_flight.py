@@ -204,6 +204,8 @@ def test_park_waits_are_avg_time_on_rq_by_cause(steal_world):
         assert doc["park_wait_s"]["plan"]["n"] == 0
         assert doc["park_wait_s"]["migrated"]["n"] == 0
         assert doc["plan_entries"] == doc["plan_stale"] == 0
+        # and no snapshot: the index is kept only for a planner
+        assert doc["snap_walked"] == doc["snap_shipped"] == 0
     assert ended >= 2  # the workers' first reserves parked, and were served
 
 
@@ -314,10 +316,11 @@ class Planned:
     its home, server 2), app 1 (parks at its home, server 3) and the
     planner (pseudo-rank 4, which the daemons send their snapshots to)."""
 
-    def __init__(self, flight_dir):
-        self.world = WorldSpec(nranks=4, nservers=2, types=(T,))
+    def __init__(self, flight_dir, types=(T,), **cfg):
+        self.world = WorldSpec(nranks=4, nservers=2, types=types)
         cfg = Config(server_impl="native", balancer="tpu",
-                     flight_dir=str(flight_dir), exhaust_check_interval=30.0)
+                     flight_dir=str(flight_dir), exhaust_check_interval=30.0,
+                     **cfg)
         self.procs = {r: daemon_mod.spawn_daemon(self.world, cfg, r)
                       for r in (HOLDER, HOME)}
         addr = {r: ("127.0.0.1", p) for r, (_h, p) in
@@ -339,11 +342,11 @@ class Planned:
                 return m
         raise AssertionError(f"rank {rank} got no {tag} in {timeout}s")
 
-    def put(self, src, server):
+    def put(self, src, server, prio=0, target_rank=-1, work_type=T):
         self.eps[src].send(server, msg(
-            Tag.FA_PUT, src, payload=b"12345678", work_type=T, prio=0,
-            target_rank=-1, answer_rank=-1, common_len=0, common_server=-1,
-            common_seqno=-1))
+            Tag.FA_PUT, src, payload=b"12345678", work_type=work_type,
+            prio=prio, target_rank=target_rank, answer_rank=-1, common_len=0,
+            common_server=-1, common_seqno=-1))
         assert self.expect(src, Tag.TA_PUT_RESP).rc == ADLB_SUCCESS
 
     def park(self):

@@ -274,11 +274,13 @@ FA_INFO_NUM, TA_INFO_NUM_RESP = 1037, 1043
 SS_QMSTAT, SS_EXHAUST_CHK_1, SS_PLAN_MIGRATE = 1101, 1111, 1119
 SS_END_1, SS_END_2 = 1114, 1115
 SS_STATE, SS_HUNGRY, F_HUNGRY = 1117, 1124, 60
+SS_RFR_RESP, SS_PLAN_MATCH = 1103, 1118
 F_PAYLOAD, F_WORK_TYPE, F_PRIO, F_TARGET_RANK, F_ANSWER_RANK = 1, 2, 3, 4, 5
 F_COMMON_LEN, F_COMMON_SERVER, F_COMMON_SEQNO, F_RC, F_HINT = 6, 7, 8, 9, 10
 F_REQ_TYPES, F_HANG, F_RQSEQNO, F_COUNT, F_NBYTES, F_CODE = 11, 12, 13, 17, 18, 20
 F_APPTAG, F_DEST, F_SEQNOS, F_PUT_ID, F_MIG_ID = 26, 47, 48, 58, 77
 F_ORIGIN, F_COMPLETE = 40, 42
+F_SEQNO, F_FOR_RANK, F_REQ_HOME = 21, 29, 46
 
 
 # ---- a TLV codec of the test's own ----------------------------------------
@@ -709,19 +711,20 @@ def test_periodic_keeps_its_deadlines_while_one_client_sends_without_pause(
 def test_a_snapshot_that_outlasts_its_interval_does_not_starve_the_reactor(
         family):
     """A planner-mode daemon with parked ranks somewhere (``SS_HUNGRY``)
-    sends the planner a snapshot every ``balancer_interval``, and a snapshot
-    walks and sorts its whole queue: with 400,000 units queued that takes
-    longer than the interval (twice, here). The next one is due an interval
-    after the last one is DONE, so the reactor serves in between: five
-    thousand more puts are answered within a snapshot or two. Counted from
-    the snapshot's start it was due again the moment it ended, the turn's
-    drain stopped at its first frame, and the daemon served one frame a
-    snapshot for as long as anyone was hungry: five thousand puts, five
-    thousand snapshots, minutes (what a flood that outran the planner's
-    first migrations did to a whole world)."""
-    n, more = 400_000, 5_000
+    sends the planner a snapshot every ``balancer_interval``. A snapshot
+    reads its first units off an index, passing by the pinned ones: with
+    the first 100,000 units pinned by a plan's matches, that takes longer
+    than the interval of a millisecond here. The next one is due an
+    interval after the last one is DONE, so the reactor serves in between:
+    five thousand more puts are answered within a snapshot or two. Counted
+    from the snapshot's start it was due again the moment it ended, the
+    turn's drain stopped at its first frame, and the daemon served one
+    frame a snapshot for as long as anyone was hungry: five thousand puts,
+    five thousand snapshots (what a flood that outran the planner's first
+    migrations did to a whole world)."""
+    n, pinned, more = 110_000, 100_000, 5_000
     cfg = Config(server_impl="native", balancer="tpu",
-                 exhaust_check_interval=60.0)
+                 balancer_interval=1e-3, exhaust_check_interval=60.0)
     peer = Peer(family=family)  # rank 0 and the planner's pseudo-rank, 2
     with Daemons(1, 1, [1], cfg=cfg, types=(1,), family=family, peer=peer,
                  others={2: peer.port}) as w:
@@ -736,7 +739,24 @@ def test_a_snapshot_that_outlasts_its_interval_does_not_starve_the_reactor(
         while acked < n:
             w.peer.expect(TA_PUT_RESP)
             acked += 1
+        # the planner matches each of the first units to rank 0, which is
+        # not parked: the unit stays pinned, the answer goes to the peer
         planner = _connect(w.ports[1], family)
+        answered = 0
+        for first in range(1, pinned + 1, 1000):
+            planner.sendall(b"".join(
+                tlv(SS_PLAN_MATCH, 2, [(F_SEQNO, q), (F_FOR_RANK, 0),
+                                       (F_REQ_HOME, 2), (F_RQSEQNO, 1)])
+                for q in range(first, first + 1000)))
+            w.peer.pump(0)
+            answered += sum(fr[0] == SS_RFR_RESP for fr in w.peer.frames)
+            w.peer.frames.clear()
+        deadline = _now() + LIMIT_S
+        while answered < pinned:
+            assert _now() < deadline, (answered, pinned)
+            w.peer.pump(0.5)
+            answered += sum(fr[0] == SS_RFR_RESP for fr in w.peer.frames)
+            w.peer.frames.clear()
         planner.sendall(tlv(SS_HUNGRY, 2, [(F_HUNGRY, 1)]))
         w.peer.frames.clear()
         for _ in range(3):  # the fast cadence is on: full snapshots come
