@@ -67,6 +67,18 @@ def warm_solve(config: dict, seed: int):
     return inputs, got, int(_NEG), time.monotonic() - t0
 
 
+def warm_dense(ctx):
+    """The default warm step of ``run``: ``warm_solve`` at the world's own
+    shape, handed over as the one-chip dense check. A warm step takes the
+    run's ``ctx`` and returns ``(check, shape, seconds)``: the solve check
+    ``run.py::finish`` compares (``solve_inputs``/``solve_got``/
+    ``pad_prio``, or ``solve_check``), the shape the set-up line prints,
+    and the device program's seconds."""
+    inputs, got, pad_prio, solve_s = warm_solve(ctx.config, ctx.seed)
+    check = {"solve_inputs": inputs, "solve_got": got, "pad_prio": pad_prio}
+    return check, f"{len(inputs[0])}x{len(inputs[3])}", solve_s
+
+
 class Tracer(threading.Thread):
     """Traces the device for a few seconds inside the window. It learns
     where the window lies from the producer's ``p0.start``."""
@@ -105,10 +117,12 @@ class Tracer(threading.Thread):
             self.error = e
 
 
-def run(ctx) -> dict:
+def run(ctx, warm=warm_dense) -> dict:
     """One world of the cell. ``ctx`` has ``config``, ``mix``, ``seed``,
     ``seconds``, ``trace``, ``chips``, ``scratch``, ``logdir``,
-    ``plan_path`` and ``say``."""
+    ``plan_path`` and ``say``. ``warm`` is the warm step (``warm_dense``'s
+    contract): a sibling plane whose planner solves another way passes
+    its own, and shares the world, the tracer and the readings."""
     import jax
 
     from adlb_tpu.native.capi import build_example, run_native_world
@@ -117,11 +131,11 @@ def run(ctx) -> dict:
     t_enter = time.monotonic()
     device = require_tpu(ctx.chips)
     t_backend = time.monotonic()
-    inputs, got, pad_prio, solve_s = warm_solve(config, ctx.seed)
+    check, shape, solve_s = warm(ctx)
     t_solved = time.monotonic()
     exe = build_example(CLIENT_SRC)
     ctx.say(f"set-up: backend {t_backend - t_enter:.2f}s, warm solve "
-            f"{len(inputs[0])}x{len(inputs[3])} {solve_s:.2f}s (of "
+            f"{shape} {solve_s:.2f}s (of "
             f"{t_solved - t_backend:.2f}s with its inputs), client build "
             f"{time.monotonic() - t_solved:.2f}s")
 
@@ -172,8 +186,7 @@ def run(ctx) -> dict:
     return {
         "device": device, "facts": facts, "flight": flight,
         "client_rcs": [rc for rc, _out, _err in results],
-        "world_s": world_s, "t_world": t0,
-        "solve_inputs": inputs, "solve_got": got, "pad_prio": pad_prio,
+        "world_s": world_s, "t_world": t0, **check,
         "trace_dir": trace_dir,
         "trace_window_s": tracer.window_s if tracer is not None else None,
     }

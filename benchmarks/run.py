@@ -15,7 +15,9 @@ a session of its own — the child, or a rank it forks, owns the chip, as
 the cell's plane decides (``planes/<plane>.py``) — and kills that whole
 session on every way out, so no rank, daemon or listener outlives a run.
 A run that finds no TPU, too few chips, a planner that solved on the
-host, or no ``adlb_tpu`` beside it exits non-zero and prints no result.
+host or kept its table on fewer chips than the cell has, a plane that
+handed over no solve check, or no ``adlb_tpu`` beside it exits non-zero
+and prints no result.
 """
 
 from __future__ import annotations
@@ -120,14 +122,58 @@ def parent(args) -> int:
 # ----------------------------------------------------------------- the child
 
 
-def check_planner(facts: dict) -> None:
-    """A run whose planner was not the chip did not measure this cell."""
+def check_planner(facts: dict, chips: int) -> None:
+    """A run whose planner was not the chip did not measure this cell; nor
+    did one of a cell on several chips whose planner kept its task table
+    on fewer (``balancer_mesh="auto"`` plans on one device where it sees
+    one). One-chip planners report no ``table_devices``."""
     if (facts.get("platform") != "tpu" or facts.get("host_solves") != 0
             or facts.get("device_failures") != 0
             or not facts.get("device_solves")):
         raise SystemExit(
             f"benchmark: the planner reports {json.dumps(facts)} — not "
             f"every round on a TPU; this run measured another system")
+    if chips > 1 and facts.get("table_devices") != chips:
+        raise SystemExit(
+            f"benchmark: the planner's task table lay on "
+            f"{facts.get('table_devices')} device(s) — it did not spread "
+            f"its table over the cell's {chips} chips")
+
+
+def solve_mismatch(rec: dict) -> int:
+    """Entries in which the device program's answer differs from the plain
+    reference's. A plane hands over one of two forms: ``solve_inputs``,
+    ``solve_got`` and ``pad_prio`` (the one-chip dense table, answered
+    here by ``reference/greedy.py``), or ``solve_check = {"want", "got"}``
+    (the answer of its own module under ``reference/`` beside the device
+    program's, two integer arrays of one shape; ``want`` may be a function
+    of no arguments, called here, so that the reference runs after the
+    window and outside set-up). A plane that hands over neither leaves
+    its device program unchecked: the run has no result."""
+    import numpy as np
+
+    from benchmarks.reference import greedy
+
+    dense = rec.get("solve_inputs") is not None
+    own = rec.get("solve_check") is not None
+    if dense == own:
+        raise SystemExit(
+            f"benchmark: the plane handed over "
+            f"{'both forms of' if dense else 'no'} solve check — the device "
+            f"program's answer cannot be compared")
+    if dense:
+        want = greedy.greedy_assign(*rec["solve_inputs"], rec["pad_prio"])
+        return int((want != rec["solve_got"]).sum())
+    want = rec["solve_check"]["want"]
+    want = np.asarray(want() if callable(want) else want)
+    got = np.asarray(rec["solve_check"]["got"])
+    if (want.shape != got.shape or want.dtype.kind not in "iu"
+            or got.dtype.kind not in "iu"):
+        raise SystemExit(
+            f"benchmark: the plane's solve check holds {want.dtype}"
+            f"{list(want.shape)} against {got.dtype}{list(got.shape)} — "
+            f"not two integer arrays of one shape")
+    return int((want != got).sum())
 
 
 def finish(spec, args, ctx, rec: dict, t_start: float) -> dict:
@@ -136,7 +182,7 @@ def finish(spec, args, ctx, rec: dict, t_start: float) -> dict:
     plane returned, ``ctx.plan`` what the producer was given."""
     from benchmarks.reduce import records, xplane
     from benchmarks.reduce.window import Window
-    from benchmarks.reference import compare, greedy, pool
+    from benchmarks.reference import compare, pool
 
     cell, config, mix = args.workload, ctx.config, ctx.mix
     logs = records.read_logs(ctx.logdir)
@@ -154,12 +200,8 @@ def finish(spec, args, ctx, rec: dict, t_start: float) -> dict:
     # the reference runs last: the window has closed, the peak is read
     plan = ctx.plan
     expected = pool.deliveries(plan)
-    mismatch = 0
-    if rec.get("solve_inputs") is not None:
-        want = greedy.greedy_assign(*rec["solve_inputs"], rec["pad_prio"])
-        mismatch = int((want != rec["solve_got"]).sum())
-    numbers = compare.compare(expected, logs, rec["client_rcs"], mismatch,
-                              len(plan))
+    numbers = compare.compare(expected, logs, rec["client_rcs"],
+                              solve_mismatch(rec), len(plan))
     correct = compare.verdict(numbers)
 
     trace = None
@@ -229,7 +271,7 @@ def child(args, root: str = ROOT) -> int:
         f"trace {args.trace}")
     rec = spec.plane(args.workload).run(ctx)
     say(f"world {rec['world_s']:.1f}s; planner {json.dumps(rec['facts'])}")
-    check_planner(rec["facts"])
+    check_planner(rec["facts"], cell["chips"])
     t_start = args.t0 if args.t0 is not None else T_START
     result = finish(spec, args, ctx, rec, t_start)
     say(f"result: {json.dumps(result)}")

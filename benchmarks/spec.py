@@ -141,3 +141,24 @@ class Spec:
             for kind in ("end_to_end", "per_layer"):
                 for m in self.metrics(kind, cell):
                     self.reader(m["name"])
+
+    def check_chips(self) -> None:
+        """The contract's rule on chips: a cell takes 1 or 4; at most half
+        the cells, rounded down, take four, and one always may; and a
+        four-chip cell's planner is told to use the mesh it is given
+        (``balancer_mesh`` "auto"), or it would plan on one chip of four."""
+        cells = self.doc["workloads"]
+        for w in cells:
+            if w["chips"] not in (1, 4):
+                raise SpecError(f"workload {w['name']!r} asks for "
+                                f"{w['chips']!r} chips, not 1 or 4")
+        four = [w["name"] for w in cells if w["chips"] == 4]
+        most = max(1, len(cells) // 2)
+        if len(four) > most:
+            raise SpecError(f"{len(four)} four-chip cells of {len(cells)} "
+                            f"({', '.join(four)}); at most {most} may be")
+        for name in four:
+            mesh = self.config(name).get("config", {}).get("balancer_mesh")
+            if mesh != "auto":
+                raise SpecError(f"four-chip cell {name!r}: its configuration "
+                                f"sets balancer_mesh {mesh!r}, not 'auto'")

@@ -99,11 +99,11 @@ def test_a_traced_run_reports_only_what_its_readers_found(tmp_path):
 def test_a_planner_that_was_not_the_chip_ends_the_run():
     good = {"platform": "tpu", "host_solves": 0, "device_failures": 0,
             "device_solves": 3}
-    bench_run.check_planner(good)
+    bench_run.check_planner(good, 1)
     for bad in ({"platform": "cpu"}, {"host_solves": 1},
                 {"device_failures": 1}, {"device_solves": 0}):
         with pytest.raises(SystemExit):
-            bench_run.check_planner({**good, **bad})
+            bench_run.check_planner({**good, **bad}, 1)
 
 
 def test_plain_greedy_by_hand():

@@ -337,7 +337,7 @@ def test_the_master_is_killed_alone_at_the_marker_reaped_and_the_run_reports(
     assert servers[str(BUDDY)]["failover_adopted"] == 640
     assert servers[str(THIRD)]["reactor_busy_s"] == 1.0
     # and the harness judges it as any run
-    bench_run.check_planner(rec["facts"])
+    bench_run.check_planner(rec["facts"], 1)
     args = types.SimpleNamespace(workload=CELL, trace=0)
     result = bench_run.finish(Spec(ROOT), args, ctx, rec, 0.0)
     assert result["correct"] is True and result["failed"] == 0

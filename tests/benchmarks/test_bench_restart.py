@@ -274,7 +274,7 @@ def test_world_a_is_killed_reaped_and_swept_and_the_run_reports(
     assert servers["9"]["wal_flush_by_second"] == {"1": 0.5, "2": 0.25}
     assert servers["10"]["reactor_busy_s"] == 1.0
     # and the harness judges it as any run
-    bench_run.check_planner(rec["facts"])
+    bench_run.check_planner(rec["facts"], 1)
     args = types.SimpleNamespace(workload=CELL, trace=0)
     result = bench_run.finish(Spec(ROOT), args, ctx, rec, 0.0)
     assert result["correct"] is True and result["failed"] == 0
